@@ -17,8 +17,7 @@ test:
 # the reference's Docker-backend role). Runs under pytest's DEFAULT
 # fd capture: the round-4 SIGABRT that forced a --capture=sys
 # mitigation stopped reproducing after the poison-chunk crash-loop
-# fix and the stray-agent cleanup (3 green full-suite runs recorded;
-# history + diagnosis kit in RUNS/stest_abort_repro.md).
+# fix and the stray-agent cleanup (3 green full-suite runs recorded).
 stest:
 	FIBER_BACKEND=tpu FIBER_TPU_HOSTS=sim:2 $(PYTEST)
 
@@ -50,7 +49,8 @@ chaos:
 
 # FIBER_BENCH_ENFORCE: fail loudly when the 1 ms host-pool point
 # drifts past its budget (the driver's plain `python bench.py` only
-# records it).
+# records it). Device mode: needs the chip, exits non-zero without one
+# (`python bench.py --platform cpu` rehearses on the CPU mesh).
 bench:
 	FIBER_BENCH_ENFORCE=1 python bench.py
 
@@ -205,7 +205,8 @@ weakscale:
 	JAX_PLATFORMS=cpu python __graft_entry__.py --weak-scaling
 
 lint:
-	python -m compileall -q fiber_tpu examples bench.py __graft_entry__.py
+	python -m compileall -q fiber_tpu examples bench.py __graft_entry__.py \
+		chip_smoke.py
 	python scripts/check_pycache.py fiber_tpu examples tests scripts
 	python scripts/check_docs_nav.py
 

@@ -2,11 +2,18 @@
 g++ (no pip involved), caches the .so next to the source, and exposes a
 ctypes binding. ``FIBER_NATIVE=0`` disables the native path entirely; every
 consumer has a pure-Python fallback.
+
+The artifact's file name carries the hash of the source it was built
+from, so a stale .so copied beside a newer checkout's pump.cpp (file
+times say nothing across copies and checkouts) is never loaded — it is
+simply not the file the loader looks for.
 """
 
 from __future__ import annotations
 
 import ctypes
+import glob
+import hashlib
 import os
 import subprocess
 import threading
@@ -14,29 +21,36 @@ from typing import Optional
 
 _HERE = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(_HERE, "pump.cpp")
-_SO = os.path.join(_HERE, "libfiberpump.so")
+_LOCK_PATH = os.path.join(_HERE, "libfiberpump.so.lock")
 
 _lib: Optional[ctypes.CDLL] = None
 _load_attempted = False
 _lock = threading.Lock()
 
 
-def _build() -> bool:
+def _so_path() -> str:
+    """The one artifact that matches THIS pump.cpp, by content."""
+    with open(_SRC, "rb") as fh:
+        digest = hashlib.sha256(fh.read()).hexdigest()[:16]
+    return os.path.join(_HERE, f"libfiberpump-{digest}.so")
+
+
+def _build(so: str) -> bool:
     """Compile under an exclusive file lock: many processes (concurrent
     pool-worker spawns) may race here, and exactly one must publish the
-    .so atomically (per-pid temp name + os.replace)."""
+    .so atomically (per-pid temp name + os.replace). Artifacts of other
+    source versions are swept once the new one is in place."""
     import fcntl
 
     cxx = os.environ.get("CXX", "g++")
-    tmp = f"{_SO}.tmp.{os.getpid()}"
-    lock_path = _SO + ".lock"
+    tmp = f"{so}.tmp.{os.getpid()}"
     try:
-        lock_fd = os.open(lock_path, os.O_CREAT | os.O_RDWR, 0o644)
+        lock_fd = os.open(_LOCK_PATH, os.O_CREAT | os.O_RDWR, 0o644)
     except OSError:
         return False
     try:
         fcntl.flock(lock_fd, fcntl.LOCK_EX)
-        if _so_fresh():
+        if os.path.exists(so):
             return True  # another process already built it
         proc = subprocess.run(
             [cxx, "-O2", "-std=c++17", "-shared", "-fPIC", "-pthread",
@@ -51,7 +65,13 @@ def _build() -> bool:
                 proc.stderr[-2000:],
             )
             return False
-        os.replace(tmp, _SO)
+        os.replace(tmp, so)
+        for stale in glob.glob(os.path.join(_HERE, "libfiberpump*.so")):
+            if stale != so:
+                try:
+                    os.unlink(stale)
+                except OSError:
+                    pass
         return True
     except (OSError, subprocess.TimeoutExpired):
         return False
@@ -67,13 +87,6 @@ def _build() -> bool:
         os.close(lock_fd)
 
 
-def _so_fresh() -> bool:
-    return os.path.exists(_SO) and (
-        not os.path.exists(_SRC)
-        or os.path.getmtime(_SRC) <= os.path.getmtime(_SO)
-    )
-
-
 def load() -> Optional[ctypes.CDLL]:
     """The pump library, building it if needed; None if unavailable."""
     global _lib, _load_attempted
@@ -83,15 +96,18 @@ def load() -> Optional[ctypes.CDLL]:
         if _load_attempted:
             return _lib
         _load_attempted = True
-        if not _so_fresh():
-            if not _build():
-                return None
         try:
-            lib = ctypes.CDLL(_SO)
+            so = _so_path()
+        except OSError:
+            return None  # no source beside the package: Python pump
+        if not os.path.exists(so) and not _build(so):
+            return None
+        try:
+            lib = ctypes.CDLL(so)
         except OSError:
             # A corrupt artifact must not poison future runs.
             try:
-                os.unlink(_SO)
+                os.unlink(so)
             except OSError:
                 pass
             return None
@@ -108,10 +124,8 @@ def load() -> Optional[ctypes.CDLL]:
         lib.fiber_pump_close.argtypes = [ctypes.c_void_p]
         lib.fiber_pump_peers.restype = ctypes.c_int
         lib.fiber_pump_peers.argtypes = [ctypes.c_void_p, ctypes.c_int]
-        if hasattr(lib, "nq_set_prefetch"):
-            lib.nq_set_prefetch.restype = None
-            lib.nq_set_prefetch.argtypes = [ctypes.c_void_p,
-                                            ctypes.c_int]
+        lib.nq_set_prefetch.restype = None
+        lib.nq_set_prefetch.argtypes = [ctypes.c_void_p, ctypes.c_int]
         lib.nq_connect.restype = ctypes.c_void_p
         lib.nq_connect.argtypes = [ctypes.c_char_p, ctypes.c_int,
                                    ctypes.c_int, ctypes.c_int,
@@ -226,10 +240,8 @@ class NativeClient:
                                 self.CONNECT_TIMEOUT_MS, key, len(key))
         if not handle:
             raise OSError(f"nq_connect failed for {host}:{port}")
-        if prefetch > 1 and hasattr(lib, "nq_set_prefetch"):
-            # r-mode credit window; a stale cached .so without the
-            # symbol silently keeps the demand-driven default.
-            lib.nq_set_prefetch(handle, int(prefetch))
+        if prefetch > 1:
+            lib.nq_set_prefetch(handle, int(prefetch))  # r-mode credits
         self._lib = lib
         self._handle = handle
         self._op_lock = threading.Lock()

@@ -115,8 +115,8 @@ def get_backend(name: Optional[str] = None) -> Backend:
             if sniffed:
                 # A sniffed selection must actually work before it is
                 # memoized: TPU-shaped environments exist where no
-                # host agent runs (e.g. a tunnel plugin injecting
-                # TPU_WORKER_HOSTNAMES into every interpreter), and
+                # host agent runs (a single TPU VM with TPU_WORKER_ID
+                # set and no `fiber-tpu up`), and
                 # accepting the backend there turns every Process
                 # start into a connection-refused retry loop. An
                 # explicit selection skips the probe — the operator

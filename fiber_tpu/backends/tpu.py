@@ -327,9 +327,10 @@ class TpuBackend(Backend):
     def probe_available(self) -> None:
         """Raise unless at least one host agent is reachable. Called by
         the registry for *sniffed* (non-explicit) selections only: a
-        TPU-shaped environment without running agents (e.g. a PJRT
-        tunnel plugin injecting TPU_WORKER_HOSTNAMES) must fall back to
-        the local backend instead of turning every job launch into a
+        TPU-shaped environment without running agents (a TPU VM with
+        TPU_WORKER_ID set where nobody ran `fiber-tpu up`) must fall
+        back to the local backend instead of turning every job launch
+        into a
         connection-refused retry loop. Sim clusters spawn their own
         agents in __init__, so they always pass."""
         import socket as pysocket

@@ -181,9 +181,6 @@ DEFAULTS: Dict[str, Any] = {
     # declares their holder suspect — recovery then never needs the
     # dead host.
     "store_replicate": True,
-    # Strip accelerator runtime preloads from spawned host workers (faster
-    # interpreter boot; only for workers that never touch the device).
-    "worker_lite": False,
     # --- telemetry plane (docs/observability.md) ---
     # Master switch for the metrics registry + span tracing. Off, every
     # instrument call is a single attribute check and nothing is

@@ -35,6 +35,11 @@ class TinyLM:
     ``"reference"`` (full score matrix, single device — for parity
     tests).
 
+    The flash kernels compile through Mosaic and need a TPU; on any
+    other platform ``attention="flash"`` is refused at construction
+    unless ``interpret=True`` asks for the Pallas interpreter (slow,
+    for CPU tests). The library never picks the interpreter itself.
+
     ``pos`` picks the positional scheme: ``"learned"`` (absolute
     table, the default) or ``"rope"`` (rotary embeddings on q/k per
     layer — relative positions, the modern long-context choice; no
@@ -58,6 +63,7 @@ class TinyLM:
         kv_heads: Optional[int] = None,
         pos: str = "learned",
         window: Optional[int] = None,
+        interpret: bool = False,
     ) -> None:
         if dim % heads:
             raise ValueError(f"dim {dim} not divisible by heads {heads}")
@@ -109,6 +115,17 @@ class TinyLM:
             raise ValueError(
                 "window= is single-device (a windowed partial's lse "
                 "is not ring-mergeable); drop the mesh or the window")
+        if attention == "flash" and not interpret:
+            import jax
+
+            platform = (mesh.devices.flat[0] if mesh is not None
+                        else jax.devices()[0]).platform
+            if platform != "tpu":
+                raise ValueError(
+                    "attention='flash' compiles Pallas kernels through "
+                    f"Mosaic and needs a TPU (platform is {platform!r}); "
+                    "pass interpret=True to run them in the Pallas "
+                    "interpreter instead")
         self.vocab = vocab
         self.dim = dim
         self.heads = heads
@@ -130,6 +147,8 @@ class TinyLM:
         self.pos = pos
         #: causal sliding window (flash plane only; None = full causal)
         self.window = window
+        #: flash plane only: run the kernels in the Pallas interpreter
+        self.interpret = interpret
         self._mesh = mesh
 
     # ------------------------------------------------------------------
@@ -190,22 +209,17 @@ class TinyLM:
 
             return reference_attention(q, k, v, causal=True)
         if self.attention == "flash":
-            from fiber_tpu.ops.pallas_attention import (
-                flash_attention,
-                flash_available,
-            )
+            from fiber_tpu.ops.pallas_attention import flash_attention
 
-            # Interpreter off-TPU so parity tests run anywhere; the
-            # kernel proper needs Mosaic.
             if self._flash_multi:
                 from fiber_tpu.ops.ring_attention import ring_attention
 
                 return ring_attention(
                     q, k, v, mesh=self._mesh, causal=True,
-                    local="flash", interpret=not flash_available())
+                    local="flash", interpret=self.interpret)
             return flash_attention(q, k, v, causal=True,
                                    window=self.window,
-                                   interpret=not flash_available())
+                                   interpret=self.interpret)
         if self.attention == "ulysses":
             from fiber_tpu.ops.ulysses_attention import ulysses_attention
 
@@ -437,6 +451,9 @@ def make_train_step(model: TinyLM, optimizer, batched: bool = False):
     attention (each sequence still spans the mesh)."""
     import jax
 
+    from fiber_tpu.utils.jaxcompat import ensure_compile_cache
+
+    ensure_compile_cache()
     if batched:
         def loss_fn(params, tokens):
             import jax.numpy as jnp
@@ -462,10 +479,10 @@ def make_train_step(model: TinyLM, optimizer, batched: bool = False):
         # async dispatch interleaves two step-generations over the CPU
         # client's fixed thread pool: step k+1's per-device programs
         # park in their first rendezvous on threads step k's last
-        # rendezvous still needs (core-dump-verified on the 1-core dev
-        # box, RUNS/stest_abort_repro.md). Serializing steps on a CPU
-        # mesh closes the window and costs nothing measurable there
-        # (compute-bound); real TPU keeps full async dispatch.
+        # rendezvous still needs (core-dump-verified on a 1-core dev
+        # box). Serializing steps on a CPU mesh closes the window and
+        # costs nothing measurable there (compute-bound); real TPU
+        # keeps full async dispatch.
         def step_sync(params, opt_state, tokens):
             out = jitted(params, opt_state, tokens)
             jax.block_until_ready(out)

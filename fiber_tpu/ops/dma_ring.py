@@ -16,12 +16,12 @@ primitive into the two exchange shapes the sequence-parallel ops use:
 * :func:`ring_all_to_all` — ``lax.all_to_all(tiled=True)`` semantics
   built from n-1 ring rotations, for the Ulysses head/sequence swap.
 
-Both run inside ``shard_map`` like the collectives they replace, and
-both carry a Pallas-interpreter fallback (``interpret=True``,
-auto-detected off-TPU) so CPU meshes can pin numerics. Interpreter
-caveat (probed, jax 0.4.37): interpret mode requires a SCALAR
-``device_id`` where compiled Mosaic takes the documented 1-tuple —
-``_device_id`` papers over it.
+Both run inside ``shard_map`` like the collectives they replace. They
+compile through Mosaic by default and raise off-TPU; ``interpret=True``
+is the caller's explicit request for the Pallas interpreter (CPU-mesh
+numerics tests). Interpreter caveat (jax 0.9.0): interpret mode
+requires a SCALAR ``device_id`` where compiled Mosaic takes the
+documented 1-tuple — ``_device_id`` papers over it.
 
 Forward-only: ``make_async_remote_copy`` defines no VJP, so the
 ``use_dma_ring=`` flags in ring/ulysses attention are for inference
@@ -46,7 +46,7 @@ def _device_id(right, interpret: bool):
 
 
 def ring_exchange(arrays: Sequence, *, axis: str, n_dev: int = None,
-                  interpret: bool = None) -> List:
+                  interpret: bool = False) -> List:
     """Rotate every array in ``arrays`` one step right along ``axis``
     (device i's block lands on device i+1 — identical semantics to
     ``lax.ppermute`` with ``[(i, (i+1) % n)]``) via async remote DMA,
@@ -54,12 +54,8 @@ def ring_exchange(arrays: Sequence, *, axis: str, n_dev: int = None,
     import jax
 
     arrays = list(arrays)
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     if n_dev is None:
-        from fiber_tpu.utils.jaxcompat import axis_size
-
-        n_dev = axis_size(axis)
+        n_dev = jax.lax.axis_size(axis)
     if n_dev <= 1 or not arrays:
         return arrays
 
@@ -80,9 +76,9 @@ def ring_exchange(arrays: Sequence, *, axis: str, n_dev: int = None,
             # scribble into memory the neighbor's previous step is
             # still using. Signal both neighbors, wait for both — the
             # left one because it writes into US. Compiled-only:
-            # interpret mode has no remote-signal lowering (probed,
-            # jax 0.4.37) and no race either — its DMA discharge rule
-            # runs the per-device programs lockstep via all_gather.
+            # interpret mode has no remote-signal lowering (jax 0.9.0)
+            # and no race either — its DMA discharge rule runs the
+            # per-device programs lockstep via all_gather.
             left = jax.lax.rem(my + n_dev - 1, n_dev)
             barrier = pltpu.get_barrier_semaphore()
             pltpu.semaphore_signal(
@@ -115,10 +111,8 @@ def ring_exchange(arrays: Sequence, *, axis: str, n_dev: int = None,
         num_scalar_prefetch=0,
         # ANY keeps the blocks in HBM: the DMA engine reads/writes HBM
         # directly, no VMEM staging of multi-MB KV blocks.
-        in_specs=[pl.BlockSpec(memory_space=pltpu.TPUMemorySpace.ANY)
-                  for _ in range(k)],
-        out_specs=[pl.BlockSpec(memory_space=pltpu.TPUMemorySpace.ANY)
-                   for _ in range(k)],
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY) for _ in range(k)],
+        out_specs=[pl.BlockSpec(memory_space=pl.ANY) for _ in range(k)],
         scratch_shapes=[pltpu.SemaphoreType.DMA] * (2 * k),
     )
     kwargs = {}
@@ -126,7 +120,7 @@ def ring_exchange(arrays: Sequence, *, axis: str, n_dev: int = None,
         # get_barrier_semaphore needs a collective_id so concurrent
         # collective kernels never share one barrier; every ring
         # rotation in a program runs sequentially, so one id is safe.
-        kwargs["compiler_params"] = pltpu.TPUCompilerParams(
+        kwargs["compiler_params"] = pltpu.CompilerParams(
             collective_id=0)
     out = pl.pallas_call(
         kernel,
@@ -140,7 +134,7 @@ def ring_exchange(arrays: Sequence, *, axis: str, n_dev: int = None,
 
 
 def ring_all_to_all(x, *, axis: str, split_axis: int, concat_axis: int,
-                    n_dev: int = None, interpret: bool = None):
+                    n_dev: int = None, interpret: bool = False):
     """``lax.all_to_all(x, axis, split_axis, concat_axis, tiled=True)``
     semantics over the DMA ring: the full local array rotates n-1
     steps; at each step the device slices out its own block of the
@@ -154,9 +148,7 @@ def ring_all_to_all(x, *, axis: str, split_axis: int, concat_axis: int,
     import jax.numpy as jnp
 
     if n_dev is None:
-        from fiber_tpu.utils.jaxcompat import axis_size
-
-        n_dev = axis_size(axis)
+        n_dev = jax.lax.axis_size(axis)
     if n_dev <= 1:
         return x
     if x.shape[split_axis] % n_dev:

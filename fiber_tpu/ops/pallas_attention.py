@@ -14,8 +14,8 @@ backward runs two more Pallas kernels (dq sweep over KV blocks; dk/dv
 sweep over Q blocks) from the saved (q, k, v, out, logsumexp) residuals
 — the FlashAttention-2 recurrence. Exact — not an approximation: output
 and gradients match the full-matrix reference to numerical tolerance,
-pinned by tests in interpret mode on CPU and A/B'd on chip by
-``bench.py --attention`` (``flash_speedup``).
+pinned by tests in interpret mode on CPU and, compiled through Mosaic,
+by ``chip_smoke.py`` on the chip.
 
 The reference framework has no kernels and no attention (SURVEY.md §5);
 this is the repo's own TPU-native bar, not a parity item.
@@ -128,8 +128,10 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref,
         safe_l = jnp.where(l == 0.0, 1.0, l)         # fully-masked rows
         o_ref[0] = (acc_ref[:] / safe_l).astype(o_ref.dtype)
         # logsumexp residual for the backward pass: exp(s - lse) is the
-        # already-normalized softmax weight.
-        lse_ref[0] = (m_ref[:] + jnp.log(safe_l))[:, 0]
+        # already-normalized softmax weight. Stored as a (block_q, 1)
+        # column — the layout the statistics already have — so no
+        # sublane-to-lane relayout is asked of Mosaic.
+        lse_ref[0] = m_ref[:] + jnp.log(safe_l)
 
 
 # ---------------------------------------------------------------------------
@@ -147,7 +149,8 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref,
 def _bwd_p_ds(q, k, v, do, lse, delta, iq, ik, *, block_q, block_kv,
               causal, scale, window=None):
     """Shared recompute: softmax weights p and score grads ds for one
-    (q-block, kv-block) pair, all f32."""
+    (q-block, kv-block) pair, all f32. ``lse`` and ``delta`` are
+    (block_q, 1) columns."""
     import jax
     import jax.numpy as jnp
 
@@ -155,7 +158,7 @@ def _bwd_p_ds(q, k, v, do, lse, delta, iq, ik, *, block_q, block_kv,
         q, k, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32,
     ) * scale
-    p = jnp.exp(s - lse[:, None])                    # (bq, bkv)
+    p = jnp.exp(s - lse)                             # (bq, bkv)
     if causal:
         p = jnp.where(_keep_mask(iq, ik, block_q, block_kv, window),
                       p, 0.0)
@@ -163,7 +166,7 @@ def _bwd_p_ds(q, k, v, do, lse, delta, iq, ik, *, block_q, block_kv,
         do, v, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32,
     )
-    ds = p * (dp - delta[:, None])
+    ds = p * (dp - delta)
     return p, ds
 
 
@@ -288,9 +291,12 @@ def flash_attention(q, k, v, *, causal: bool = False,
     from O(S^2) to O(S*window) — the standard local-attention layer of
     sliding-window transformers. Composes with GQA.
 
-    ``interpret=True`` runs the kernels in the Pallas interpreter
-    (CPU-testable, slow) — used by the test suite; on TPU leave False.
-    The compiled program is cached per (shape, dtype, flags).
+    The kernels compile through Mosaic and need a TPU: off-TPU the
+    default raises (Pallas refuses to compile for the platform).
+    ``interpret=True`` is the caller's explicit request for the Pallas
+    interpreter (CPU-testable, slow) — the test suite passes it; the
+    library never selects it. The compiled program is cached per
+    (shape, dtype, flags).
     """
     fn = _build(q.shape, str(q.dtype), causal, block_q, block_kv,
                 interpret, _kv_heads_of(q, k), window)
@@ -344,6 +350,9 @@ def _build_calls(shape, dtype, causal, block_q, block_kv, interpret,
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
+    from fiber_tpu.utils.jaxcompat import ensure_compile_cache
+
+    ensure_compile_cache()
     s, h, d = shape
     kvh = kv_heads or h
     if kvh < 1 or h % kvh:
@@ -363,7 +372,11 @@ def _build_calls(shape, dtype, causal, block_q, block_kv, interpret,
     qkv_spec_q = pl.BlockSpec((1, bq, d), lambda ih, iq, ik: (ih, iq, 0))
     qkv_spec_k = pl.BlockSpec(
         (1, bk, d), lambda ih, iq, ik: (ih // group, ik, 0))
-    row_spec_q = pl.BlockSpec((1, bq), lambda ih, iq, ik: (ih, iq))
+    # Per-row statistics (lse, delta) travel as (h, s, 1) columns:
+    # Mosaic wants the last two block dims divisible by (8, 128) or
+    # equal to the array's, which a (1, bq) row block of an (h, s)
+    # array is not — and the kernels consume them as columns anyway.
+    row_spec_q = pl.BlockSpec((1, bq, 1), lambda ih, iq, ik: (ih, iq, 0))
 
     fwd_call = pl.pallas_call(
         functools.partial(_fwd_kernel, block_q=bq, block_kv=bk,
@@ -373,7 +386,7 @@ def _build_calls(shape, dtype, causal, block_q, block_kv, interpret,
         in_specs=[qkv_spec_q, qkv_spec_k, qkv_spec_k],
         out_specs=[qkv_spec_q, row_spec_q],
         out_shape=[jax.ShapeDtypeStruct((h, s, d), dtype),
-                   jax.ShapeDtypeStruct((h, s), jnp.float32)],
+                   jax.ShapeDtypeStruct((h, s, 1), jnp.float32)],
         scratch_shapes=[
             pltpu.VMEM((bq, 1), jnp.float32),    # running max m
             pltpu.VMEM((bq, 1), jnp.float32),    # denominator l
@@ -403,7 +416,7 @@ def _build_calls(shape, dtype, causal, block_q, block_kv, interpret,
     dkv_k_spec = pl.BlockSpec(
         (1, bk, d), lambda ikv, ik, g, iq: (ikv, ik, 0))
     dkv_row_spec = pl.BlockSpec(
-        (1, bq), lambda ikv, ik, g, iq: (ikv * group + g, iq))
+        (1, bq, 1), lambda ikv, ik, g, iq: (ikv * group + g, iq, 0))
     dkv_call = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, block_q=bq, block_kv=bk,
                           n_q=n_q, group=group, causal=causal,
@@ -431,10 +444,11 @@ def _make_attn(shape, dtype, causal, block_q, block_kv, interpret,
         window)
 
     def _fwd_core(q, k, v):
-        """(S,H,D) API -> (H,S,D) kernels and back."""
+        """(S,H,D) API -> (H,S,D) kernels and back; the kernel's
+        (H,S,1) logsumexp column leaves as the public (H,S)."""
         out, lse = fwd_call(jnp.swapaxes(q, 0, 1), jnp.swapaxes(k, 0, 1),
                             jnp.swapaxes(v, 0, 1))
-        return jnp.swapaxes(out, 0, 1), lse
+        return jnp.swapaxes(out, 0, 1), lse[..., 0]
 
     def _bwd_core(q, k, v, out, lse, dout, dlse):
         # ds_ij = p_ij * (dp_ij - delta_i + dlse_i): the lse cotangent
@@ -447,6 +461,7 @@ def _make_attn(shape, dtype, causal, block_q, block_kv, interpret,
             delta = delta - dlse.astype(jnp.float32)
         qt, kt, vt = (jnp.swapaxes(x, 0, 1) for x in (q, k, v))
         dot = jnp.swapaxes(dout, 0, 1)
+        lse, delta = lse[..., None], delta[..., None]   # kernel columns
         dq = dq_call(qt, kt, vt, dot, lse, delta)
         dk, dv = dkv_call(qt, kt, vt, dot, lse, delta)
         return tuple(jnp.swapaxes(g, 0, 1) for g in (dq, dk, dv))
@@ -499,14 +514,3 @@ def _build_lse(shape, dtype, causal, block_q, block_kv, interpret,
     return _make_attn(shape, dtype, causal, block_q, block_kv,
                       interpret, with_lse=True, kv_heads=kv_heads,
                       window=window)
-
-
-def flash_available() -> bool:
-    """True when the TPU kernel path can run here (a TPU backend with
-    Mosaic; the interpreter path works anywhere but is test-only)."""
-    try:
-        import jax
-
-        return jax.devices()[0].platform == "tpu"
-    except Exception:
-        return False

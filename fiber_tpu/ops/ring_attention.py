@@ -78,6 +78,12 @@ def _accumulate_block(q_blk, q_pos, k_cur, v_cur, kv_pos0, m, l, o,
 
     acc = _acc_dtype(q_blk.dtype)
 
+    # Rematerialized: under jax AD the backward pass recomputes a
+    # chunk's scores from (k_c, v_c, m, l, o) instead of keeping every
+    # chunk's (h, sq, chunk) score and weight slabs alive until then.
+    # Without it the O(sq x chunk) bound holds for the forward only — a
+    # 16k-token TinyLM train step asked one v5e chip for 90 GB of HBM.
+    @jax.checkpoint
     def one_chunk(k_c, v_c, kv_pos, m, l, o):
         mask = None
         if causal:
@@ -191,16 +197,15 @@ def _kv_rotate(k_cur, v_cur, *, axis: str, n_dev: int,
     exchange (ops/dma_ring): both blocks' DMAs are in flight at once
     and the copy engine runs beside compute instead of serializing the
     program on each transfer. Forward-only (no VJP) — callers needing
-    gradients keep the default. ``interpret=True`` forces the Pallas
-    interpreter; False auto-detects (interpreter off-TPU)."""
+    gradients keep the default. ``interpret=True`` runs the exchange
+    in the Pallas interpreter; False compiles it (TPU only)."""
     import jax
 
     if use_dma_ring:
         from fiber_tpu.ops.dma_ring import ring_exchange
 
         k_cur, v_cur = ring_exchange(
-            (k_cur, v_cur), axis=axis, n_dev=n_dev,
-            interpret=True if interpret else None)
+            (k_cur, v_cur), axis=axis, n_dev=n_dev, interpret=interpret)
         return k_cur, v_cur
     perm = [(i, (i + 1) % n_dev) for i in range(n_dev)]
     return (jax.lax.ppermute(k_cur, axis, perm),
@@ -299,8 +304,7 @@ def ring_attention_local(q_blk, k_blk, v_blk, *, axis: str,
     batch shard, and every sequence still spans the full seq axis. It
     also composes with ``vmap`` and jax AD (gradient parity with full
     attention is pinned in tests). ``n_devices`` defaults to the bound
-    axis's true size (the ``axis_size`` shim in utils/jaxcompat —
-    ``jax.lax.axis_size`` only exists on newer jax) — pass it only to
+    axis's true size (``jax.lax.axis_size``) — pass it only to
     override, and beware a mismatch silently drops KV blocks.
 
     ``use_dma_ring=True`` rotates KV via the Pallas async remote-DMA
@@ -312,9 +316,7 @@ def ring_attention_local(q_blk, k_blk, v_blk, *, axis: str,
     import jax
     import jax.numpy as jnp
 
-    from fiber_tpu.utils.jaxcompat import axis_size
-
-    n_dev = (axis_size(axis) if n_devices is None
+    n_dev = (jax.lax.axis_size(axis) if n_devices is None
              else n_devices)
     if local == "flash":
         return _ring_flash_local(q_blk, k_blk, v_blk, axis=axis,

@@ -26,16 +26,17 @@ strategies"); this is part of the TPU-native long-context mandate.
 
 from __future__ import annotations
 
-# (mesh, axis, causal) -> jitted program. Same policy as ring_attention:
-# meshes hash by value, there are only ever a handful per process, so a
-# plain dict is the right cache.
+# (mesh, axis, causal, ...) -> jitted program. Same policy as
+# ring_attention: meshes hash by value, there are only ever a handful
+# per process, so a plain dict is the right cache.
 _compiled_cache: dict = {}
 
 
 def ulysses_attention_local(q_blk, k_blk, v_blk, *, axis: str,
                             causal: bool = False,
                             local: str = "reference",
-                            use_dma_ring: bool = False):
+                            use_dma_ring: bool = False,
+                            interpret: bool = False):
     """The raw per-device Ulysses body, for COMPOSITION inside a
     caller's own ``shard_map`` (the all-to-alls bind by axis NAME, so
     it composes with other mesh axes exactly like
@@ -48,7 +49,10 @@ def ulysses_attention_local(q_blk, k_blk, v_blk, *, axis: str,
     sequence: ``"reference"`` (full score matrix — fastest at moderate
     seq, O(S^2) memory), ``"blockwise"`` (KV-chunked online softmax —
     O(S·chunk) memory, differentiable everywhere), or ``"flash"``
-    (the Pallas kernels — TPU, forward+backward)."""
+    (the Pallas kernels — TPU, forward+backward). ``interpret=True``
+    runs every Pallas piece (flash kernels, DMA ring) in the Pallas
+    interpreter for CPU-mesh tests; the default compiles them and
+    raises off-TPU."""
     import jax
 
     from fiber_tpu.ops.ring_attention import (
@@ -63,43 +67,38 @@ def ulysses_attention_local(q_blk, k_blk, v_blk, *, axis: str,
     # (seq, heads/n, head_dim); every device now sees the whole
     # sequence for its head slice.
     def seq_to_heads(x):
-        return _a2a(x, axis, 1, 0, use_dma_ring)
+        return _a2a(x, axis, 1, 0, use_dma_ring, interpret)
 
     qh = seq_to_heads(q_blk)
     kh = seq_to_heads(k_blk)
     vh = seq_to_heads(v_blk)
     if local == "flash":
-        from fiber_tpu.ops.pallas_attention import (
-            flash_attention,
-            flash_available,
-        )
+        from fiber_tpu.ops.pallas_attention import flash_attention
 
-        # Interpreter off-TPU so the composed path is pinnable by the
-        # CPU suite; the kernel proper needs Mosaic.
         out = flash_attention(qh, kh, vh, causal=causal,
-                              interpret=not flash_available())
+                              interpret=interpret)
     elif local == "blockwise":
         out = blockwise_attention(qh, kh, vh, causal=causal)
     else:
         out = reference_attention(qh, kh, vh, causal=causal)
     # all-to-all #2: scatter sequence, gather heads — back to the
     # input layout.
-    return _a2a(out, axis, 0, 1, use_dma_ring)
+    return _a2a(out, axis, 0, 1, use_dma_ring, interpret)
 
 
 def _a2a(x, axis: str, split_axis: int, concat_axis: int,
-         use_dma_ring: bool):
+         use_dma_ring: bool, interpret: bool):
     """The tiled all-to-all both Ulysses swaps run: XLA's native
     collective by default, or the Pallas async remote-DMA ring
-    (ops/dma_ring — forward-only, interpreter fallback off-TPU) when
-    ``use_dma_ring`` is set."""
+    (ops/dma_ring — forward-only) when ``use_dma_ring`` is set."""
     import jax
 
     if use_dma_ring:
         from fiber_tpu.ops.dma_ring import ring_all_to_all
 
         return ring_all_to_all(x, axis=axis, split_axis=split_axis,
-                               concat_axis=concat_axis)
+                               concat_axis=concat_axis,
+                               interpret=interpret)
     return jax.lax.all_to_all(
         x, axis, split_axis=split_axis, concat_axis=concat_axis,
         tiled=True,
@@ -107,7 +106,7 @@ def _a2a(x, axis: str, split_axis: int, concat_axis: int,
 
 
 def _build(mesh, axis: str, causal: bool, local: str,
-           use_dma_ring: bool = False):
+           use_dma_ring: bool = False, interpret: bool = False):
     import functools
 
     import jax
@@ -116,7 +115,7 @@ def _build(mesh, axis: str, causal: bool, local: str,
 
     local_fn = functools.partial(
         ulysses_attention_local, axis=axis, causal=causal, local=local,
-        use_dma_ring=use_dma_ring,
+        use_dma_ring=use_dma_ring, interpret=interpret,
     )
 
     spec = P(axis)
@@ -129,7 +128,8 @@ def _build(mesh, axis: str, causal: bool, local: str,
 
 def ulysses_attention(q, k, v, mesh=None, axis: str = "pool",
                       causal: bool = False, local: str = "reference",
-                      use_dma_ring: bool = False):
+                      use_dma_ring: bool = False,
+                      interpret: bool = False):
     """Exact attention with the sequence dim sharded over ``axis``.
 
     q, k, v: (seq, heads, head_dim); ``seq`` and ``heads`` must both
@@ -139,8 +139,9 @@ def ulysses_attention(q, k, v, mesh=None, axis: str = "pool",
     ``"flash"`` lift the O(S^2) local-memory constraint.
     ``use_dma_ring=True`` runs both swaps over the Pallas async
     remote-DMA ring (forward-only; numerics pinned against the native
-    collective in tests). Mesh keys hash by value, so the compiled
-    program is shared across equal meshes (no id-aliasing)."""
+    collective in tests). ``interpret=True`` runs the Pallas pieces in
+    the interpreter (CPU-mesh tests). Mesh keys hash by value, so the
+    compiled program is shared across equal meshes (no id-aliasing)."""
     from fiber_tpu.parallel.mesh import default_mesh
 
     mesh = mesh or default_mesh()
@@ -155,9 +156,9 @@ def ulysses_attention(q, k, v, mesh=None, axis: str = "pool",
             f"ulysses needs heads % n_dev == 0 (got {heads} heads over "
             f"{n_dev} devices); use ring_attention for odd head counts"
         )
-    key = (mesh, axis, causal, local, use_dma_ring)
+    key = (mesh, axis, causal, local, use_dma_ring, interpret)
     fn = _compiled_cache.get(key)
     if fn is None:
-        fn = _build(mesh, axis, causal, local, use_dma_ring)
+        fn = _build(mesh, axis, causal, local, use_dma_ring, interpret)
         _compiled_cache[key] = fn
     return fn(q, k, v)

@@ -35,6 +35,9 @@ def make_mesh(shape: Optional[Sequence[int]] = None,
     from jax.sharding import Mesh
     import numpy as np
 
+    from fiber_tpu.utils.jaxcompat import ensure_compile_cache
+
+    ensure_compile_cache()
     devices = jax.devices()
     if shape is None:
         cfg = mesh_from_config()
@@ -60,10 +63,9 @@ def is_multidevice_cpu(mesh) -> bool:
     """True when ``mesh`` spans >1 CPU device — the configuration where
     XLA's in-process collective rendezvous can DEADLOCK if async
     dispatch interleaves two program generations over the CPU client's
-    fixed thread pool (core-dump-verified on the 1-core dev box,
-    RUNS/stest_abort_repro.md). Decides on the mesh's OWN devices, not
-    the default backend: an explicit CPU mesh under an accelerator
-    default must still count."""
+    fixed thread pool (core-dump-verified on a 1-core dev box). Decides
+    on the mesh's OWN devices, not the default backend: an explicit CPU
+    mesh under an accelerator default must still count."""
     if mesh is None or getattr(mesh, "size", 1) <= 1:
         return False
     try:

@@ -75,6 +75,24 @@ def jax_distributed_initializer(rank: int, size: int,
     rank 0's address is the coordinator; afterwards jax.devices() spans
     every host and collectives ride ICI/DCN.
 
+    Ranks are ONE PER HOST. A chip belongs to one process at a time and
+    a JAX process reaches for every chip of its host; the launcher
+    gives every rank the same environment, so it cannot hand each rank
+    a chip of its own. Tried on one four-chip v5e host (PR 21): rank 0
+    took all four chips and ranks 1-3 died with "The TPU is already in
+    use by process with pid N". (Ranks CAN share a host if the caller's
+    own initializer sets libtpu's per-process variables —
+    ``TPU_VISIBLE_CHIPS``, ``TPU_CHIPS_PER_PROCESS_BOUNDS``,
+    ``TPU_PROCESS_BOUNDS``, ``TPU_PROCESS_ADDRESSES``,
+    ``TPU_PROCESS_PORT``, ``CLOUD_TPU_TASK_ID`` — from ``rank`` before
+    jax is imported, then calls this function: that ran, four processes
+    with one chip each and a global psum. It is the caller's code, not
+    a launcher feature.) On one host the supported path is the
+    single-process mesh (``jax.devices()`` in the process that calls
+    the device plane). Mark the rank function
+    ``@fiber_tpu.meta(device=True)``: jobs without a device hint are
+    host-plane workers, whose JAX the launcher pins to the CPU.
+
     On CPU hosts (tests, dev boxes) cross-process collectives need the
     gloo implementation selected before the backend initializes; on TPU
     the ICI fabric needs nothing extra. Verified end-to-end by
@@ -84,10 +102,7 @@ def jax_distributed_initializer(rank: int, size: int,
     import jax
 
     if jax.config.jax_platforms == "cpu":
-        try:
-            jax.config.update("jax_cpu_collectives_implementation", "gloo")
-        except Exception:  # older/newer jax without the knob: best effort
-            pass
+        jax.config.update("jax_cpu_collectives_implementation", "gloo")
     coordinator = f"{addrs[0][0]}:{addrs[0][1]}"
     jax.distributed.initialize(
         coordinator_address=coordinator,

@@ -70,7 +70,10 @@ def _jax_array_rebuild(host):
 
     # The device boundary of every pickled jax.Array (store resolution,
     # result deserialize): accounted per-site so `fiber-tpu explain`
-    # can blame transfer seconds (docs/observability.md).
+    # can blame transfer seconds (docs/observability.md). The array
+    # lands on THIS process's default device: the chip in the process
+    # that holds it, the CPU in a host-plane worker (whose environment
+    # the launcher pins to JAX_PLATFORMS=cpu).
     with DEVICE.transfer("deserialize", getattr(host, "nbytes", 0)):
         return jax.device_put(host)
 
@@ -91,11 +94,11 @@ def register_jax_reducers() -> None:
     # Pickle dispatch is exact-type, so the concrete ArrayImpl class must
     # be registered (not the jax.Array ABC). Import it without creating an
     # array: materializing even a scalar would initialize the TPU runtime
-    # from whatever process happens to pickle first.
-    try:
-        from jax._src.array import ArrayImpl
-    except ImportError:  # pragma: no cover - jax internals moved
-        return
+    # from whatever process happens to pickle first. A private path (jax
+    # 0.9.0): if it moves, this import fails loudly rather than leaving
+    # jax.Arrays to pickle device-by-device.
+    from jax._src.array import ArrayImpl
+
     ForkingPickler.register(ArrayImpl, _jax_array_reduce)
     _jax_reducer_registered = True
 

@@ -14,13 +14,12 @@ indistinguishable from slow compute, MFU only computed inside
   a tracing span when a trace context is ambient, and a flight event —
   so ``fiber-tpu explain`` can grow a ``transfer`` blame category.
 * **Compile observability** — ``jax.monitoring`` event/duration
-  listeners (null-safe shim in :mod:`fiber_tpu.utils.jaxcompat` for
-  jax versions without it) count compiles and compile seconds, and a
-  fingerprint-keyed recompile detector feeds the watchdog's
+  listeners count compiles, compile seconds and persistent-cache hits,
+  and a fingerprint-keyed recompile detector feeds the watchdog's
   ``recompile_storm`` rule: the SAME logical function compiling over
   and over is shape churn, not progress.
 * **Device gauges** — per-process HBM ``memory_stats()``
-  (bytes_in_use / limit; honestly ``None`` on CPU and older jaxlib),
+  (bytes_in_use / limit; honestly ``None`` on CPU),
   live-array count/bytes, pushed into the registry each monitor tick
   so the PR-8 time-series and the ``hbm_fill`` anomaly rule see them.
 * **Live MFU** — whenever a device peak resolves
@@ -100,13 +99,13 @@ class DeviceTelemetry:
         # compile observability
         self._compiles = 0
         self._compile_seconds = 0.0
+        self._cache_hits = 0  # persistent compilation cache hits
         self._fingerprints: Dict[str, int] = {}
         self._recompiles: "collections.deque" = collections.deque(
             maxlen=256)  # (mono, fingerprint)
         self.storm_count = 4
         self.storm_window_s = 30.0
         self._listeners_installed = False
-        self._monitoring_available: Optional[bool] = None
         # last live-MFU observation (None values are honest nulls)
         self._mfu: Dict[str, Any] = {
             "mfu": None, "flops_per_sec": None, "peak_row": None,
@@ -179,41 +178,50 @@ class DeviceTelemetry:
 
     # -- compile observability -----------------------------------------
     def install_listeners(self) -> bool:
-        """Register the jax.monitoring compile listeners (idempotent;
-        null-safe: False when the installed jax has no monitoring
-        surface — every other signal still works). NEVER imports jax:
-        a process that hasn't loaded it (lite pool workers, host
-        agents) must not pay a multi-second interpreter tax for
-        telemetry — installation is retried from the gauge probe and
-        compile notes once jax shows up."""
+        """Register the jax.monitoring compile listeners (idempotent).
+        NEVER imports jax: a process that hasn't loaded it (lite pool
+        workers, host agents) must not pay a multi-second interpreter
+        tax for telemetry — False means deferred, and installation is
+        retried from the gauge probe and compile notes once jax shows
+        up."""
         if self._listeners_installed:
             return True
         if "jax" not in sys.modules:
-            return False  # deferred, not unavailable: retried later
-        if self._monitoring_available is False:
             return False
-        from fiber_tpu.utils.jaxcompat import register_monitoring_listeners
+        from jax import monitoring
 
-        ok = register_monitoring_listeners(self._on_jax_event,
-                                           self._on_jax_duration)
-        self._monitoring_available = ok
-        self._listeners_installed = ok
-        return ok
+        monitoring.register_event_listener(self._on_jax_event)
+        monitoring.register_event_duration_secs_listener(
+            self._on_jax_duration)
+        self._listeners_installed = True
+        return True
 
     def _on_jax_event(self, event: str, **kwargs: Any) -> None:
-        # jax emits many event kinds; only compilation concerns us —
-        # and a compilation-CACHE hit/request is precisely not a
-        # compilation (counting it would make the healthy cached path
-        # look like a storm).
-        if "compil" not in event:
+        # jax emits many event kinds; only compilation concerns us. A
+        # persistent-cache hit is precisely not a compilation: it is
+        # counted on its own. A miss is one, but the event names no
+        # program, so it moves the totals and stays out of the
+        # fingerprint-keyed storm detector (every program's miss would
+        # otherwise look like ONE function recompiling).
+        if event == "/jax/compilation_cache/cache_hits":
+            with self._lock:
+                self._cache_hits += 1
             return
-        if "cache" in event and "miss" not in event:
+        if event != "/jax/compilation_cache/cache_misses":
             return
-        self.note_compile(event)
+        if not self.enabled:
+            return
+        with self._lock:
+            self._compiles += 1
+            self.revision += 1
+        _m_compiles.inc()
 
     def _on_jax_duration(self, event: str, duration: float,
                          **kwargs: Any) -> None:
-        if "compil" not in event:
+        # Trace/lower/backend-compile durations; the persistent cache's
+        # own durations (time SAVED by a hit, retrieval time) are not
+        # compilation seconds.
+        if "compil" not in event or "compilation_cache" in event:
             return
         if not self.enabled:
             return
@@ -292,10 +300,10 @@ class DeviceTelemetry:
                        items: int) -> Optional[float]:
         """One device map finished having executed ``flops`` analytic
         FLOPs in ``wall_s``. When the device peak resolves
-        (utils/flops.py — real TPU kind, or FIBER_PEAK_FLOPS), the MFU
-        lands in the ``pool_map_mfu`` gauge; otherwise the observation
-        records ``mfu: None`` honestly (CPU posture). Returns the MFU
-        or None."""
+        (utils/flops.py — a TPU kind in its table), the MFU lands in
+        the ``pool_map_mfu`` gauge; otherwise the observation records
+        ``mfu: None`` honestly (CPU posture). A TPU missing from the
+        table raises. Returns the MFU or None."""
         if not self.enabled or wall_s <= 0:
             return None
         from fiber_tpu.utils import flops as flopsmod
@@ -303,13 +311,10 @@ class DeviceTelemetry:
         value = None
         fps = float(flops) / wall_s
         peak = {"peak_row": None}
-        try:
-            devices = _devices()
-            if devices:
-                value = flopsmod.mfu(fps, devices)
-                peak = flopsmod.peak_report(devices)
-        except Exception:  # noqa: BLE001 - accounting must not fail maps
-            pass
+        devices = _devices()
+        if devices:
+            value = flopsmod.mfu(fps, devices)
+            peak = flopsmod.peak_report(devices)
         with self._lock:
             self._mfu = {"mfu": value, "flops_per_sec": fps,
                          "peak_row": peak.get("peak_row"),
@@ -361,6 +366,7 @@ class DeviceTelemetry:
                     sum(a[1] for a in self._transfers.values()), 6),
                 "compiles": self._compiles,
                 "compile_seconds": round(self._compile_seconds, 6),
+                "compile_cache_hits": self._cache_hits,
                 "compile_fingerprints": dict(self._fingerprints),
                 "hbm": dict(self._hbm),
                 "live_arrays": dict(self._live),
@@ -386,6 +392,7 @@ class DeviceTelemetry:
             self._transfers.clear()
             self._compiles = 0
             self._compile_seconds = 0.0
+            self._cache_hits = 0
             self._fingerprints.clear()
             self._recompiles.clear()
             self.revision = 0
@@ -425,13 +432,13 @@ def _platform() -> Optional[str]:
 def _hbm_stats() -> Dict[str, Optional[int]]:
     """First-local-device memory stats: ``{"bytes_in_use", "bytes_limit"}``,
     both None when unavailable (CPU backends return None or an empty
-    dict from ``memory_stats()``; older jaxlib lacks the method)."""
+    dict from ``memory_stats()``)."""
     devices = _devices()
     if not devices:
         return {"bytes_in_use": None, "bytes_limit": None}
     try:
-        stats = getattr(devices[0], "memory_stats", lambda: None)()
-    except Exception:  # noqa: BLE001 - platform-dependent surface
+        stats = devices[0].memory_stats()
+    except Exception:  # noqa: BLE001 - a PJRT error must not kill the probe
         stats = None
     if not stats:
         return {"bytes_in_use": None, "bytes_limit": None}

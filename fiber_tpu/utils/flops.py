@@ -22,14 +22,14 @@ Counting conventions (stated so the numbers are auditable):
 
 Peak figures are bf16 MXU peaks per *jax device* (on v2/v3 a device is
 one TensorCore, half a chip; v4 onward a device is one chip). Public
-numbers; override with ``FIBER_PEAK_FLOPS`` (FLOP/s per device) for
-unlisted hardware.
+numbers, one table keyed by ``device_kind``. A TPU that is not in the
+table is an error, not a default and not an environment variable: add
+its row with its source. The CPU has no row and resolves to "no peak"
+(``None``) — an honest answer, so CPU runs report ``mfu: null``.
 """
 
 from __future__ import annotations
 
-import os
-import sys
 from typing import Optional, Sequence
 
 #: bf16 peak matmul FLOP/s per jax device, by substring of device_kind
@@ -39,7 +39,6 @@ _PEAK_BY_KIND = (
     ("v5p", 459e12),       # v5p chip
     ("v5 lite", 197e12),   # v5e chip
     ("v5e", 197e12),
-    ("v5", 459e12),        # bare "v5" -> assume v5p-class
     ("v4 lite", 138e12),   # v4i inference chip
     ("v4", 275e12),        # v4 chip (megacore device)
     ("v3", 61.5e12),       # v3 TensorCore (123e12 per 2-core chip)
@@ -59,52 +58,39 @@ ENV_STEP_FLOPS = {
 }
 
 
-#: device_kinds already reported (warn once per kind per process)
-_reported_miss: set = set()
-
-
 def _resolve_peak(device):
     """Single source of truth for peak resolution — both the MFU math
     (device_peak_flops) and the audit fields (peak_report) derive from
     this, so the reported row can never diverge from the peak used.
 
     Returns ``(kind, peak, row)``: lowercased device_kind (platform as
-    fallback), peak FLOP/s or None, and the human-auditable row string
-    ("env:...", "<table-sub>:<peak>", or None)."""
+    fallback), peak FLOP/s, and the human-auditable row string
+    ("<table-sub>:<peak>"). A non-TPU device has no peak:
+    ``(kind, None, None)``. A TPU whose device_kind matches no row
+    raises ``LookupError``."""
     kind = ((getattr(device, "device_kind", "") or "").lower()
             or getattr(device, "platform", ""))
-    env = os.environ.get("FIBER_PEAK_FLOPS")
-    if env:
-        peak = float(env)
-        return kind, peak, f"env:{peak:.4g}"
     if "tpu" not in kind and getattr(device, "platform", "") != "tpu":
         return kind, None, None
     for sub, peak in _PEAK_BY_KIND:
         if sub in kind:
             return kind, peak, f"{sub}:{peak:.4g}"
-    return kind, None, None
+    raise LookupError(
+        f"no peak FLOP/s row for TPU device_kind {kind!r}: add it to "
+        "_PEAK_BY_KIND in fiber_tpu/utils/flops.py with its source")
 
 
 def device_peak_flops(device) -> Optional[float]:
-    """bf16 peak matmul FLOP/s for one jax device, or None if unknown
-    (e.g. the CPU fallback — an MFU against a CPU 'peak' would be
-    noise, not signal). A TPU device_kind that matches NO peak-table
-    row is a loud failure (stderr, once per kind): a silent None here
-    would make the first real-hardware MFU quietly null."""
-    kind, peak, row = _resolve_peak(device)
-    is_tpu = "tpu" in kind or getattr(device, "platform", "") == "tpu"
-    if peak is None and is_tpu and kind not in _reported_miss:
-        _reported_miss.add(kind)
-        print(f"FLOPS PEAK TABLE MISS: device_kind={kind!r} matched no "
-              f"_PEAK_BY_KIND row; mfu will be null — set "
-              f"FIBER_PEAK_FLOPS to override", file=sys.stderr, flush=True)
-    return peak
+    """bf16 peak matmul FLOP/s for one jax device; None for a device
+    that is not a TPU (an MFU against a CPU 'peak' would be noise, not
+    signal). Raises ``LookupError`` for a TPU with no table row."""
+    return _resolve_peak(device)[1]
 
 
 def peak_report(devices: Sequence) -> dict:
     """Self-validation fields for bench records: the device_kind the
-    measurement ran on and which peak-table row (or env override) it
-    resolved to, so an MFU figure is auditable without rerunning."""
+    measurement ran on and which peak-table row it resolved to, so an
+    MFU figure is auditable without rerunning."""
     kind, _, row = _resolve_peak(devices[0])
     return {"device_kind": kind, "peak_row": row}
 

@@ -1,69 +1,43 @@
-"""Compatibility shims for the installed jax version.
+"""The device plane's door to JAX, written for the one installation there
+is (Python 3.12, jax/jaxlib 0.9.0).
 
-The device plane uses ``shard_map``, whose public surface moved across
-jax releases: newer jax exports ``jax.shard_map`` with a ``check_vma``
-kwarg; older releases ship ``jax.experimental.shard_map.shard_map``
-with the same parameter named ``check_rep``. Every fiber_tpu site
-imports from here so the repo runs against either — a hard constraint
-of the environment (no pip installs; the baked-in jax is what there
-is)."""
+Every module that builds a compiled program reaches JAX through here:
+the ``shard_map`` sites import the re-export below, and the others
+(``flash_attention``, ``make_train_step``, ``make_mesh``) call
+:func:`ensure_compile_cache` themselves. Either way the persistent
+compilation cache is placed before the process's first compilation.
+Never imported from ``fiber_tpu/__init__.py`` — host workers stay
+JAX-free.
+"""
 
-import inspect
+import os
 
-try:  # newer jax: public alias
-    from jax import shard_map as _shard_map
-except ImportError:  # older jax: experimental home, same semantics
-    from jax.experimental.shard_map import shard_map as _shard_map
+import jax
+from jax import shard_map  # noqa: F401 - re-export for the shard_map sites
 
-_PARAMS = frozenset(inspect.signature(_shard_map).parameters)
-_RENAMES = (("check_vma", "check_rep"), ("check_rep", "check_vma"))
-
-
-def shard_map(f, **kwargs):
-    """``shard_map`` with kwarg-name translation: callers may use the
-    modern names; whichever spelling the installed jax understands is
-    what it receives."""
-    for ours, theirs in _RENAMES:
-        if ours in kwargs and ours not in _PARAMS and theirs in _PARAMS:
-            kwargs[theirs] = kwargs.pop(ours)
-    return _shard_map(f, **kwargs)
+#: Where compiled programs persist when nobody said otherwise: one fixed
+#: directory inside the checkout, derived from this file's location. The
+#: path is part of what a cache hit depends on, so it must not move
+#: between runs (no tempfile, pid, timestamp, $HOME or cwd).
+COMPILE_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))),
+    ".jax_cache")
 
 
-def axis_size(axis_name):
-    """Size of a bound mesh axis, inside a collective context.
-    ``jax.lax.axis_size`` only exists on newer jax; the classic
-    spelling — ``psum(1, axis)``, constant-folded to the axis size —
-    works everywhere."""
-    import jax
+def ensure_compile_cache() -> str:
+    """Place JAX's persistent compilation cache; returns the directory
+    in effect. A directory configured from outside wins —
+    ``JAX_COMPILATION_CACHE_DIR`` (which JAX reads itself) or an
+    earlier ``jax.config.update`` — and then this sets nothing.
+    Otherwise the cache goes to :data:`COMPILE_CACHE_DIR`. Idempotent;
+    JAX initializes the cache lazily at the next compilation, so a
+    program compiled before this call merely went uncached."""
+    configured = jax.config.jax_compilation_cache_dir
+    if configured:
+        return configured
+    jax.config.update("jax_compilation_cache_dir", COMPILE_CACHE_DIR)
+    return COMPILE_CACHE_DIR
 
-    if hasattr(jax.lax, "axis_size"):
-        return int(jax.lax.axis_size(axis_name))
-    return int(jax.lax.psum(1, axis_name))
 
-
-def register_monitoring_listeners(on_event, on_duration) -> bool:
-    """Null-safe shim over ``jax.monitoring``: register ``on_event``
-    (called with the event key) and ``on_duration`` (event key +
-    seconds) for jax-internal events — compilation being the one the
-    device telemetry plane cares about. Returns False when the
-    installed jax predates the monitoring surface (or exposes neither
-    listener hook): callers degrade gracefully, recording nothing
-    rather than raising (docs/observability.md "Device telemetry")."""
-    try:
-        from jax import monitoring
-    except ImportError:
-        return False
-    reg_event = getattr(monitoring, "register_event_listener", None)
-    reg_duration = getattr(
-        monitoring, "register_event_duration_secs_listener",
-        getattr(monitoring, "register_event_duration_listener", None))
-    if reg_event is None and reg_duration is None:
-        return False
-    try:
-        if reg_event is not None:
-            reg_event(on_event)
-        if reg_duration is not None:
-            reg_duration(on_duration)
-    except Exception:  # noqa: BLE001 - a broken hook must not crash init
-        return False
-    return True
+ensure_compile_cache()

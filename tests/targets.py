@@ -448,3 +448,19 @@ def arr_sum_plus_accel(arr, i):
 from fiber_tpu.meta import meta as _meta  # noqa: E402
 
 arr_sum_plus_accel = _meta(tpu=1)(arr_sum_plus_accel)
+
+
+def jax_worker_view(arr):
+    """What a host-plane worker sees of JAX: its platform pin, and where
+    a pickled jax.Array landed when it was unpickled here (the one-
+    process-per-chip rule — tests/test_chip_smoke.py)."""
+    import jax
+
+    return {
+        "pid": os.getpid(),
+        "JAX_PLATFORMS": os.environ.get("JAX_PLATFORMS"),
+        "is_jax_array": isinstance(arr, jax.Array),
+        "platforms": sorted({d.platform for d in arr.devices()}),
+        "default_backend": jax.default_backend(),
+        "sum": float(arr.sum()),
+    }

@@ -216,7 +216,7 @@ def test_kill_worker_resubmit_bills_each_task_exactly_once(tmp_path):
         seed=SEED, token_dir=str(tmp_path / "tokens"),
         kill_after_chunks=2, kill_times=1))
     try:
-        fiber_tpu.init(worker_lite=True)
+        fiber_tpu.init()
         with fiber_tpu.Pool(2) as pool:
             xs = list(range(60))
             assert pool.map(targets.square, xs, chunksize=4,
@@ -254,7 +254,7 @@ def test_wire_reconciliation_across_io_engines(io):
     I/O core beneath the accounting plane. Under shm this also proves
     the doorbell wake frames stay off both ledgers (they are dropped
     before the counting ingress by design)."""
-    fiber_tpu.init(worker_lite=True, transport_io=io)
+    fiber_tpu.init(transport_io=io)
     job = f"acct-io-{io}"
     with fiber_tpu.Pool(2) as pool:
         xs = list(range(40))
@@ -287,7 +287,7 @@ def test_speculation_first_result_wins_bills_once(tmp_path):
         slow_worker_after_chunks=1, slow_worker_s=1.0,
         slow_worker_times=1))
     try:
-        fiber_tpu.init(worker_lite=True, speculation_enabled=True,
+        fiber_tpu.init(speculation_enabled=True,
                        speculation_quantile=2.0)
         with fiber_tpu.Pool(3) as pool:
             pool.map(targets.identity, range(3))  # spin-up barrier
@@ -351,7 +351,7 @@ def test_budget_exceeded_fires_on_capped_map_and_record_persists():
     renders the persisted report with the violation."""
     from fiber_tpu import cli, telemetry
 
-    fiber_tpu.init(worker_lite=True)
+    fiber_tpu.init()
     with fiber_tpu.Pool(2) as pool:
         xs = list(range(12))
         out = pool.map(targets.sleep_echo, xs, chunksize=2,
@@ -417,7 +417,7 @@ def test_two_concurrent_maps_disjoint_reports_over_sim_pool(monkeypatch):
     config.get().update(tpu_hosts="sim:2")
     reset_backends()
     try:
-        fiber_tpu.init(worker_lite=True, backend="tpu",
+        fiber_tpu.init(backend="tpu",
                        tpu_hosts="sim:2")
         with fiber_tpu.Pool(4) as pool:
             pool.map(targets.identity, range(4))  # spin-up barrier
@@ -548,7 +548,7 @@ def test_telemetry_snapshot_carries_costs():
 
 
 def test_accounting_disabled_pool_bills_nothing():
-    fiber_tpu.init(worker_lite=True, accounting_enabled=False)
+    fiber_tpu.init(accounting_enabled=False)
     with fiber_tpu.Pool(2) as pool:
         assert pool.map(targets.square, list(range(8))) == \
             [x * x for x in range(8)]

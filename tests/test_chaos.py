@@ -364,7 +364,7 @@ def test_soak_partition_plus_master_kill_then_resume(chaos_plan,
     script = (
         "import fiber_tpu\n"
         "from tests import targets\n"
-        "fiber_tpu.init(worker_lite=True, heartbeat_interval=0.2,\n"
+        "fiber_tpu.init(heartbeat_interval=0.2,\n"
         "               suspect_timeout=1.2)\n"
         "with fiber_tpu.Pool(2) as pool:\n"
         f"    pool.map(targets.sleep_echo, list(range(64)), chunksize=2,\n"

@@ -1,0 +1,179 @@
+"""chip_smoke.py and the rules it pins, as far as a machine without a
+chip can check them: the default invocation refuses to run here; the
+explicit CPU rehearsal runs every phase end to end (so the script cannot
+rot between chip runs) and a second run hits the compile cache; the
+cache lives at one fixed place unless placed from outside; host-plane
+workers are pinned to the CPU; and the Pallas kernels compile through
+Mosaic for a v5e (ahead of time — libtpu needs no chip for that)."""
+
+import json
+import os
+import subprocess
+import sys
+
+import pytest
+
+import fiber_tpu
+from tests import targets
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+SMOKE = os.path.join(REPO, "chip_smoke.py")
+
+
+def _python(args, env=None, cwd=REPO, timeout=600):
+    full_env = dict(os.environ)
+    full_env.update(env or {})
+    return subprocess.run([sys.executable, *args], cwd=cwd, env=full_env,
+                          capture_output=True, text=True, timeout=timeout)
+
+
+def test_smoke_refuses_to_run_without_a_chip():
+    """No accelerator: non-zero exit, the reason on stderr, and no
+    result line — never a CPU run under the chip's name."""
+    proc = _python([SMOKE], env={"JAX_PLATFORMS": "cpu"})
+    assert proc.returncode == 2, proc.stdout + proc.stderr
+    assert "not 'tpu'" in proc.stderr
+    assert "platform=cpu" in proc.stdout       # the header still says what
+    assert "compile cache=" in proc.stdout     # it found
+    assert not any(line.startswith("{")
+                   for line in proc.stdout.splitlines())
+
+
+def test_smoke_cpu_rehearsal_end_to_end_and_second_run_hits_cache(
+        tmp_path):
+    """The explicit rehearsal drives every phase (tiny shapes, 8 virtual
+    devices, kernels interpreted at the script's own request); a second
+    invocation against the same cache directory reports cache hits."""
+    env = {"JAX_COMPILATION_CACHE_DIR": str(tmp_path / "jaxcache")}
+    verdicts = []
+    for _ in range(2):
+        proc = _python([SMOKE, "--rehearse-cpu"], env=env)
+        assert proc.returncode == 0, proc.stdout + proc.stderr
+        verdicts.append(json.loads(proc.stdout.splitlines()[-1]))
+    first, second = verdicts
+    assert first["ok"] is True and first["rehearsal"] is True
+    assert first["device"] == {"platform": "cpu", "kind": "cpu",
+                               "count": 8}
+    assert set(first["phases"].values()) == {"pass"}
+    assert len(first["phases"]) == 7
+    assert first["compile_cache_hits"] == 0
+    assert second["ok"] is True
+    assert second["compile_cache_hits"] > 0
+
+
+_CACHE_PROBE = (
+    "import os, sys; sys.path.insert(0, {repo!r}); "
+    "import fiber_tpu.utils.jaxcompat as jc, jax; "
+    "print(jax.config.jax_compilation_cache_dir); "
+    "print(jc.ensure_compile_cache())"
+)
+
+
+def test_compile_cache_place(tmp_path):
+    """Unset, the cache is ONE fixed directory inside the checkout —
+    identical across fresh interpreters and working directories (the
+    path is part of what a hit depends on). Placed from outside, the
+    library leaves jax's own setting alone."""
+    code = _CACHE_PROBE.format(repo=REPO)
+    unset = dict(os.environ)
+    unset.pop("JAX_COMPILATION_CACHE_DIR", None)
+    seen = set()
+    for cwd in (REPO, str(tmp_path)):
+        proc = subprocess.run([sys.executable, "-c", code], cwd=cwd,
+                              env=unset, capture_output=True, text=True,
+                              timeout=120)
+        assert proc.returncode == 0, proc.stderr
+        configured, returned = proc.stdout.split()
+        assert configured == returned
+        seen.add(configured)
+    assert seen == {os.path.join(REPO, ".jax_cache")}
+
+    outside = str(tmp_path / "elsewhere")
+    proc = _python(["-c", code], env={"JAX_COMPILATION_CACHE_DIR": outside},
+                   cwd=str(tmp_path), timeout=120)
+    assert proc.returncode == 0, proc.stderr
+    assert proc.stdout.split() == [outside, outside]
+
+
+def test_host_worker_is_pinned_to_cpu_and_unpickles_arrays_there(
+        monkeypatch):
+    """One process per chip: a Pool worker started without a device
+    hint gets JAX_PLATFORMS=cpu whatever the master's environment says,
+    so it cannot take the chip, and a pickled jax.Array lands on its
+    CPU device."""
+    import jax.numpy as jnp
+
+    monkeypatch.setenv("JAX_PLATFORMS", "tpu")  # what a TPU host exports
+    fiber_tpu.init()
+    arr = jnp.arange(16.0)
+    with fiber_tpu.Pool(2) as pool:
+        views = pool.map(targets.jax_worker_view, [arr, arr])
+    for view in views:
+        assert view["pid"] != os.getpid()
+        assert view["JAX_PLATFORMS"] == "cpu"
+        assert view["is_jax_array"] is True
+        assert view["platforms"] == ["cpu"]
+        assert view["default_backend"] == "cpu"
+        assert view["sum"] == 120.0
+
+
+def test_device_hinted_job_inherits_the_launching_environment(
+        monkeypatch):
+    """...and a job that DOES carry a device hint is not pinned: it
+    inherits what the master exports (on a pod host, the accelerator)."""
+    from fiber_tpu.backends import get_backend
+    from fiber_tpu.launcher import JobLauncher
+
+    monkeypatch.setenv("JAX_PLATFORMS", "tpu")
+    launcher = JobLauncher.__new__(JobLauncher)
+    launcher.backend = get_backend()
+    plain = fiber_tpu.Process(target=targets.noop)
+    hinted = fiber_tpu.Process(target=targets.noop,
+                               meta_hints={"device": True})
+    assert launcher._job_spec(plain, ["true"]).env["JAX_PLATFORMS"] == "cpu"
+    assert "JAX_PLATFORMS" not in launcher._job_spec(hinted, ["true"]).env
+
+
+_AOT = r"""
+import os, sys
+sys.path.insert(0, {repo!r})
+os.environ["JAX_PLATFORMS"] = "cpu"
+os.environ.setdefault("TPU_ACCELERATOR_TYPE", "v5litepod-4")
+os.environ.setdefault("TPU_WORKER_HOSTNAMES", "localhost")
+import jax, jax.numpy as jnp
+jax.config.update("jax_enable_compilation_cache", False)
+from jax.experimental import topologies
+from jax.sharding import SingleDeviceSharding
+try:
+    topo = topologies.get_topology_desc(platform="tpu",
+                                        topology_name="v5e:2x2")
+except Exception as err:
+    print("NO_TOPOLOGY", repr(err)); sys.exit(0)
+from fiber_tpu.ops.pallas_attention import flash_attention_lse
+sharding = SingleDeviceSharding(topo.devices[0])
+def loss(q, k, v):
+    out, lse = flash_attention_lse(q, k, v, causal=True, window=1024)
+    return jnp.sum(out * out) + jnp.sum(lse)
+S, H, KVH, D = 2048, 8, 2, 32
+args = [jax.ShapeDtypeStruct((S, h, D), jnp.float32, sharding=sharding)
+        for h in (H, KVH, KVH)]
+lowered = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).trace(*args).lower(
+    lowering_platforms=("tpu",))
+print("CUSTOM_CALLS", lowered.as_text().count("tpu_custom_call"))
+lowered.compile()
+print("COMPILED", topo.devices[0].device_kind)
+"""
+
+
+def test_flash_kernels_compile_through_mosaic_for_v5e():
+    """Forward, dq and dkv kernels lower and compile for a TPU v5e with
+    the installed libtpu — no chip needed for compilation, so a block
+    spec or layout Mosaic refuses fails HERE, not on the first chip run.
+    (GQA 8/2, head_dim 32, sliding window: the awkward corners.)"""
+    proc = _python(["-c", _AOT.format(repo=REPO)], timeout=600)
+    if "NO_TOPOLOGY" in proc.stdout:
+        pytest.skip("libtpu gives no compile-only v5e topology here: "
+                    + proc.stdout.strip()[-300:])
+    assert proc.returncode == 0, proc.stdout + proc.stderr[-3000:]
+    assert "CUSTOM_CALLS 3" in proc.stdout, proc.stdout
+    assert "COMPILED TPU v5" in proc.stdout, proc.stdout

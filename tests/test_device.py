@@ -1525,7 +1525,7 @@ def test_train_step_serializes_on_cpu_mesh():
     """Multi-device CPU-mesh training steps must dispatch synchronously:
     XLA CPU's in-process collective rendezvous can deadlock when async
     dispatch interleaves two step generations over the client's fixed
-    thread pool (core-dump-verified, RUNS/stest_abort_repro.md). The
+    thread pool (core-dump-verified on a 1-core dev box). The
     guard must also see the EFFECTIVE mesh — a bare ring/ulysses model
     resolves the default mesh at attend time."""
     import optax

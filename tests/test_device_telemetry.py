@@ -94,7 +94,7 @@ def test_transfer_counters_through_real_map_with_store_broadcast():
     shipped to the master on the result stream (("dev", ...) frames),
     where Pool.device_stats() renders it beside the master's own and
     the backend's per-host snapshots."""
-    fiber_tpu.init(worker_lite=True, store_inline_max=64 * 1024)
+    fiber_tpu.init(store_inline_max=64 * 1024)
     arr = np.ones((200_000,), dtype=np.float64)  # 1.6MB > inline max
     with fiber_tpu.Pool(2) as pool:
         out = pool.starmap(targets.arr_sum_plus,
@@ -167,42 +167,52 @@ def test_dmap_transfer_accounted_and_fingerprinted():
 # ---------------------------------------------------------------------------
 
 
-def test_monitoring_listener_shim_is_null_safe(monkeypatch):
-    """Older jax without jax.monitoring (or with the hooks missing):
-    registration reports False and nothing raises — every other
-    device-plane signal keeps working."""
-    from fiber_tpu.utils import jaxcompat
+def test_monitoring_listeners_install_directly_and_defer_without_jax(
+        monkeypatch):
+    """The compile listeners register with jax.monitoring itself (the
+    installed jax has the surface; no shim). A process that never
+    imported jax defers — telemetry must not pay the import."""
+    import sys
 
-    monitoring = pytest.importorskip("jax").monitoring
-    monkeypatch.delattr(monitoring, "register_event_listener",
-                        raising=False)
-    monkeypatch.delattr(monitoring,
-                        "register_event_duration_secs_listener",
-                        raising=False)
-    monkeypatch.delattr(monitoring, "register_event_duration_listener",
-                        raising=False)
-    assert jaxcompat.register_monitoring_listeners(
-        lambda *a, **k: None, lambda *a, **k: None) is False
+    import jax
+
     from fiber_tpu.telemetry.device import DeviceTelemetry
 
     fresh = DeviceTelemetry()
-    assert fresh.install_listeners() is False
-    # and a compile-accounting call still works without the listeners
+    with monkeypatch.context() as m:
+        m.delitem(sys.modules, "jax")
+        assert fresh.install_listeners() is False   # deferred
+    assert fresh.install_listeners() is True
+    assert fresh.install_listeners() is True        # idempotent
+    # a real compilation reaches the installed duration listener
+    jax.jit(lambda x: x * 3 + 1)(np.arange(7.0)).block_until_ready()
+    assert fresh.snapshot()["compile_seconds"] > 0
+    # and a compile-accounting call works with or without listeners
     fresh.note_compile("fp")
-    assert fresh.snapshot()["compiles"] == 1
+    assert fresh.snapshot()["compile_fingerprints"] == {"fp": 1}
 
 
 def test_jax_event_listener_counts_compiles_not_cache_hits():
     fiber_tpu.init()
     DEVICE._on_jax_event("/jax/compilation_cache/tasks_using_cache")
     DEVICE._on_jax_event("/jax/compilation_cache/cache_hits")
-    assert DEVICE.snapshot()["compiles"] == 0
-    DEVICE._on_jax_event("/jax/compilation_cache/cache_misses")
+    snap = DEVICE.snapshot()
+    assert snap["compiles"] == 0
+    assert snap["compile_cache_hits"] == 1
+    # A miss is a compilation — but the event names no program, so a
+    # burst of them (every program misses a cold persistent cache) must
+    # not read as ONE function recompiling.
+    for _ in range(DEVICE.storm_count + 1):
+        DEVICE._on_jax_event("/jax/compilation_cache/cache_misses")
     DEVICE._on_jax_duration("backend_compile", 0.25)
     DEVICE._on_jax_duration("/jax/unrelated/event", 9.0)
+    # time SAVED by a cache hit is not time spent compiling
+    DEVICE._on_jax_duration(
+        "/jax/compilation_cache/compile_time_saved_sec", 5.0)
     snap = DEVICE.snapshot()
-    assert snap["compiles"] == 1
+    assert snap["compiles"] == DEVICE.storm_count + 1
     assert snap["compile_seconds"] == pytest.approx(0.25)
+    assert snap["recompile"]["storm"] is False
 
 
 def test_recompile_storm_synthetic_trigger_and_watchdog_edge_clear():
@@ -339,14 +349,20 @@ def test_live_mfu_gauge_when_peak_resolves(monkeypatch):
     assert mfu["mfu"] is None
     assert mfu["items"] == 8
     assert mfu["flops_per_sec"] > 0
-    # a resolved peak (FIBER_PEAK_FLOPS, the bench-cluster override)
-    # populates the gauge
-    monkeypatch.setenv("FIBER_PEAK_FLOPS", "1e12")
+    # a resolved peak (a device whose kind has a row in the table of
+    # utils/flops.py) populates the gauge
+    class V5e:
+        platform = "tpu"
+        device_kind = "TPU v5 lite"
+
+    from fiber_tpu.telemetry import device as devicemod
+
+    monkeypatch.setattr(devicemod, "_devices", lambda: [V5e()])
     with fiber_tpu.Pool(2) as pool:
         pool.map(sq, np.arange(8.0))
     mfu = DEVICE.snapshot()["mfu"]
     assert mfu["mfu"] is not None and 0 < mfu["mfu"] < 1
-    assert mfu["peak_row"] == "env:1e+12"
+    assert mfu["peak_row"] == "v5 lite:1.97e+14"
     assert telemetry.gauge("pool_map_mfu").value() == \
         pytest.approx(mfu["mfu"])
     kinds = {(e["plane"], e["kind"]) for e in FLIGHT.snapshot()}
@@ -380,7 +396,7 @@ def test_trace_dump_merges_xla_capture(tmp_path):
     """The unified timeline: trace_dump writes ONE valid Chrome trace
     holding host spans AND the XLA capture's device ops, rebased onto
     the wall axis and on distinct process rows."""
-    fiber_tpu.init(worker_lite=True)
+    fiber_tpu.init()
     xla_dir = _write_fake_xla_capture(tmp_path / "xla")
     with fiber_tpu.Pool(2) as pool:
         xs = list(range(8))
@@ -482,7 +498,7 @@ def test_device_stats_and_cli_over_sim_pool(monkeypatch, capsys):
     config.get().update(tpu_hosts="sim:2")
     reset_backends()
     try:
-        fiber_tpu.init(worker_lite=True, backend="tpu",
+        fiber_tpu.init(backend="tpu",
                        tpu_hosts="sim:2", store_inline_max=64 * 1024)
         arr = np.ones((200_000,), dtype=np.float64)
         with fiber_tpu.Pool(4) as pool:

@@ -139,7 +139,7 @@ def test_ulysses_attention_dma_matches_reference():
     k = _rand((128, 8, 16), seed=11)
     v = _rand((128, 8, 16), seed=12)
     got = np.asarray(jax.device_get(ulysses_attention(
-        q, k, v, causal=True, use_dma_ring=True)))
+        q, k, v, causal=True, use_dma_ring=True, interpret=True)))
     want = np.asarray(jax.device_get(
         reference_attention(q, k, v, causal=True)))
     assert np.abs(got - want).max() < 2e-5

@@ -222,7 +222,7 @@ def test_master_sigkill_mid_map_then_cli_resume(tmp_path, capsys):
     script = (
         "import fiber_tpu\n"
         "from tests import targets\n"
-        "fiber_tpu.init(worker_lite=True)\n"
+        "fiber_tpu.init()\n"
         "with fiber_tpu.Pool(2) as pool:\n"
         f"    pool.map(targets.sleep_echo, list(range(48)), chunksize=2,\n"
         f"             job_id={job!r})\n"

@@ -26,7 +26,7 @@ def _hier_isolation():
 
 
 def _hier_pool(n=2, **over):
-    fiber_tpu.init(worker_lite=True, cpu_per_job=2,
+    fiber_tpu.init(cpu_per_job=2,
                    dispatch_mode="hier", **over)
     return fiber_tpu.Pool(n)
 

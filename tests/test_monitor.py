@@ -276,8 +276,7 @@ def test_chaos_slow_worker_raises_throughput_drop(tmp_path):
     against its trailing window and the watchdog must flag it."""
     plan = _install_chaos(tmp_path, slow_worker_after_chunks=6,
                           slow_worker_s=1.0, slow_worker_times=2)
-    fiber_tpu.init(monitor_interval_s=0.1, anomaly_drop_pct=0.5,
-                   worker_lite=True)
+    fiber_tpu.init(monitor_interval_s=0.1, anomaly_drop_pct=0.5)
     with fiber_tpu.Pool(2) as pool:
         xs = list(range(24))
         out = pool.map(targets.sleep_echo, xs, chunksize=1)
@@ -298,7 +297,7 @@ def test_chaos_partition_raises_heartbeat_age(tmp_path):
     plan = _install_chaos(tmp_path, partition_after=6, partition_s=3.0,
                           partition_times=1)
     fiber_tpu.init(monitor_interval_s=0.1, heartbeat_interval=0.2,
-                   suspect_timeout=1.5, worker_lite=True)
+                   suspect_timeout=1.5)
     with fiber_tpu.Pool(2) as pool:
         xs = list(range(60))
         out = pool.map(targets.sleep_echo, xs, chunksize=2)
@@ -375,7 +374,7 @@ def test_profiler_folded_roundtrip_through_real_map(tmp_path):
     """Workers run the sampler (profiler_hz ships in the spawn prep),
     drain folded stacks onto the result stream, and the master's
     aggregate names the worker-side busy frame."""
-    fiber_tpu.init(profiler_hz=200.0, worker_lite=True)
+    fiber_tpu.init(profiler_hz=200.0)
     with fiber_tpu.Pool(2) as pool:
         pool.map(targets.spin_for, [0.08] * 16, chunksize=1)
         folded = pool.profiles()
@@ -450,8 +449,7 @@ def test_top_cli_renders_live_pool_with_chaos_anomaly(
 
     plan = _install_chaos(tmp_path, slow_worker_after_chunks=6,
                           slow_worker_s=1.0, slow_worker_times=2)
-    fiber_tpu.init(monitor_interval_s=0.1, anomaly_drop_pct=0.5,
-                   worker_lite=True)
+    fiber_tpu.init(monitor_interval_s=0.1, anomaly_drop_pct=0.5)
     hosts = f"127.0.0.1:{embedded_agent.port}"
     with fiber_tpu.Pool(2) as pool:
         xs = list(range(24))
@@ -575,7 +573,7 @@ def test_explain_compute_verdict_names_profile_frames(tmp_path, capsys):
 
 
 def test_pool_timeseries_surface():
-    fiber_tpu.init(monitor_interval_s=0.1, worker_lite=True)
+    fiber_tpu.init(monitor_interval_s=0.1)
     with fiber_tpu.Pool(2) as pool:
         xs = list(range(32))
         assert pool.map(targets.sleep_echo, xs, chunksize=2) == xs

@@ -92,7 +92,7 @@ def test_tiny_lm_flash_attention_parity():
     from fiber_tpu.models import TinyLM
 
     kwargs = dict(vocab=64, dim=32, heads=2, layers=1, max_seq=128)
-    lm_flash = TinyLM(attention="flash", **kwargs)
+    lm_flash = TinyLM(attention="flash", interpret=True, **kwargs)
     lm_ref = TinyLM(attention="reference", **kwargs)
     params = lm_flash.init(jax.random.PRNGKey(0))
     tokens = jax.random.randint(jax.random.PRNGKey(1), (128,), 0, 64)
@@ -175,7 +175,8 @@ def test_tiny_lm_multi_device_flash_trains():
 
     mesh = default_mesh()
     kwargs = dict(vocab=64, dim=32, heads=2, layers=1, max_seq=128)
-    lm_flash = TinyLM(attention="flash", mesh=mesh, **kwargs)
+    lm_flash = TinyLM(attention="flash", mesh=mesh, interpret=True,
+                      **kwargs)
     lm_ref = TinyLM(attention="reference", **kwargs)
     params = lm_flash.init(jax.random.PRNGKey(0))
     tokens = jax.random.randint(jax.random.PRNGKey(1), (128,), 0, 64)
@@ -284,7 +285,7 @@ def test_tiny_lm_gqa_trains_all_planes():
     tokens = jax.random.randint(jax.random.PRNGKey(1), (128,), 0, 64)
     l_ref = float(lm_ref.loss(params, tokens))
 
-    lm_flash = TinyLM(attention="flash", **kwargs)
+    lm_flash = TinyLM(attention="flash", interpret=True, **kwargs)
     assert abs(float(lm_flash.loss(params, tokens)) - l_ref) < 1e-4
 
     mesh = default_mesh()
@@ -311,7 +312,8 @@ def test_tiny_lm_gqa_multi_device_ring_flash():
     kwargs = dict(vocab=32, dim=32, heads=4, layers=1, max_seq=128,
                   kv_heads=2)
     lm_ref = TinyLM(attention="reference", **kwargs)
-    lm_rf = TinyLM(attention="flash", mesh=default_mesh(), **kwargs)
+    lm_rf = TinyLM(attention="flash", mesh=default_mesh(),
+                   interpret=True, **kwargs)
     params = lm_ref.init(jax.random.PRNGKey(0))
     tokens = jax.random.randint(jax.random.PRNGKey(1), (128,), 0, 32)
 
@@ -449,7 +451,8 @@ def test_ulysses_flash_local_exact():
     mesh = Mesh(np.asarray(devs), ("pool",))
     q, k, v = _rand_qkv(256, 2, 32)
     got = jax.device_get(ulysses_attention(
-        q, k, v, mesh=mesh, causal=True, local="flash"))
+        q, k, v, mesh=mesh, causal=True, local="flash",
+        interpret=True))
     want = jax.device_get(reference_attention(q, k, v, causal=True))
     assert np.abs(np.asarray(got) - np.asarray(want)).max() < 2e-5
 
@@ -609,7 +612,7 @@ def test_tiny_lm_rope_planes_and_decode():
     tokens = jax.random.randint(jax.random.PRNGKey(1), (64,), 0, 32)
 
     l_ref = float(lm_ref.loss(params, tokens))
-    lm_flash = TinyLM(attention="flash", **kwargs)
+    lm_flash = TinyLM(attention="flash", interpret=True, **kwargs)
     assert abs(float(lm_flash.loss(params, tokens)) - l_ref) < 1e-4
 
     # decode parity: incremental rope == full-apply rope
@@ -650,7 +653,8 @@ def test_tiny_lm_window_trains_and_decodes():
     from fiber_tpu.parallel import default_mesh
 
     model = TinyLM(vocab=32, dim=32, heads=4, layers=1, max_seq=64,
-                   attention="flash", window=8)  # < decoded length, so
+                   attention="flash", window=8,
+                   interpret=True)  # window < decoded length, so
     # late positions genuinely DROP early context in both paths
     params = model.init(jax.random.PRNGKey(0))
     tokens = jax.random.randint(jax.random.PRNGKey(1), (64,), 0, 32)
@@ -676,3 +680,36 @@ def test_tiny_lm_window_trains_and_decodes():
         TinyLM(attention="flash", window=16, mesh=default_mesh())
     with pytest.raises(ValueError, match="window"):
         TinyLM(attention="flash", window=0)
+
+
+def test_kernels_raise_off_tpu_instead_of_interpreting():
+    """Interpret mode is something a caller asks for, never something
+    the library picks: with the default (compile through Mosaic) the
+    kernels, the planes that compose them and the LM that trains through
+    them all refuse a CPU instead of quietly running the interpreter."""
+    from fiber_tpu.models import TinyLM
+    from fiber_tpu.ops.pallas_attention import flash_attention_lse
+    from fiber_tpu.ops.ring_attention import ring_attention
+    from fiber_tpu.ops.ulysses_attention import ulysses_attention
+    from fiber_tpu.parallel import default_mesh
+
+    q, k, v = _rand_qkv(128, 2, 16)
+    q8, k8, v8 = _rand_qkv(128, 8, 16)
+    for call in (
+        lambda: flash_attention(q, k, v, causal=True),
+        lambda: flash_attention(q, k, v, causal=True, interpret=False),
+        lambda: flash_attention_lse(q, k, v, causal=True),
+        lambda: ring_attention(q, k, v, causal=True, local="flash"),
+        lambda: ulysses_attention(q8, k8, v8, causal=True,
+                                  local="flash"),
+    ):
+        with pytest.raises(ValueError, match="[Ii]nterpret"):
+            jax.block_until_ready(call())
+
+    for mesh in (None, default_mesh()):
+        with pytest.raises(ValueError, match="needs a TPU.*interpret=True"):
+            TinyLM(attention="flash", mesh=mesh)
+    # the explicit request is honored, and recorded on the model
+    assert TinyLM(attention="flash", interpret=True).interpret is True
+    # planes with no kernel in them are unaffected
+    assert TinyLM(attention="ring").interpret is False

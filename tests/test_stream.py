@@ -282,7 +282,7 @@ def test_master_rss_stays_flat_across_big_result_stream():
         "import sys, resource, fiber_tpu\n"
         "from tests import targets\n"
         "n = int(sys.argv[1])\n"
-        "fiber_tpu.init(worker_lite=True, stream_window=4)\n"
+        "fiber_tpu.init(stream_window=4)\n"
         "with fiber_tpu.Pool(2) as pool:\n"
         "    k = 0\n"
         "    for v in pool.imap_unordered(targets.big_result,\n"
@@ -341,7 +341,7 @@ def test_speculation_fires_on_stream_chunk(tmp_path):
         slow_worker_times=1))
     try:
         fiber_tpu.init(stream_window=16, speculation_enabled=True,
-                       speculation_quantile=1.2, worker_lite=True)
+                       speculation_quantile=1.2)
         with fiber_tpu.Pool(2) as pool:
             out = list(pool.imap(targets.sleep_echo, _gen(40),
                                  chunksize=1))
@@ -450,7 +450,7 @@ def test_master_sigkill_mid_stream_then_cli_resume(tmp_path, capsys):
     script = (
         "import fiber_tpu\n"
         "from tests import targets\n"
-        "fiber_tpu.init(worker_lite=True, stream_window=8)\n"
+        "fiber_tpu.init(stream_window=8)\n"
         "def gen():\n"
         "    for i in range(96):\n"
         "        yield i\n"

@@ -9,11 +9,13 @@ full width of what the repo ships (depth and step counts are cut; the
 weights are random, made from a seed). The mesh is ``jax.devices()`` —
 all of them, one chip or four. Every phase checks what comes out against
 the repo's own reference and fails the run on its own; the last line of
-standard output is one JSON object,
+standard output is one JSON object with exactly these keys,
 
-    {"ok": true, "device": {"platform": "tpu", "kind": "...", "count": N}, ...}
+    {"ok": true, "device": {"platform": "tpu", "kind": "...", "count": N}}
 
-and the exit code is 0 only if every phase passed. With no accelerator
+(the line before it, ``summary: {...}``, carries the per-phase verdicts,
+the JAX version, the fiber backend and the compile-cache hits), and the
+exit code is 0 only if every phase passed. With no accelerator
 the default invocation prints why and exits 2 — it never falls back to
 the CPU. It checks correctness, not speed: the seconds it prints are
 set-up (first call: trace + compile + run) and run (later calls) times
@@ -22,7 +24,8 @@ for orientation, not measurements.
 ``--rehearse-cpu`` is an explicit request to run the same phases on the
 CPU so the script cannot rot between chip runs: tiny shapes, and the
 Pallas kernels in the interpreter because THIS SCRIPT asks for it
-(``interpret=True``); its JSON line says ``"rehearsal": true``.
+(``interpret=True``); its summary line says ``"rehearsal": true`` and
+its verdict names the CPU devices it ran on.
 """
 
 from __future__ import annotations
@@ -602,13 +605,14 @@ def _run(args) -> int:
     ok = all(v == "pass" for v in verdicts.values())
     print("phases: " + " ".join(f"{k}={v}" for k, v in verdicts.items())
           + f"; total {time.perf_counter() - t_start:.0f}s")
-    result = {"ok": ok, "device": device, "phases": verdicts,
-              "jax": jax.__version__, "backend": backend,
-              "compile_cache_hits":
-                  DEVICE.snapshot()["compile_cache_hits"]}
-    if rehearsal:
-        result["rehearsal"] = True
-    print(json.dumps(result), flush=True)
+    summary = {"phases": verdicts, "jax": jax.__version__,
+               "backend": backend, "rehearsal": rehearsal,
+               "compile_cache_hits":
+                   DEVICE.snapshot()["compile_cache_hits"]}
+    print("summary: " + json.dumps(summary))
+    # The verdict: the last line of stdout, these two keys and no other
+    # (the driver's contract); everything else is on the lines above.
+    print(json.dumps({"ok": ok, "device": device}), flush=True)
     return 0 if ok else 1
 
 

@@ -49,16 +49,22 @@ def test_smoke_cpu_rehearsal_end_to_end_and_second_run_hits_cache(
     for _ in range(2):
         proc = _python([SMOKE, "--rehearse-cpu"], env=env)
         assert proc.returncode == 0, proc.stdout + proc.stderr
-        verdicts.append(json.loads(proc.stdout.splitlines()[-1]))
-    first, second = verdicts
-    assert first["ok"] is True and first["rehearsal"] is True
-    assert first["device"] == {"platform": "cpu", "kind": "cpu",
-                               "count": 8}
-    assert set(first["phases"].values()) == {"pass"}
-    assert len(first["phases"]) == 7
-    assert first["compile_cache_hits"] == 0
-    assert second["ok"] is True
-    assert second["compile_cache_hits"] > 0
+        lines = proc.stdout.splitlines()
+        # The verdict is the LAST line and holds exactly "ok" and
+        # "device" (platform, kind, count) — the driver's contract; the
+        # rest is on the summary line before it.
+        assert lines[-2].startswith("summary: ")
+        verdicts.append((json.loads(lines[-1]),
+                         json.loads(lines[-2][len("summary: "):])))
+    (first, first_summary), (second, second_summary) = verdicts
+    assert first == second == {
+        "ok": True,
+        "device": {"platform": "cpu", "kind": "cpu", "count": 8}}
+    assert first_summary["rehearsal"] is True
+    assert set(first_summary["phases"].values()) == {"pass"}
+    assert len(first_summary["phases"]) == 7
+    assert first_summary["compile_cache_hits"] == 0
+    assert second_summary["compile_cache_hits"] > 0
 
 
 _CACHE_PROBE = (

@@ -55,7 +55,8 @@ def _survival_scan(step_fn, act_step_fn, state0, carry0, steps):
     def scan_step(carry, _):
         state, pc, done, total = carry
         new_pc, action = act_step_fn(pc, state)
-        next_state, terminated = step_fn(state, action)
+        with jax.named_scope("env.step"):
+            next_state, terminated = step_fn(state, action)
         reward = jnp.where(done, 0.0, 1.0)
         new_done = done | terminated
         # tree.map on BOTH freezes so pytree env states work the same
@@ -268,7 +269,8 @@ class Pendulum:
             state, total = carry
             torque = act_fn(flat_params, cls.obs(state))
             torque = jnp.reshape(torque, ())
-            new_state, reward = cls.step(state, torque)
+            with jax.named_scope("env.step"):
+                new_state, reward = cls.step(state, torque)
             return (new_state, total + reward), None
 
         (_, total), _ = jax.lax.scan(
@@ -328,13 +330,15 @@ class PixelChase:
 
         def scan_step(carry, _):
             agent, total = carry
-            obs = cls._render(agent, target)
+            with jax.named_scope("env.step"):
+                obs = cls._render(agent, target)
             action = act_fn(flat_params, obs)
-            agent = jnp.clip(
-                agent + moves[action], 0.0, float(cls.H - 1)
-            )
-            dist = jnp.sqrt(jnp.sum((agent - target) ** 2))
-            reward = -dist / cls.H
+            with jax.named_scope("env.step"):
+                agent = jnp.clip(
+                    agent + moves[action], 0.0, float(cls.H - 1)
+                )
+                dist = jnp.sqrt(jnp.sum((agent - target) ** 2))
+                reward = -dist / cls.H
             return (agent, total + reward), None
 
         (_, total), _ = jax.lax.scan(
@@ -385,29 +389,30 @@ class DeceptiveMaze:
         def scan_step(pos, _):
             obs = jnp.stack([pos[0], pos[1], gx - pos[0], gy - pos[1]])
             v = jnp.tanh(apply_fn(flat_params, obs)) * cls.SPEED
-            new = pos + v
-            # The wall blocks any step whose path crosses WALL_Y inside
-            # |x| <= WALL_HALF. The test point is the x where the
-            # segment intersects the wall plane (NOT the endpoint x —
-            # that would let diagonal steps cut the corner by up to
-            # SPEED). Park blocked steps just on the starting side.
-            dy = new[1] - pos[1]
-            t = jnp.where(jnp.abs(dy) > 1e-12,
-                          (cls.WALL_Y - pos[1]) / jnp.where(
-                              jnp.abs(dy) > 1e-12, dy, 1.0),
-                          2.0)  # parallel to wall: no crossing (t>1)
-            x_cross = pos[0] + t * (new[0] - pos[0])
-            crosses = (t >= 0.0) & (t <= 1.0) \
-                & (jnp.abs(x_cross) <= cls.WALL_HALF)
-            stop_y = jnp.where(pos[1] < cls.WALL_Y,
-                               cls.WALL_Y - 1e-3, cls.WALL_Y + 1e-3)
-            # Blocked steps park at the intersection point (x_cross,
-            # stop_y), not (new_x, stop_y): keeping the full lateral
-            # displacement would re-open the corner cut over two steps
-            # (advisor, round 2) — strict wall physics is what makes the
-            # maze deceptive for plain ES.
-            new_x = jnp.where(crosses, x_cross, new[0])
-            new_y = jnp.where(crosses, stop_y, new[1])
+            with jax.named_scope("env.step"):
+                new = pos + v
+                # The wall blocks any step whose path crosses WALL_Y inside
+                # |x| <= WALL_HALF. The test point is the x where the
+                # segment intersects the wall plane (NOT the endpoint x —
+                # that would let diagonal steps cut the corner by up to
+                # SPEED). Park blocked steps just on the starting side.
+                dy = new[1] - pos[1]
+                t = jnp.where(jnp.abs(dy) > 1e-12,
+                              (cls.WALL_Y - pos[1]) / jnp.where(
+                                  jnp.abs(dy) > 1e-12, dy, 1.0),
+                              2.0)  # parallel to wall: no crossing (t>1)
+                x_cross = pos[0] + t * (new[0] - pos[0])
+                crosses = (t >= 0.0) & (t <= 1.0) \
+                    & (jnp.abs(x_cross) <= cls.WALL_HALF)
+                stop_y = jnp.where(pos[1] < cls.WALL_Y,
+                                   cls.WALL_Y - 1e-3, cls.WALL_Y + 1e-3)
+                # Blocked steps park at the intersection point (x_cross,
+                # stop_y), not (new_x, stop_y): keeping the full lateral
+                # displacement would re-open the corner cut over two steps
+                # (advisor, round 2) — strict wall physics is what makes the
+                # maze deceptive for plain ES.
+                new_x = jnp.where(crosses, x_cross, new[0])
+                new_y = jnp.where(crosses, stop_y, new[1])
             return jnp.stack([new_x, new_y]), None
 
         pos, _ = jax.lax.scan(
@@ -492,11 +497,12 @@ class ParamHillWalker:
                 cls.slope(env_params, x + 1.0),
             ])
             action = act_fn(flat_params, obs)
-            force = (action.astype(jnp.float32) - 1.0) * cls.force_mag
-            acc = force - cls.gravity * cls.slope(env_params, x) \
-                - cls.friction * v
-            v = v + cls.dt * acc
-            x = x + cls.dt * v
+            with jax.named_scope("env.step"):
+                force = (action.astype(jnp.float32) - 1.0) * cls.force_mag
+                acc = force - cls.gravity * cls.slope(env_params, x) \
+                    - cls.friction * v
+                v = v + cls.dt * acc
+                x = x + cls.dt * v
             return (x, v), None
 
         (x, _v), _ = jax.lax.scan(
@@ -616,57 +622,59 @@ class ParamBipedWalker:
             state, done, best_x = carry
             x, y, vx, vy, phi, om, th1, th2, L1, L2 = state
 
-            obs = jnp.stack([
-                vx / 3.0, vy / 3.0, om, jnp.sin(phi), jnp.cos(phi),
-                th1, th2, L1, L2,
-                # previous-step contact proxies: current penetration
-                jnp.asarray(
-                    cls.height(env_params, x + L1 * jnp.sin(th1))
-                    >= y - L1 * jnp.cos(th1), jnp.float32),
-                jnp.asarray(
-                    cls.height(env_params, x + L2 * jnp.sin(th2))
-                    >= y - L2 * jnp.cos(th2), jnp.float32),
-                cls._slope(env_params, x + 0.3),
-                cls._slope(env_params, x + 0.8),
-                y - cls.height(env_params, x),
-            ])
+            with jax.named_scope("env.step"):
+                obs = jnp.stack([
+                    vx / 3.0, vy / 3.0, om, jnp.sin(phi), jnp.cos(phi),
+                    th1, th2, L1, L2,
+                    # previous-step contact proxies: current penetration
+                    jnp.asarray(
+                        cls.height(env_params, x + L1 * jnp.sin(th1))
+                        >= y - L1 * jnp.cos(th1), jnp.float32),
+                    jnp.asarray(
+                        cls.height(env_params, x + L2 * jnp.sin(th2))
+                        >= y - L2 * jnp.cos(th2), jnp.float32),
+                    cls._slope(env_params, x + 0.3),
+                    cls._slope(env_params, x + 0.8),
+                    y - cls.height(env_params, x),
+                ])
             action = act_fn(flat_params, obs)
-            bit = lambda k: 2.0 * jnp.asarray(
-                (action >> k) & 1, jnp.float32) - 1.0
-            dth1 = bit(3) * cls.hip_rate
-            dth2 = bit(2) * cls.hip_rate
-            dL1 = bit(1) * cls.len_rate
-            dL2 = bit(0) * cls.len_rate
+            with jax.named_scope("env.step"):
+                bit = lambda k: 2.0 * jnp.asarray(
+                    (action >> k) & 1, jnp.float32) - 1.0
+                dth1 = bit(3) * cls.hip_rate
+                dth2 = bit(2) * cls.hip_rate
+                dL1 = bit(1) * cls.len_rate
+                dL2 = bit(0) * cls.len_rate
 
-            f1x, f1y, t1, _c1 = leg_forces(x, y, vx, vy, th1, L1,
-                                           dth1, dL1, env_params)
-            f2x, f2y, t2, _c2 = leg_forces(x, y, vx, vy, th2, L2,
-                                           dth2, dL2, env_params)
+                f1x, f1y, t1, _c1 = leg_forces(x, y, vx, vy, th1, L1,
+                                               dth1, dL1, env_params)
+                f2x, f2y, t2, _c2 = leg_forces(x, y, vx, vy, th2, L2,
+                                               dth2, dL2, env_params)
 
-            ax = (f1x + f2x) / cls.mass
-            ay = (f1y + f2y) / cls.mass - cls.gravity
-            alpha = (t1 + t2) / cls.inertia - cls.omega_damp * om
+                ax = (f1x + f2x) / cls.mass
+                ay = (f1y + f2y) / cls.mass - cls.gravity
+                alpha = (t1 + t2) / cls.inertia - cls.omega_damp * om
 
-            nvx = vx + cls.dt * ax
-            nvy = vy + cls.dt * ay
-            nom = om + cls.dt * alpha
-            nx = x + cls.dt * nvx
-            ny = y + cls.dt * nvy
-            nphi = phi + cls.dt * nom
-            nth1 = jnp.clip(th1 + cls.dt * dth1, -cls.theta_lim,
-                            cls.theta_lim)
-            nth2 = jnp.clip(th2 + cls.dt * dth2, -cls.theta_lim,
-                            cls.theta_lim)
-            nL1 = jnp.clip(L1 + cls.dt * dL1, cls.len_low, cls.len_high)
-            nL2 = jnp.clip(L2 + cls.dt * dL2, cls.len_low, cls.len_high)
+                nvx = vx + cls.dt * ax
+                nvy = vy + cls.dt * ay
+                nom = om + cls.dt * alpha
+                nx = x + cls.dt * nvx
+                ny = y + cls.dt * nvy
+                nphi = phi + cls.dt * nom
+                nth1 = jnp.clip(th1 + cls.dt * dth1, -cls.theta_lim,
+                                cls.theta_lim)
+                nth2 = jnp.clip(th2 + cls.dt * dth2, -cls.theta_lim,
+                                cls.theta_lim)
+                nL1 = jnp.clip(L1 + cls.dt * dL1, cls.len_low, cls.len_high)
+                nL2 = jnp.clip(L2 + cls.dt * dL2, cls.len_low, cls.len_high)
 
-            new_state = jnp.stack([
-                nx, ny, nvx, nvy, nphi, nom, nth1, nth2, nL1, nL2,
-            ])
-            fell = ((ny - cls.height(env_params, nx) < 0.3)
-                    | (jnp.abs(nphi) > 1.2))
-            keep = jnp.where(done, state, new_state)
-            new_best = jnp.where(done, best_x, jnp.maximum(best_x, nx))
+                new_state = jnp.stack([
+                    nx, ny, nvx, nvy, nphi, nom, nth1, nth2, nL1, nL2,
+                ])
+                fell = ((ny - cls.height(env_params, nx) < 0.3)
+                        | (jnp.abs(nphi) > 1.2))
+                keep = jnp.where(done, state, new_state)
+                new_best = jnp.where(done, best_x, jnp.maximum(best_x, nx))
             return (keep, done | fell, new_best), None
 
         (_, _, best_x), _ = jax.lax.scan(

@@ -10,7 +10,21 @@ single 2-D array.
 
 from __future__ import annotations
 
+import functools
 from typing import Callable, Sequence, Tuple
+
+
+def _policy_apply_scope(method):
+    """Trace ``method`` under ``jax.named_scope("policy.apply")``: every
+    op it makes says so in a profile (``es.rollout/policy.apply/...``),
+    and nothing else changes."""
+    @functools.wraps(method)
+    def scoped(*args, **kwargs):
+        import jax
+
+        with jax.named_scope("policy.apply"):
+            return method(*args, **kwargs)
+    return scoped
 
 
 def _compute_dtype(explicit):
@@ -64,6 +78,7 @@ class MLPPolicy:
             parts.append(b)
         return jnp.concatenate(parts)
 
+    @_policy_apply_scope
     def apply(self, flat_params, obs):
         """Logits for one observation; jittable / vmappable."""
         import jax.numpy as jnp
@@ -135,6 +150,7 @@ class ConvPolicy:
             parts.append(jnp.zeros((shape[-1],)))
         return jnp.concatenate(parts)
 
+    @_policy_apply_scope
     def apply(self, flat_params, obs):
         import jax
         import jax.numpy as jnp
@@ -235,6 +251,7 @@ class GRUPolicy:
             offset += n
         return out
 
+    @_policy_apply_scope
     def step(self, flat_params, carry, obs):
         """(carry', logits) for one step; jittable/vmappable."""
         import jax

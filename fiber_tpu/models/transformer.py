@@ -277,37 +277,49 @@ class TinyLM:
         """Post-attention residual + MLP (shared like _project_qkv)."""
         import jax
 
-        x = x + attn_flat @ blk["wo"]
-        h = self._rms(x, blk["norm2"])
-        return x + jax.nn.gelu(h @ blk["w1"] + blk["b1"]) @ blk["w2"] \
-            + blk["b2"]
+        with jax.named_scope("lm.attn"), jax.named_scope("out"):
+            x = x + attn_flat @ blk["wo"]
+        with jax.named_scope("lm.mlp"):
+            h = self._rms(x, blk["norm2"])
+            return x + jax.nn.gelu(h @ blk["w1"] + blk["b1"]) \
+                @ blk["w2"] + blk["b2"]
 
     def apply(self, params, tokens):
-        """tokens (max_seq,) int -> logits (max_seq, vocab)."""
+        """tokens (max_seq,) int -> logits (max_seq, vocab).
+
+        The named scopes (``lm.embed``, ``lm.attn`` with ``qkv``,
+        ``kernel``, ``out``, ``lm.mlp``, ``lm.head_loss``) are metadata:
+        every op's ``op_name`` in a profile starts with its phase."""
+        import jax
         import jax.numpy as jnp
 
         S, H, Dh = self.max_seq, self.heads, self.head_dim
         KVH = self.kv_heads
-        x = params["embed"][tokens]                          # (S, dim)
-        rope = None
-        if self.pos == "learned":
-            x = x + params["pos"]
-        else:
-            cos, sin = self._rope_angles(jnp.arange(S), Dh)  # (S, dh/2)
-            rope = (cos[:, None, :], sin[:, None, :])
+        with jax.named_scope("lm.embed"):
+            x = params["embed"][tokens]                      # (S, dim)
+            rope = None
+            if self.pos == "learned":
+                x = x + params["pos"]
+            else:
+                cos, sin = self._rope_angles(jnp.arange(S), Dh)
+                rope = (cos[:, None, :], sin[:, None, :])    # (S, 1, dh/2)
         for blk in params["blocks"]:
-            h = self._rms(x, blk["norm1"])
-            q, k, v = self._project_qkv(blk, h)
-            q = q.reshape(S, H, Dh)
-            k = k.reshape(S, KVH, Dh)
-            v = v.reshape(S, KVH, Dh)
-            if rope is not None:
-                q = self._rope_rotate(q, *rope)
-                k = self._rope_rotate(k, *rope)
-            attn = self._attend(q, k, v).reshape(S, -1)
+            with jax.named_scope("lm.attn"):
+                with jax.named_scope("qkv"):
+                    h = self._rms(x, blk["norm1"])
+                    q, k, v = self._project_qkv(blk, h)
+                    q = q.reshape(S, H, Dh)
+                    k = k.reshape(S, KVH, Dh)
+                    v = v.reshape(S, KVH, Dh)
+                    if rope is not None:
+                        q = self._rope_rotate(q, *rope)
+                        k = self._rope_rotate(k, *rope)
+                with jax.named_scope("kernel"):
+                    attn = self._attend(q, k, v).reshape(S, -1)
             x = self._block_tail(blk, x, attn)
-        x = self._rms(x, params["final_norm"])
-        return x @ params["out"]
+        with jax.named_scope("lm.head_loss"):
+            x = self._rms(x, params["final_norm"])
+            return x @ params["out"]
 
     def loss(self, params, tokens):
         """Mean next-token cross-entropy over positions 0..S-2."""
@@ -315,10 +327,11 @@ class TinyLM:
         import jax.numpy as jnp
 
         logits = self.apply(params, tokens)[:-1]             # (S-1, V)
-        targets = tokens[1:]
-        logp = jax.nn.log_softmax(logits, axis=-1)
-        return -jnp.mean(
-            jnp.take_along_axis(logp, targets[:, None], axis=1))
+        with jax.named_scope("lm.head_loss"):
+            targets = tokens[1:]
+            logp = jax.nn.log_softmax(logits, axis=-1)
+            return -jnp.mean(
+                jnp.take_along_axis(logp, targets[:, None], axis=1))
 
     # ------------------------------------------------------------------
     # Inference: autoregressive decode with per-layer KV caches.
@@ -448,9 +461,19 @@ def make_train_step(model: TinyLM, optimizer, batched: bool = False):
     ``optimizer`` is any optax-style (init, update) pair. With
     ``batched=True`` tokens is (B, max_seq) and the loss is the batch
     mean — the batch axis vmaps straight over the sequence-sharded
-    attention (each sequence still spans the mesh)."""
+    attention (each sequence still spans the mesh).
+
+    What comes back is a plain function around the jitted step: each
+    call is one ``lm.train_step`` span (docs/observability.md; compile
+    spans hang from it, device idle time is charged to it) and moves
+    the ``device_steps`` / ``device_step_units`` counters by one call
+    and the argument's token count. It keeps the jitted step's
+    ``lower`` and ``__name__``."""
+    import math
+
     import jax
 
+    from fiber_tpu.telemetry import device as device_telemetry
     from fiber_tpu.utils.jaxcompat import ensure_compile_cache
 
     ensure_compile_cache()
@@ -465,15 +488,29 @@ def make_train_step(model: TinyLM, optimizer, batched: bool = False):
 
     def step(params, opt_state, tokens):
         loss, grads = jax.value_and_grad(loss_fn)(params, tokens)
-        updates, opt_state = optimizer.update(grads, opt_state, params)
-        # Plain tree-map instead of optax.apply_updates: the optimizer
-        # only needs the (init, update) protocol — no hard optax
-        # dependency in the library (it isn't in install_requires).
-        params = jax.tree_util.tree_map(
-            lambda p, u: p + u, params, updates)
+        with jax.named_scope("lm.optimizer"):
+            updates, opt_state = optimizer.update(
+                grads, opt_state, params)
+            # Plain tree-map instead of optax.apply_updates: the
+            # optimizer only needs the (init, update) protocol — no hard
+            # optax dependency in the library (it isn't in
+            # install_requires).
+            params = jax.tree_util.tree_map(
+                lambda p, u: p + u, params, updates)
         return params, opt_state, loss
 
     jitted = jax.jit(step)
+
+    def spanned(call):
+        def run(params, opt_state, tokens):
+            n = math.prod(tokens.shape)
+            with device_telemetry.step("lm.train_step", n, tokens=n):
+                return call(params, opt_state, tokens)
+
+        run.__name__ = call.__name__
+        run.lower = jitted.lower
+        return run
+
     if _needs_cpu_collective_serialization(model):
         # XLA CPU's in-process collectives can DEADLOCK when jax's
         # async dispatch interleaves two step-generations over the CPU
@@ -488,8 +525,8 @@ def make_train_step(model: TinyLM, optimizer, batched: bool = False):
             jax.block_until_ready(out)
             return out
 
-        return step_sync
-    return jitted
+        return spanned(step_sync)
+    return spanned(jitted)
 
 
 def _needs_cpu_collective_serialization(model) -> bool:

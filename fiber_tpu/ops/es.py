@@ -81,25 +81,33 @@ def build_fused_runner(device_step, mesh, n_state: int,
 
 class _FusedRunMixin:
     """run_fused() for the state-tuple families. Requires
-    ``self._device_step_fn`` (raw per-device step), ``self.mesh``, and
-    the ``step``/``run`` contract ``state = tuple`` (or NamedTuple).
-    Compiled runners are cached per generation count."""
+    ``self._device_step_fn`` (raw per-device step), ``self.mesh``,
+    ``self.pop_size`` and the ``step``/``run`` contract ``state = tuple``
+    (or NamedTuple). Compiled runners are cached per generation count.
+    The runner's call is the ``es.run_fused`` span
+    (docs/observability.md): compile spans hang from it."""
 
     def run_fused(self, state, key, generations: int):
         """Run N generations as one XLA program. Returns
         (state, stats_seq (generations, k)) — same trajectory as N
         ``step`` calls with the per-generation key splits."""
+        from fiber_tpu.telemetry import device as device_telemetry
+
         cache = getattr(self, "_fused_runner_cache", None)
         if cache is None:
             cache = self._fused_runner_cache = {}
         fn = cache.get(generations)
-        if fn is None:
+        built = fn is None
+        if built:
             fn = build_fused_runner(
                 self._device_step_fn, self.mesh, len(tuple(state)),
                 generations,
             )
             cache[generations] = fn
-        out = fn(*tuple(state), key)
+        with device_telemetry.step(
+                "es.run_fused", generations * self.pop_size,
+                generations=generations, pop=self.pop_size, built=built):
+            out = fn(*tuple(state), key)
         new_state, stats_seq = out[:-1], out[-1]
         if hasattr(type(state), "_make"):  # NamedTuple states
             new_state = type(state)._make(new_state)
@@ -208,33 +216,41 @@ class EvolutionStrategy(_FusedRunMixin):
             # params (dim,) replicated; key replicated. In sgd mode the
             # (m, v, t) slots are zero-size placeholders (see step()) so
             # no dead state rides the jitted program.
+            # The named scopes are metadata (every op's op_name in a
+            # profile starts with its phase); they add no operation.
             my = jax.lax.axis_index("pool")
-            dev_key = jax.random.fold_in(key, my)
-            eps_key, eval_key = jax.random.split(dev_key)
+            with jax.named_scope("es.perturb"):
+                dev_key = jax.random.fold_in(key, my)
+                eps_key, eval_key = jax.random.split(dev_key)
 
-            eps = jax.random.normal(eps_key, (pairs, dim))
-            thetas = jnp.concatenate(
-                [params + sigma * eps, params - sigma * eps], axis=0
-            )  # (2*pairs, dim)
-            eval_keys = jax.random.split(eval_key, 2 * pairs)
-            fitness = jax.vmap(eval_fn)(thetas, eval_keys)  # (2*pairs,)
+                eps = jax.random.normal(eps_key, (pairs, dim))
+                thetas = jnp.concatenate(
+                    [params + sigma * eps, params - sigma * eps], axis=0
+                )  # (2*pairs, dim)
+            with jax.named_scope("es.rollout"):
+                eval_keys = jax.random.split(eval_key, 2 * pairs)
+                fitness = jax.vmap(eval_fn)(thetas, eval_keys)  # (2*pairs,)
 
             # Global rank shaping: gather all fitness (tiny), rank
             # identically on every device.
-            all_fit = jax.lax.all_gather(fitness, "pool")  # (ndev, 2*pairs)
-            flat_fit = all_fit.reshape(-1)
-            ranks = centered_rank(flat_fit).reshape(all_fit.shape)
-            my_ranks = ranks[my]                       # (2*pairs,)
-            w = my_ranks[:pairs] - my_ranks[pairs:]    # antithetic weights
+            with jax.named_scope("es.rank"):
+                # (ndev, 2*pairs)
+                all_fit = jax.lax.all_gather(fitness, "pool")
+                flat_fit = all_fit.reshape(-1)
+                ranks = centered_rank(flat_fit).reshape(all_fit.shape)
+                my_ranks = ranks[my]                       # (2*pairs,)
+                w = my_ranks[:pairs] - my_ranks[pairs:]    # antithetic weights
 
-            g_local = w @ eps                          # (dim,) on the MXU
-            grad = jax.lax.psum(g_local, "pool") / (pop * sigma)
+            with jax.named_scope("es.gradient"):
+                g_local = w @ eps                          # (dim,) on the MXU
+                grad = jax.lax.psum(g_local, "pool") / (pop * sigma)
             # Optimizer state is replicated like params; the update
             # math is the shared apply_es_update (one copy, also used
             # by AskTellES).
-            new_params, m_new, v_new, t_new = apply_es_update(
-                params, grad, m, v, t, lr=lr, wd=wd, adam=adam,
-            )
+            with jax.named_scope("es.update"):
+                new_params, m_new, v_new, t_new = apply_es_update(
+                    params, grad, m, v, t, lr=lr, wd=wd, adam=adam,
+                )
             stats = jnp.stack([
                 flat_fit.mean(),
                 flat_fit.max(),

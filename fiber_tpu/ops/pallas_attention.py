@@ -393,6 +393,7 @@ def _build_calls(shape, dtype, causal, block_q, block_kv, interpret,
             pltpu.VMEM((bq, d), jnp.float32),    # numerator acc
         ],
         interpret=interpret,
+        name="flash_attn_fwd",
     )
 
     dq_call = pl.pallas_call(
@@ -406,6 +407,7 @@ def _build_calls(shape, dtype, causal, block_q, block_kv, interpret,
         out_shape=jax.ShapeDtypeStruct((h, s, d), dtype),
         scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
         interpret=interpret,
+        name="flash_attn_dq",
     )
 
     # dkv grid is (kv_heads, n_kv, group, n_q): program ids land as
@@ -430,6 +432,7 @@ def _build_calls(shape, dtype, causal, block_q, block_kv, interpret,
         scratch_shapes=[pltpu.VMEM((bk, d), jnp.float32),
                         pltpu.VMEM((bk, d), jnp.float32)],
         interpret=interpret,
+        name="flash_attn_dkv",
     )
     return fwd_call, dq_call, dkv_call
 

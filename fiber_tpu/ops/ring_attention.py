@@ -201,15 +201,17 @@ def _kv_rotate(k_cur, v_cur, *, axis: str, n_dev: int,
     in the Pallas interpreter; False compiles it (TPU only)."""
     import jax
 
-    if use_dma_ring:
-        from fiber_tpu.ops.dma_ring import ring_exchange
+    with jax.named_scope("ring.rotate"):
+        if use_dma_ring:
+            from fiber_tpu.ops.dma_ring import ring_exchange
 
-        k_cur, v_cur = ring_exchange(
-            (k_cur, v_cur), axis=axis, n_dev=n_dev, interpret=interpret)
-        return k_cur, v_cur
-    perm = [(i, (i + 1) % n_dev) for i in range(n_dev)]
-    return (jax.lax.ppermute(k_cur, axis, perm),
-            jax.lax.ppermute(v_cur, axis, perm))
+            k_cur, v_cur = ring_exchange(
+                (k_cur, v_cur), axis=axis, n_dev=n_dev,
+                interpret=interpret)
+            return k_cur, v_cur
+        perm = [(i, (i + 1) % n_dev) for i in range(n_dev)]
+        return (jax.lax.ppermute(k_cur, axis, perm),
+                jax.lax.ppermute(v_cur, axis, perm))
 
 
 def _ring_flash_local(q_blk, k_blk, v_blk, *, axis: str, n_dev: int,
@@ -250,6 +252,7 @@ def _ring_flash_local(q_blk, k_blk, v_blk, *, axis: str, n_dev: int,
         return (jnp.zeros(q_blk.shape, jnp.float32),
                 jnp.full((h, sq), -1e30, jnp.float32))
 
+    @jax.named_scope("ring.block")
     def one_rotation(k_cur, v_cur, src):
         if not causal:
             return full_block(k_cur, v_cur)
@@ -270,7 +273,8 @@ def _ring_flash_local(q_blk, k_blk, v_blk, *, axis: str, n_dev: int,
                                   interpret=interpret)
         src = (src - 1) % n_dev
         o2, lse2 = one_rotation(k_cur, v_cur, src)
-        o, lse = _merge_partials(o, lse, o2, lse2)
+        with jax.named_scope("ring.merge"):
+            o, lse = _merge_partials(o, lse, o2, lse2)
         return (k_cur, v_cur, src, o, lse), None
 
     if n_dev > 1:
@@ -340,6 +344,7 @@ def ring_attention_local(q_blk, k_blk, v_blk, *, axis: str,
     # tokens on one chip needs tens of GB for scores. Differentiable
     # and exact: the chunk loop is the same online-softmax recurrence
     # the ring itself uses.
+    @jax.named_scope("ring.block")
     def accumulate(k_cur, v_cur, src_dev, m, l, o):
         return _accumulate_block(q_blk, q_pos, k_cur, v_cur,
                                  src_dev * k_cur.shape[0], m, l, o,

@@ -2835,28 +2835,18 @@ class Pool:
         ``xla_dir=`` names its log directory, or a
         ``utils.profiling.trace`` region ran in this process (the
         device plane notes the newest capture) — its device ops merge
-        in beside the host spans on the dual clock
+        in beside the host spans, aligned on the spans both hold
         (docs/observability.md "Unified timeline"). Returns ``path``."""
         from fiber_tpu.telemetry import export
         from fiber_tpu.telemetry.device import DEVICE
 
         spans = tracing.SPANS.snapshot()
-        wall_start = None
         if xla_dir is None:
-            noted = DEVICE.last_xla_trace()
-            if noted is not None:
-                cand_dir, cand_wall, _mono = noted
-                # Auto-merge only a capture that OVERLAPS this dump's
-                # span window: a profiling.trace region from minutes
-                # ago must not glue stale device ops onto an unrelated
-                # map's timeline (an explicit xla_dir= always merges).
-                t0 = min((float(sp.get("ts", 0.0)) for sp in spans),
-                         default=None)
-                if t0 is None or cand_wall >= t0 - 60.0:
-                    xla_dir, wall_start = cand_dir, cand_wall
-        return export.write_chrome_trace(path, spans,
-                                         xla_dir=xla_dir,
-                                         xla_wall_start=wall_start)
+            # the merge aligns on spans both sides hold, so a capture
+            # that shares none with this dump (a profiling.trace region
+            # from minutes ago) merges nothing
+            xla_dir = DEVICE.last_xla_trace()
+        return export.write_chrome_trace(path, spans, xla_dir=xla_dir)
 
     def flight_dump(self, path: str) -> str:
         """Write this process's flight-recorder buffer (pool submits and

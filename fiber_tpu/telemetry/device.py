@@ -17,7 +17,15 @@ indistinguishable from slow compute, MFU only computed inside
   listeners count compiles, compile seconds and persistent-cache hits,
   and a fingerprint-keyed recompile detector feeds the watchdog's
   ``recompile_storm`` rule: the SAME logical function compiling over
-  and over is shape churn, not progress.
+  and over is shape churn, not progress. The time-span listener stores
+  each phase (``jax.trace``, ``jax.lower``, ``jax.backend_compile``) as
+  a span under the ambient one, with JAX's ``fun_name`` and, on the
+  backend phase, whether the persistent cache hit — so a compile
+  inside call k hangs from call k's span and says which function it
+  was. A trace inside another phase is folded into that phase's span.
+* **Step calls** — :func:`step` is the span and the two counters
+  (``device_steps``, ``device_step_units``) around one call of a
+  device-plane step function (``es.run_fused``, ``lm.train_step``).
 * **Device gauges** — per-process HBM ``memory_stats()``
   (bytes_in_use / limit; honestly ``None`` on CPU),
   live-array count/bytes, pushed into the registry each monitor tick
@@ -51,7 +59,7 @@ import os
 import sys
 import threading
 import time
-from typing import Any, Dict, Iterator, Optional, Tuple
+from typing import Any, Dict, Iterator, Optional
 
 from fiber_tpu import telemetry
 from fiber_tpu.telemetry import tracing
@@ -72,6 +80,12 @@ _m_compiles = telemetry.counter(
     "device_compiles", "XLA compilations observed in this process")
 _m_compile_seconds = telemetry.counter(
     "device_compile_seconds", "XLA compilation seconds in this process")
+_m_steps = telemetry.counter(
+    "device_steps", "Calls of a device-plane step function, by fn")
+_m_step_units = telemetry.counter(
+    "device_step_units",
+    "Work those calls asked for, by fn (ES: generations x population; "
+    "LM: tokens)")
 _g_hbm_in_use = telemetry.gauge(
     "device_hbm_bytes_in_use", "HBM bytes in use on the first local device")
 _g_hbm_limit = telemetry.gauge(
@@ -83,6 +97,14 @@ _g_live_array_bytes = telemetry.gauge(
 _g_map_mfu = telemetry.gauge(
     "pool_map_mfu",
     "MFU of the last device map whose device peak resolved")
+
+
+#: JAX's compile phases (jax/_src/dispatch.py) -> span names
+_COMPILE_SPANS = {
+    "/jax/core/compile/jaxpr_trace_duration": "jax.trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "jax.lower",
+    "/jax/core/compile/backend_compile_duration": "jax.backend_compile",
+}
 
 
 class DeviceTelemetry:
@@ -106,6 +128,11 @@ class DeviceTelemetry:
         self.storm_count = 4
         self.storm_window_s = 30.0
         self._listeners_installed = False
+        self._install_lock = threading.Lock()
+        # per compiling thread: the last persistent-cache outcome
+        # (``cache``, consumed by the backend-compile span that
+        # encloses it) and the compile phases in progress (``phases``)
+        self._compiling = threading.local()
         # last live-MFU observation (None values are honest nulls)
         self._mfu: Dict[str, Any] = {
             "mfu": None, "flops_per_sec": None, "peak_row": None,
@@ -118,7 +145,7 @@ class DeviceTelemetry:
             "count": None, "bytes": None}
         # last XLA profiler capture (utils/profiling.trace notes it so
         # trace_dump can merge the device timeline without being told)
-        self._xla_trace: Optional[Tuple[str, float, float]] = None
+        self._xla_trace: Optional[str] = None
 
     # -- transfer accounting -------------------------------------------
     @contextlib.contextmanager
@@ -188,12 +215,25 @@ class DeviceTelemetry:
             return True
         if "jax" not in sys.modules:
             return False
-        from jax import monitoring
+        # The sampler thread's probe and the main thread both come
+        # here: registered twice, every compile would count twice.
+        with self._install_lock:
+            if self._listeners_installed:
+                return True
+            from jax import monitoring
 
-        monitoring.register_event_listener(self._on_jax_event)
-        monitoring.register_event_duration_secs_listener(
-            self._on_jax_duration)
-        self._listeners_installed = True
+            monitoring.register_event_listener(self._on_jax_event)
+            monitoring.register_event_duration_secs_listener(
+                self._on_jax_duration)
+            monitoring.register_event_time_span_listener(
+                self._on_jax_time_span)
+            monitoring.register_scalar_listener(self._on_jax_scalar)
+            self._listeners_installed = True
+        # jax is fully imported by now, its exit hooks registered: the
+        # sampler's (whose probes call into jax) goes in after them
+        from fiber_tpu.telemetry.timeseries import stop_at_exit
+
+        stop_at_exit()
         return True
 
     def _on_jax_event(self, event: str, **kwargs: Any) -> None:
@@ -204,11 +244,13 @@ class DeviceTelemetry:
         # fingerprint-keyed storm detector (every program's miss would
         # otherwise look like ONE function recompiling).
         if event == "/jax/compilation_cache/cache_hits":
+            self._compiling.cache = "hit"
             with self._lock:
                 self._cache_hits += 1
             return
         if event != "/jax/compilation_cache/cache_misses":
             return
+        self._compiling.cache = "miss"
         if not self.enabled:
             return
         with self._lock:
@@ -232,6 +274,47 @@ class DeviceTelemetry:
         from fiber_tpu.telemetry.accounting import COSTS
 
         COSTS.bill_ambient(compile_s=float(duration))
+
+    def _on_jax_scalar(self, event: str, value: float,
+                       **kwargs: Any) -> None:
+        # JAX announces the start of a compile phase as a scalar (its
+        # start time): the phases in progress on this thread, each with
+        # the count and the seconds of the traces folded into it.
+        if event in _COMPILE_SPANS:
+            phases = getattr(self._compiling, "phases", None)
+            if phases is None:
+                phases = self._compiling.phases = []
+            phases.append([0, 0.0])
+
+    def _on_jax_time_span(self, event: str, start: float, end: float,
+                          **kwargs: Any) -> None:
+        # The same three events as _on_jax_duration, with their epoch
+        # start and end and JAX's name of the function: one span each,
+        # a child of whatever span the compiling thread is inside.
+        # Traces nest: every jitted function met while another is
+        # traced is traced in turn (a thousand small ones under one
+        # train step), and lowering a Pallas kernel traces hundreds
+        # more. A trace that ends inside another phase is folded into
+        # that phase's ``nested`` / ``nested_s`` (compile_seconds counts
+        # it twice: for itself and inside the outer one's duration).
+        name = _COMPILE_SPANS.get(event)
+        if name is None:
+            return
+        phases = getattr(self._compiling, "phases", None)
+        nested, nested_s = phases.pop() if phases else (0, 0.0)
+        if not self.enabled:
+            return
+        if name == "jax.trace" and phases:
+            phases[-1][0] += 1 + nested
+            phases[-1][1] += (end - start) + nested_s
+            return
+        attrs = {"fun_name": str(kwargs.get("fun_name", ""))}
+        if nested:
+            attrs.update(nested=nested, nested_s=nested_s)
+        if name == "jax.backend_compile":
+            attrs["cache"] = getattr(self._compiling, "cache", None)
+            self._compiling.cache = None
+        tracing.record(name, int(start * 1e9), int(end * 1e9), **attrs)
 
     def note_compile(self, fingerprint: str) -> None:
         """One compilation (or compile-cache miss) of the logical
@@ -329,17 +412,15 @@ class DeviceTelemetry:
         return value
 
     # -- unified timeline ----------------------------------------------
-    def note_xla_trace(self, log_dir: str, wall_start: float,
-                       mono_start: float) -> None:
+    def note_xla_trace(self, log_dir: str) -> None:
         """utils/profiling.trace records where the XLA profiler wrote
-        its capture (and the wall clock at trace start), so
-        ``Pool.trace_dump`` can merge the device timeline beside the
-        host spans without being told the directory."""
+        its capture, so ``Pool.trace_dump`` can merge the device
+        timeline beside the host spans without being told the
+        directory."""
         with self._lock:
-            self._xla_trace = (str(log_dir), float(wall_start),
-                               float(mono_start))
+            self._xla_trace = str(log_dir)
 
-    def last_xla_trace(self) -> Optional[Tuple[str, float, float]]:
+    def last_xla_trace(self) -> Optional[str]:
         with self._lock:
             return self._xla_trace
 
@@ -482,6 +563,18 @@ DEVICE = DeviceTelemetry()
 def transfer(site: str, nbytes: int = 0):
     """Module-level convenience: ``with device.transfer("dmap", n): …``"""
     return DEVICE.transfer(site, nbytes)
+
+
+@contextlib.contextmanager
+def step(fn: str, units: int, **attrs) -> Iterator[Optional[Dict]]:
+    """One call of the device-plane step function ``fn``: the span that
+    device idle time is charged to and that compile spans hang from,
+    and the operator's counters of calls made and ``units`` of work
+    asked for. Host-side only; touches no device array."""
+    with tracing.span(fn, **attrs) as sp:
+        yield sp
+    _m_steps.inc(fn=fn)
+    _m_step_units.inc(units, fn=fn)
 
 
 def snapshot() -> Dict[str, Any]:

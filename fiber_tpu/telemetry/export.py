@@ -50,7 +50,8 @@ def chrome_trace(spans: List[Dict]) -> Dict:
         events.append({
             "name": str(sp.get("name", "span")),
             "ph": "X",
-            "ts": float(sp.get("ts", 0.0)) * 1e6,
+            "ts": (sp["start_ns"] / 1e3 if "start_ns" in sp
+                   else float(sp.get("ts", 0.0)) * 1e6),
             "dur": max(float(sp.get("dur", 0.0)), 1e-7) * 1e6,
             "pid": pid,
             "tid": tid,
@@ -66,15 +67,14 @@ def chrome_trace(spans: List[Dict]) -> Dict:
 
 
 def write_chrome_trace(path: str, spans: List[Dict],
-                       xla_dir: Optional[str] = None,
-                       xla_wall_start: Optional[float] = None) -> str:
+                       xla_dir: Optional[str] = None) -> str:
     """Write spans as Chrome trace JSON; with ``xla_dir`` the newest
     XLA profiler capture under it (``jax.profiler.trace`` output) is
     merged in so device ops render beside the host spans — the unified
     timeline (docs/observability.md "Device telemetry")."""
     doc = chrome_trace(spans)
     if xla_dir:
-        merge_xla_trace(doc, xla_dir, wall_start=xla_wall_start)
+        merge_xla_trace(doc, xla_dir)
     with open(path, "w") as fh:
         json.dump(doc, fh)
     return path
@@ -123,19 +123,19 @@ def load_xla_chrome_trace(path: str) -> Optional[Dict]:
     return doc
 
 
-def merge_xla_trace(doc: Dict, log_dir: str,
-                    wall_start: Optional[float] = None) -> int:
+def merge_xla_trace(doc: Dict, log_dir: str) -> int:
     """Merge the newest XLA capture under ``log_dir`` into a host
     Chrome-trace ``doc`` (chrome_trace output), in place. Host spans
-    carry wall-epoch timestamps; XLA events carry the profiler's own
-    µs origin — with ``wall_start`` (the wall clock when the capture
-    began, noted by ``utils.profiling.trace``) the device events are
-    rebased onto the wall axis so both planes line up on the dual
-    clock; without it they are rebased to the host trace's start.
-    Device pids are offset past the host rows (and their process_name
-    metadata prefixed ``XLA``) so Perfetto renders separate device
-    lanes. Returns the number of device events merged (0 = no capture
-    found; never raises)."""
+    carry epoch timestamps; XLA events count from the start of the
+    profiler's session. ``tracing.span`` writes every span made during
+    a capture into the capture too, under its span id, so the two
+    clocks are aligned on the spans both sides hold (the median of the
+    differences of their starts); a capture that shares no span with
+    ``doc`` cannot be placed and is left out. Device pids are offset
+    past the host rows (and their process_name metadata prefixed
+    ``XLA``) so Perfetto renders separate device lanes. Returns the
+    number of device events merged (0 = no capture found, or none that
+    shares a span; never raises)."""
     try:
         path = find_xla_chrome_trace(log_dir)
         if path is None:
@@ -147,13 +147,18 @@ def merge_xla_trace(doc: Dict, log_dir: str,
         pid_base = max((int(e.get("pid", 0)) for e in host_events),
                        default=0) + 1000
         xla_events = xla.get("traceEvents", [])
-        timed = [float(e["ts"]) for e in xla_events if "ts" in e]
-        xla_t0 = min(timed) if timed else 0.0
-        if wall_start is None:
-            wall_start = min(
-                (float(e["ts"]) / 1e6 for e in host_events
-                 if e.get("ph") == "X"), default=0.0)
-        offset_us = float(wall_start) * 1e6 - xla_t0
+        host_start = {e["args"]["span"]: float(e["ts"])
+                      for e in host_events
+                      if e.get("ph") == "X" and "span" in e.get("args", {})}
+        offsets = sorted(
+            host_start[e["args"]["span"]] - float(e["ts"])
+            for e in xla_events
+            if "ts" in e and (e.get("args") or {}).get("span") in host_start)
+        if not offsets:
+            logger.warning("telemetry: the XLA capture under %s shares no "
+                           "span with this dump; not merged", log_dir)
+            return 0
+        offset_us = offsets[len(offsets) // 2]
         merged = 0
         for ev in xla_events:
             if "ph" not in ev:

@@ -29,11 +29,13 @@ Design constraints, mirrored from the rest of the plane:
 
 from __future__ import annotations
 
+import atexit
 import threading
 import time
 import weakref
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
+from fiber_tpu.telemetry import tracing
 from fiber_tpu.utils.logging import get_logger
 
 logger = get_logger()
@@ -160,11 +162,7 @@ class MonitorSampler:
         self._interval = interval
         if not restart and bool(enabled) == self.enabled:
             return
-        # Stop whatever thread is running (it checks `enabled` and its
-        # private wake event after every wait).
-        self.enabled = False
-        self._wake.set()
-        self._thread = None
+        self.stop()
         if bool(enabled):
             self.enabled = True
             self._wake = threading.Event()
@@ -172,6 +170,17 @@ class MonitorSampler:
                 target=self._loop, args=(self._wake, interval),
                 name="fiber-monitor-sampler", daemon=True)
             self._thread.start()
+
+    def stop(self, join_s: float = 0.0) -> None:
+        """Stop whatever thread is running (it checks ``enabled`` and
+        its private wake event after every wait); with ``join_s`` also
+        wait that long for it to finish its tick."""
+        self.enabled = False
+        self._wake.set()
+        thread, self._thread = self._thread, None
+        if (join_s > 0 and thread is not None
+                and thread is not threading.current_thread()):
+            thread.join(join_s)
 
     def add_probe(self, probe: Callable[[], None]) -> None:
         """Register a callable run before every sample (pools push
@@ -272,7 +281,10 @@ class MonitorSampler:
             if not self.enabled or wake is not self._wake:
                 return
             try:
-                self.sample_once()
+                # One span per pass: when a tick ran and how long it
+                # took, next to the step calls it may have intruded on.
+                with tracing.span("monitor.tick"):
+                    self.sample_once()
             except Exception:  # noqa: BLE001 - keep sampling
                 logger.exception("monitor: sample failed")
 
@@ -308,6 +320,23 @@ class MonitorSampler:
 #: Process-wide sampler (knobs follow ``monitor_*`` via
 #: telemetry.refresh()).
 TIMESERIES = MonitorSampler()
+
+
+
+def stop_at_exit() -> None:
+    """(Re-)register the exit hook that stops the sampler and waits for
+    its thread. The sampler's probes call into JAX (live arrays, memory
+    stats), and a daemon thread that the interpreter's finalization
+    catches inside JAX aborts the process ("FATAL: exception not
+    rethrown", rc -6). Exit hooks run last-in first-out and jax
+    registers its own (it clears the backends) when it is imported, so
+    the device plane calls this again once it sees jax: the sampler
+    stops before the backends go."""
+    atexit.unregister(TIMESERIES.stop)
+    atexit.register(TIMESERIES.stop, join_s=10.0)
+
+
+stop_at_exit()
 
 
 # ---------------------------------------------------------------------------

@@ -16,7 +16,16 @@ Spans are plain dicts (picklable, JSON-able)::
 
     {"name": "worker.execute", "trace": "6fa1…", "span": "03bc…",
      "parent": "9d2e…" | None, "ts": <epoch s>, "dur": <s>,
+     "start_ns": <epoch ns>, "end_ns": <epoch ns>,
      "host": "<hostname>", "pid": <os pid>, ...attrs}
+
+:func:`span` is the ONE way to time a region. In a process that has
+loaded jax it also enters a ``jax.profiler.TraceAnnotation`` of the same
+name (carrying the span id), so during any profiler capture the span
+sits in the capture's host plane on the device ops' own clock, and
+``export.merge_xla_trace`` aligns the two timelines on the spans both
+sides hold. This module never imports jax itself: lite workers and host
+agents must not pay for it.
 """
 
 from __future__ import annotations
@@ -25,6 +34,7 @@ import collections
 import contextlib
 import os
 import socket
+import sys
 import threading
 import time
 import uuid
@@ -128,20 +138,24 @@ def trace_context(trace_id: str,
         stack.pop()
 
 
-@contextlib.contextmanager
-def span(name: str, trace: Optional[str] = None,
-         parent: Optional[str] = None, store: Optional[SpanStore] = None,
-         **attrs) -> Iterator[Optional[Dict]]:
-    """Record one timed span into the process span store (no-op when
-    telemetry is disabled — yields None). Trace/parent default to the
-    ambient context; with neither, the span roots a fresh trace.
-    Yields the span dict so callers can read ``span["span"]`` to use as
-    the parent id for propagated work."""
-    from fiber_tpu import telemetry
+_annotation = None
+_NO_ANNOTATION = contextlib.nullcontext()
 
-    if not telemetry.tracing_active():
-        yield None
-        return
+
+def _trace_annotation():
+    """``jax.profiler.TraceAnnotation`` once this process has loaded
+    jax, else None. Looked up in ``sys.modules``, never imported."""
+    global _annotation
+    if _annotation is None:
+        profiler = getattr(sys.modules.get("jax"), "profiler", None)
+        _annotation = getattr(profiler, "TraceAnnotation", None)
+    return _annotation
+
+
+def _new_span(name: str, trace: Optional[str], parent: Optional[str],
+              attrs: Dict) -> Dict:
+    """A span dict under the ambient context (or rooting a fresh
+    trace), not yet timed or stored."""
     if trace is None:
         ctx = current()
         if ctx is not None:
@@ -155,21 +169,65 @@ def span(name: str, trace: Optional[str] = None,
         "trace": trace,
         "span": new_id(),
         "parent": parent,
-        "ts": time.time(),
+        "ts": 0.0,
         "dur": 0.0,
+        "start_ns": 0,
+        "end_ns": 0,
         "host": host_id(),
         "pid": os.getpid(),
     }
     if attrs:
         sp.update(attrs)
-    t0 = time.perf_counter()
+    return sp
+
+
+@contextlib.contextmanager
+def span(name: str, trace: Optional[str] = None,
+         parent: Optional[str] = None, store: Optional[SpanStore] = None,
+         **attrs) -> Iterator[Optional[Dict]]:
+    """Record one timed span into the process span store (no-op when
+    telemetry is disabled — yields None). Trace/parent default to the
+    ambient context; with neither, the span roots a fresh trace.
+    Yields the span dict so callers can read ``span["span"]`` to use as
+    the parent id for propagated work. Start and end are epoch
+    nanoseconds (``time.time_ns``), ``dur`` is the monotonic clock's."""
+    from fiber_tpu import telemetry
+
+    if not telemetry.tracing_active():
+        yield None
+        return
+    sp = _new_span(name, trace, parent, attrs)
+    annotation = _trace_annotation()
     stack = getattr(_tls, "stack", None)
     if stack is None:
         stack = _tls.stack = []
-    stack.append((trace, sp["span"]))
+    stack.append((sp["trace"], sp["span"]))
+    sp["start_ns"] = time.time_ns()
+    sp["ts"] = sp["start_ns"] / 1e9
+    t0 = time.perf_counter()
     try:
-        yield sp
+        with (annotation(name, span=sp["span"]) if annotation is not None
+              else _NO_ANNOTATION):
+            yield sp
     finally:
-        stack.pop()
         sp["dur"] = time.perf_counter() - t0
+        sp["end_ns"] = time.time_ns()
+        stack.pop()
         (store or SPANS).add(sp)
+
+
+def record(name: str, start_ns: int, end_ns: int,
+           **attrs) -> Optional[Dict]:
+    """Store a region that already ended and was timed by someone else
+    (JAX's compile events report their own start and end), as a child
+    of the ambient span. None when telemetry is disabled."""
+    from fiber_tpu import telemetry
+
+    if not telemetry.tracing_active():
+        return None
+    sp = _new_span(name, None, None, attrs)
+    sp["start_ns"], sp["end_ns"] = int(start_ns), int(end_ns)
+    sp["ts"] = sp["start_ns"] / 1e9
+    sp["dur"] = (sp["end_ns"] - sp["start_ns"]) / 1e9
+    SPANS.add(sp)
+    return sp

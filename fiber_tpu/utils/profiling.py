@@ -7,10 +7,9 @@ equivalent — this is a gap, not a port target"). fiber_tpu provides:
 * ``trace(path)`` — context manager wrapping ``jax.profiler.trace`` so a
   device-plane region (ES generations, device_map calls) produces a
   TensorBoard-loadable XLA trace;
-* ``annotate(name)`` — ``jax.profiler.TraceAnnotation`` passthrough for
-  labelling host-side regions inside a trace; the same region is also
-  recorded as a fiber_tpu telemetry span, so XLA profiler regions and
-  cluster task traces line up in one timeline (docs/observability.md);
+* ``annotate`` — the telemetry plane's ``tracing.span`` under its old
+  name: the one span primitive already writes every span into an active
+  capture (docs/observability.md "Unified timeline");
 * ``Timer`` / ``timed`` — lightweight host-plane timing with aggregated
   stats. The process-wide ``global_timer`` mirrors every section into
   the telemetry registry's ``timer_seconds`` histogram (label:
@@ -27,42 +26,30 @@ import time
 from collections import defaultdict
 from typing import Dict, Iterator, Optional
 
+from fiber_tpu.telemetry.tracing import span as annotate  # noqa: F401
+
 
 @contextlib.contextmanager
 def trace(log_dir: str) -> Iterator[None]:
     """Capture an XLA/host trace of the enclosed region into ``log_dir``
-    (view with TensorBoard's profile plugin). The capture location and
-    the wall clock at trace start are noted with the device telemetry
-    plane, so a later ``Pool.trace_dump`` merges the XLA device
-    timeline beside the host spans on the dual clock
+    (view with TensorBoard's profile plugin). The whole region is one
+    ``xla.capture`` span, held by the span store and by the capture
+    alike, and the capture's location is noted with the device
+    telemetry plane: a later ``Pool.trace_dump`` merges the device
+    timeline beside the host spans, aligned on the spans both hold
     (docs/observability.md "Unified timeline")."""
     import jax
 
-    wall0, mono0 = time.time(), time.monotonic()
+    from fiber_tpu.telemetry import tracing
+    from fiber_tpu.telemetry.device import DEVICE
+
     jax.profiler.start_trace(log_dir)
     try:
-        yield
+        with tracing.span("xla.capture", log_dir=str(log_dir)):
+            yield
     finally:
         jax.profiler.stop_trace()
-        try:
-            from fiber_tpu.telemetry.device import DEVICE
-
-            DEVICE.note_xla_trace(log_dir, wall0, mono0)
-        except Exception:  # noqa: BLE001 - accounting must not fail traces
-            pass
-
-
-@contextlib.contextmanager
-def annotate(name: str) -> Iterator[None]:
-    """Label a region inside an active XLA trace AND record it as a
-    telemetry span (joining the ambient trace context if one is set)."""
-    import jax
-
-    from fiber_tpu.telemetry import tracing as _tracing
-
-    with jax.profiler.TraceAnnotation(name):
-        with _tracing.span(name, kind="jax.annotation"):
-            yield
+        DEVICE.note_xla_trace(log_dir)
 
 
 class Timer:

@@ -1,0 +1,12 @@
+"""jax_backend_compile_s: seconds of set-up that JAX spent in the backend:
+XLA compiling or, on a warm run, reading and loading the cached executable.
+The sum of the program's ``jax.backend_compile`` spans (one per
+``/jax/core/compile/*`` event, stored by ``fiber_tpu.telemetry.device`` with
+JAX's own start, end and ``fun_name``) that ended before the window's first
+call span began. The three phases together are what ``compile_s`` lumps. No
+such span: nothing."""
+import program_spans
+
+
+def read(run):
+    return program_spans.setup_seconds(run, "jax.backend_compile")

@@ -1543,3 +1543,150 @@ def test_train_step_serializes_on_cpu_mesh():
     single = TinyLM(vocab=16, dim=32, heads=4, layers=1, max_seq=16,
                     attention="reference")
     assert not _needs_cpu_collective_serialization(single)
+
+
+# ---------------------------------------------------------------------------
+# the device plane under the span primitive (docs/observability.md
+# "Device-plane spans"): step calls, counters, named phases
+# ---------------------------------------------------------------------------
+
+
+def _counter(name, fn):
+    from fiber_tpu import telemetry
+
+    return telemetry.counter(name).value(fn=fn)
+
+
+@pytest.mark.parametrize("attention,name", [
+    ("reference", "step"),        # the jitted step itself
+    ("ring", "step_sync"),        # serialized on the CPU mesh
+])
+def test_train_step_keeps_lower_and_name_under_its_span(attention, name):
+    """What make_train_step returns is a function around the jitted
+    step on both paths: it keeps ``__name__`` and ``lower`` (the
+    benchmark and chip_smoke lower the step from shapes), and a call is
+    one ``lm.train_step`` span with the argument's token count, one
+    ``device_steps`` and that many ``device_step_units``."""
+    import jax
+    import jax.numpy as jnp
+    import optax
+
+    from fiber_tpu.models import TinyLM, make_train_step
+    from fiber_tpu.telemetry import tracing
+
+    fiber_tpu.init()
+    model = TinyLM(vocab=16, dim=32, heads=4, layers=1, max_seq=16,
+                   attention=attention)
+    opt = optax.adamw(1e-3)
+    step = make_train_step(model, opt)
+    assert step.__name__ == name
+    params = model.init(jax.random.PRNGKey(0))
+    state = opt.init(params)
+    tokens = jnp.arange(16, dtype=jnp.int32) % 16
+    lowered = step.lower(params, state, tokens)
+    assert "lm.optimizer" in lowered.as_text(debug_info=True)
+    calls0 = _counter("device_steps", "lm.train_step")
+    units0 = _counter("device_step_units", "lm.train_step")
+    tracing.SPANS.clear()
+    params, state, loss = step(params, state, tokens)
+    assert np.isfinite(float(loss))
+    (span,) = [s for s in tracing.SPANS.snapshot()
+               if s["name"] == "lm.train_step"]
+    assert span["tokens"] == 16 and span["parent"] is None
+    assert _counter("device_steps", "lm.train_step") == calls0 + 1
+    assert _counter("device_step_units", "lm.train_step") == units0 + 16
+
+
+def test_run_fused_span_and_counters():
+    """``run_fused`` is one ``es.run_fused`` span a call, saying how
+    much work was asked for and whether the runner was built in this
+    call; the counters move by calls and generations x population."""
+    import jax
+
+    from fiber_tpu.telemetry import tracing
+
+    fiber_tpu.init()
+    policy = MLPPolicy(CartPole.obs_dim, CartPole.act_dim, hidden=(8,))
+    es = EvolutionStrategy(
+        lambda p, k: CartPole.rollout(policy.act, p, k, max_steps=20),
+        dim=policy.dim, pop_size=16)
+    params = policy.init(jax.random.PRNGKey(0))
+    calls0 = _counter("device_steps", "es.run_fused")
+    units0 = _counter("device_step_units", "es.run_fused")
+    tracing.SPANS.clear()
+    for i in range(2):
+        params, _ = es.run_fused(params, jax.random.PRNGKey(i), 3)
+    spans = [s for s in tracing.SPANS.snapshot()
+             if s["name"] == "es.run_fused"]
+    assert [(s["generations"], s["pop"], s["built"]) for s in spans] \
+        == [(3, 16, True), (3, 16, False)]
+    assert _counter("device_steps", "es.run_fused") == calls0 + 2
+    assert _counter("device_step_units", "es.run_fused") \
+        == units0 + 2 * 3 * 16
+
+
+def _lowered_es_step():
+    import jax
+    import jax.numpy as jnp
+
+    from fiber_tpu.models import ParamBipedWalker
+
+    policy = MLPPolicy(ParamBipedWalker.obs_dim, ParamBipedWalker.act_dim,
+                       hidden=(8,))
+    course = jnp.zeros((len(ParamBipedWalker.PARAM_LOW),), jnp.float32)
+    es = EvolutionStrategy(
+        lambda p, k: ParamBipedWalker.rollout_p(
+            policy.act, course, p, k, 10),
+        dim=policy.dim, pop_size=16, optimizer="adam")
+    vec = jnp.zeros((policy.dim,))
+    return es._step.lower(vec, vec, vec, jnp.asarray(0.0),
+                          jax.random.PRNGKey(0))
+
+
+def _lowered_lm_step(attention, **kw):
+    import jax
+    import jax.numpy as jnp
+    import optax
+
+    from fiber_tpu.models import TinyLM, make_train_step
+
+    model = TinyLM(vocab=16, dim=32, heads=4, kv_heads=2, layers=1,
+                   max_seq=64, pos="rope", attention=attention, **kw)
+    opt = optax.adamw(1e-3)
+    params = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    state = jax.eval_shape(opt.init, params)
+    return make_train_step(model, opt).lower(
+        params, state, jax.ShapeDtypeStruct((64,), jnp.int32))
+
+
+_LM_SCOPES = ("lm.embed", "lm.attn", "/qkv/", "/kernel/", "/out/",
+              "lm.mlp", "lm.head_loss", "lm.optimizer")
+
+
+@pytest.mark.parametrize("lower,scopes", [
+    (_lowered_es_step,
+     ("es.perturb", "es.rollout", "policy.apply", "env.step", "es.rank",
+      "es.gradient", "es.update")),
+    (lambda: _lowered_lm_step("flash", interpret=True, window=32,
+                              mesh=_one_device_mesh()),
+     _LM_SCOPES + ("flash_attn_fwd", "flash_attn_dq", "flash_attn_dkv")),
+    (lambda: _lowered_lm_step("flash", interpret=True,
+                              mesh=default_mesh()),
+     _LM_SCOPES + ("ring.rotate", "ring.block", "ring.merge",
+                   "flash_attn_fwd")),
+], ids=["es_step", "flash_lm_step", "ring_lm_step"])
+def test_phase_scopes_reach_the_lowered_program(lower, scopes):
+    """The phases of the two programs are ``jax.named_scope``s and the
+    Pallas kernels carry names: metadata that reaches every op's
+    ``op_name`` (what a profile keeps per event) and adds no operation.
+    CPU, kernels in the interpreter."""
+    text = lower().as_text(debug_info=True)
+    missing = [s for s in scopes if s not in text]
+    assert not missing, missing
+
+
+def _one_device_mesh():
+    import jax
+    from jax.sharding import Mesh
+
+    return Mesh(np.asarray(jax.devices()[:1]), ("pool",))

@@ -374,9 +374,12 @@ def test_live_mfu_gauge_when_peak_resolves(monkeypatch):
 # ---------------------------------------------------------------------------
 
 
-def _write_fake_xla_capture(root) -> str:
+def _write_fake_xla_capture(root, shared=None) -> str:
     """A capture shaped like jax.profiler.trace output: Chrome trace
-    JSON gzipped under plugins/profile/<run>/."""
+    JSON gzipped under plugins/profile/<run>/. ``shared`` is a span of
+    the host's that the capture holds too, as ``tracing.span`` writes
+    it: same name, the span id in ``args``, on the capture's own clock
+    (here: 150 us after its first device op)."""
     run = os.path.join(str(root), "plugins", "profile", "run1")
     os.makedirs(run)
     doc = {"traceEvents": [
@@ -387,6 +390,10 @@ def _write_fake_xla_capture(root) -> str:
         {"ph": "X", "name": "copy.2", "pid": 1, "tid": 1,
          "ts": 200.0, "dur": 10.0},
     ]}
+    if shared is not None:
+        doc["traceEvents"].append(
+            {"ph": "X", "name": shared["name"], "pid": 2, "tid": 7,
+             "ts": 250.0, "dur": 5.0, "args": {"span": shared["span"]}})
     with gzip.open(os.path.join(run, "host.trace.json.gz"), "wt") as fh:
         json.dump(doc, fh)
     return str(root)
@@ -394,10 +401,13 @@ def _write_fake_xla_capture(root) -> str:
 
 def test_trace_dump_merges_xla_capture(tmp_path):
     """The unified timeline: trace_dump writes ONE valid Chrome trace
-    holding host spans AND the XLA capture's device ops, rebased onto
-    the wall axis and on distinct process rows."""
+    holding host spans AND the XLA capture's device ops, placed on the
+    epoch's axis by the span both sides hold, on distinct process
+    rows."""
     fiber_tpu.init()
-    xla_dir = _write_fake_xla_capture(tmp_path / "xla")
+    with tracing.span("xla.capture") as shared:
+        pass
+    xla_dir = _write_fake_xla_capture(tmp_path / "xla", shared)
     with fiber_tpu.Pool(2) as pool:
         xs = list(range(8))
         assert pool.map(targets.sleep_echo, xs, chunksize=2) == xs
@@ -412,8 +422,11 @@ def test_trace_dump_merges_xla_capture(tmp_path):
                    if e.get("name") == "worker.execute")
     dev_ev = next(e for e in doc["traceEvents"]
                   if e.get("name") == "fusion.1")
-    # device events rebased onto the host wall axis (same epoch scale)
+    # device events placed on the epoch's axis by the shared span: the
+    # capture's fusion.1 began 150 us before the capture's copy of it
     assert abs(dev_ev["ts"] - host_ev["ts"]) < 600 * 1e6
+    assert dev_ev["ts"] == pytest.approx(
+        shared["start_ns"] / 1e3 - 150.0, abs=1.0)
     assert dev_ev["pid"] != host_ev["pid"]    # separate lanes
     metas = [e["args"]["name"] for e in doc["traceEvents"]
              if e.get("ph") == "M" and e.get("name") == "process_name"]
@@ -422,12 +435,12 @@ def test_trace_dump_merges_xla_capture(tmp_path):
 
 def test_trace_dump_uses_noted_capture_and_survives_missing(tmp_path):
     fiber_tpu.init()
-    with tracing.span("pool.serialize", trace="t9", seq=9):
+    with tracing.span("pool.serialize", trace="t9", seq=9) as shared:
         pass
     # a noted capture directory with NO trace files: merge is a no-op,
     # the host dump still writes
-    DEVICE.note_xla_trace(str(tmp_path / "empty"), time.time(),
-                          time.monotonic())
+    DEVICE.note_xla_trace(str(tmp_path / "empty"))
+    assert DEVICE.last_xla_trace() == str(tmp_path / "empty")
     from fiber_tpu.telemetry import export
 
     out = export.write_chrome_trace(
@@ -437,10 +450,171 @@ def test_trace_dump_uses_noted_capture_and_survives_missing(tmp_path):
         doc = json.load(fh)
     assert any(e.get("name") == "pool.serialize"
                for e in doc["traceEvents"])
-    # and with a real capture, the noted dir merges without being told
-    xla_dir = _write_fake_xla_capture(tmp_path / "xla2")
-    assert export.merge_xla_trace(doc, xla_dir,
-                                  wall_start=time.time()) == 3
+    # a capture that holds a span of the dump merges whole ...
+    xla_dir = _write_fake_xla_capture(tmp_path / "xla2", shared)
+    assert export.merge_xla_trace(doc, xla_dir) == 4
+    # ... and one that shares none cannot be placed: nothing is guessed
+    stale = _write_fake_xla_capture(tmp_path / "xla3")
+    assert export.merge_xla_trace(doc, stale) == 0
+
+
+def test_real_capture_holds_the_span_and_merges_on_it(tmp_path):
+    """End to end with the real profiler: ``utils.profiling.trace``
+    makes the whole capture one ``xla.capture`` span, which the span
+    store and the capture both hold (same name, the span id beside it),
+    so the merge needs no guess at the clocks."""
+    import jax.numpy as jnp
+
+    from fiber_tpu.telemetry import export
+    from fiber_tpu.utils.profiling import trace
+
+    fiber_tpu.init()
+    tracing.SPANS.clear()
+    out = str(tmp_path / "capture")
+    with trace(out):
+        jnp.arange(64.0).sum().block_until_ready()
+    spans = tracing.SPANS.snapshot()
+    (shared,) = [s for s in spans if s["name"] == "xla.capture"]
+    assert DEVICE.last_xla_trace() == out
+    xla = export.load_xla_chrome_trace(export.find_xla_chrome_trace(out))
+    theirs = [e for e in xla["traceEvents"]
+              if e.get("name") == "xla.capture"]
+    assert [e["args"]["span"] for e in theirs] == [shared["span"]]
+    doc = export.chrome_trace(spans)
+    assert export.merge_xla_trace(doc, out) > 0
+    # the capture's copy of the span lands where the host's own is (the
+    # two clocks are read microseconds apart, and a sampler tick may be
+    # a second shared span)
+    placed = [e for e in doc["traceEvents"]
+              if e.get("name") == "xla.capture" and e["pid"] >= 1000]
+    assert placed[0]["ts"] == pytest.approx(
+        shared["start_ns"] / 1e3, abs=500.0)
+
+
+def _phase_spans(parent=None):
+    return [s for s in tracing.SPANS.snapshot()
+            if s["name"] in ("jax.trace", "jax.lower",
+                             "jax.backend_compile")
+            and (parent is None or s["parent"] == parent)]
+
+
+def test_compile_spans_carry_fun_name_phase_and_cache():
+    """Each of JAX's three compile phases becomes a span with JAX's own
+    start and end, the function's name, and on the backend phase
+    whether the persistent cache was hit (a cleared in-memory cache and
+    the same program again) or missed."""
+    import jax
+
+    fiber_tpu.init()
+    assert DEVICE.install_listeners()
+    old = (jax.config.jax_persistent_cache_min_compile_time_secs,
+           jax.config.jax_persistent_cache_min_entry_size_bytes)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    try:
+        def unit_compile_probe(x):
+            return (x * 5.0 + 2.0).sum()
+
+        seen = []
+        for _ in range(2):
+            tracing.SPANS.clear()
+            jax.clear_caches()
+            jax.jit(unit_compile_probe)(
+                np.arange(11.0)).block_until_ready()
+            seen.append([s for s in _phase_spans()
+                         if "unit_compile_probe" in s["fun_name"]])
+    finally:
+        jax.config.update(
+            "jax_persistent_cache_min_compile_time_secs", old[0])
+        jax.config.update(
+            "jax_persistent_cache_min_entry_size_bytes", old[1])
+    for spans, outcome in zip(seen, ("miss", "hit")):
+        assert {s["name"] for s in spans} == {
+            "jax.trace", "jax.lower", "jax.backend_compile"}
+        for s in spans:
+            assert isinstance(s["start_ns"], int)
+            assert s["end_ns"] >= s["start_ns"] > 1e18   # the epoch's
+            assert s["dur"] == pytest.approx(
+                (s["end_ns"] - s["start_ns"]) / 1e9)
+            if s["name"] == "jax.backend_compile":
+                assert s["cache"] == outcome
+            else:
+                assert "cache" not in s
+
+
+def test_traces_inside_a_trace_fold_into_the_outermost_span():
+    """Every jitted function met while another is traced is traced in
+    turn (a thousand under one train step): one ``jax.trace`` span for
+    the outermost, with the count and the seconds of those inside it,
+    which ``compile_seconds`` counts a second time."""
+    import jax
+    import jax.numpy as jnp
+
+    fiber_tpu.init()
+    assert DEVICE.install_listeners()
+
+    @jax.jit
+    def unit_inner(x):
+        return jnp.sin(x) * 2.0
+
+    def unit_outer(x):
+        return unit_inner(x) + unit_inner(x + 1.0).sum()
+
+    tracing.SPANS.clear()
+    before = DEVICE.snapshot()["compile_seconds"]
+    jax.jit(unit_outer)(np.arange(5.0)).block_until_ready()
+    spent = DEVICE.snapshot()["compile_seconds"] - before
+    spans = _phase_spans()
+    assert not [s for s in spans if "unit_inner" in s["fun_name"]]
+    outer = [s for s in spans if s["name"] == "jax.trace"
+             and s["fun_name"] == "unit_outer"]
+    assert outer and all(s["nested"] >= 1 for s in outer)
+    assert all(0 < s["nested_s"] < s["dur"] for s in outer)
+    # per listening instance: spans + nested seconds == compile_seconds
+    mine = sum(s["dur"] + s.get("nested_s", 0.0) for s in spans)
+    assert mine / (len(outer) or 1) == pytest.approx(spent, rel=0.05)
+
+
+def test_new_shape_compiles_under_that_calls_span():
+    """Which call recompiled: the compile spans are children of the
+    ambient span, so a call with a new shape shows its three phases
+    under ITS ``lm.train_step`` span, and a call with a known shape
+    shows none."""
+    import jax
+    import jax.numpy as jnp
+    import optax
+
+    from fiber_tpu.models import TinyLM, make_train_step
+
+    fiber_tpu.init()
+    assert DEVICE.install_listeners()
+    model = TinyLM(vocab=16, dim=16, heads=2, layers=1, max_seq=8,
+                   attention="reference")
+    opt = optax.sgd(1e-2)
+    step = make_train_step(model, opt, batched=True)
+    params = model.init(jax.random.PRNGKey(0))
+    state = opt.init(params)
+    tracing.SPANS.clear()
+    for batch in (1, 1, 2):
+        params, state, loss = step(
+            params, state, jnp.zeros((batch, 8), jnp.int32))
+    loss.block_until_ready()
+    calls = [s for s in tracing.SPANS.snapshot()
+             if s["name"] == "lm.train_step"]
+    assert [c["tokens"] for c in calls] == [8, 8, 16]
+    first, second, third = (_phase_spans(c["span"]) for c in calls)
+    assert second == []
+    for children, call in ((first, calls[0]), (third, calls[2])):
+        # JAX names the function "step" while tracing it and
+        # "jit(step)" while lowering and compiling it
+        own = [s for s in children
+               if s["fun_name"] in ("step", "jit(step)")]
+        assert {s["name"] for s in own} == {
+            "jax.trace", "jax.lower", "jax.backend_compile"}
+        assert all(s["trace"] == call["trace"] for s in children)
+        assert all(call["start_ns"] <= s["start_ns"]
+                   and s["end_ns"] <= call["end_ns"] + 1_000_000
+                   for s in own)
 
 
 # ---------------------------------------------------------------------------

@@ -126,6 +126,75 @@ def test_monitor_knobs_follow_refresh():
     assert TIMESERIES.samples >= 3  # the thread ticks on its own
 
 
+def test_each_sampler_pass_is_a_monitor_tick_span():
+    """The sampler thread's passes leave ``monitor.tick`` spans: when a
+    tick ran and how long it took, on the same store as the step calls
+    it may have intruded on."""
+    from fiber_tpu.telemetry import tracing
+
+    tracing.SPANS.clear()
+    fiber_tpu.init(monitor_interval_s=0.02)
+    deadline = time.monotonic() + 5.0
+    ticks = []
+    while len(ticks) < 3 and time.monotonic() < deadline:
+        time.sleep(0.02)
+        ticks = [s for s in tracing.SPANS.snapshot()
+                 if s["name"] == "monitor.tick"]
+    assert len(ticks) >= 3
+    assert all(t["end_ns"] > t["start_ns"] and t["dur"] > 0
+               for t in ticks)
+    assert all(t["pid"] == os.getpid() for t in ticks)
+    # a pass somebody asks for by hand is not the thread's
+    fiber_tpu.init(monitor_enabled=False)
+    tracing.SPANS.clear()
+    TIMESERIES.sample_once()
+    assert tracing.SPANS.snapshot() == []
+
+
+_EXIT_PROBE = r"""
+import atexit, sys, threading
+sys.path.insert(0, {repo!r})
+
+def report():   # registered first, so it runs after every other hook
+    alive = [t.name for t in threading.enumerate()
+             if t.name == "fiber-monitor-sampler" and t.is_alive()]
+    print("SAMPLER_ALIVE_AT_EXIT", alive, flush=True)
+
+atexit.register(report)
+import fiber_tpu.telemetry
+from fiber_tpu.telemetry import TIMESERIES
+import jax, jax.numpy as jnp
+from fiber_tpu.telemetry.device import DEVICE
+DEVICE.install_listeners()
+# ticks back to back: the process ends while the thread is inside JAX
+# (live arrays, memory stats)
+TIMESERIES.configure(enabled=True, interval=0.02, capacity=16)
+keep = [jnp.float32(i) for i in range(2000)]
+while TIMESERIES.samples < 5:
+    pass
+print("SAMPLES", TIMESERIES.samples, flush=True)
+"""
+
+
+def test_sampler_thread_is_stopped_and_joined_at_exit():
+    """A process that imports ``fiber_tpu.telemetry`` and ends while its
+    sampler thread is inside JAX exits 0: the plane's own ``atexit``
+    hook stops the thread and waits for it before the interpreter is
+    finalized (a daemon thread caught there inside JAX aborts the
+    process, rc -6)."""
+    import subprocess
+    import sys
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    for _ in range(3):
+        proc = subprocess.run(
+            [sys.executable, "-c", _EXIT_PROBE.format(repo=repo)],
+            capture_output=True, text=True, timeout=180, cwd=repo)
+        assert proc.returncode == 0, proc.stdout + proc.stderr[-2000:]
+        assert "SAMPLES" in proc.stdout
+        assert "SAMPLER_ALIVE_AT_EXIT []" in proc.stdout, proc.stdout
+
+
 def test_sampler_derives_rates_from_counters():
     # Thread off: the test drives the ticks so the newest two points
     # deterministically straddle a counter increment.

@@ -119,6 +119,114 @@ def test_prometheus_exposition_renders_and_parses():
     assert samples["fiber_lat_count"] == 1
 
 
+def test_span_records_epoch_nanoseconds_and_record_stores_a_finished_one():
+    """A span carries its start and end as integer nanoseconds of the
+    epoch beside ``ts``/``dur``, so it can be laid against JAX's own
+    epoch-stamped events; ``record`` stores a region someone else timed
+    as a child of the ambient span."""
+    t0 = time.time_ns()
+    with tracing.span("unit.timed", k=1) as sp:
+        time.sleep(0.002)
+        inner = tracing.record("unit.finished", t0 + 5, t0 + 1_000_005,
+                               fun_name="f")
+    (stored,) = [s for s in tracing.SPANS.snapshot()
+                 if s["name"] == "unit.timed"]
+    assert stored is sp and sp["k"] == 1
+    assert isinstance(sp["start_ns"], int) and isinstance(sp["end_ns"], int)
+    assert t0 <= sp["start_ns"] < sp["end_ns"] <= time.time_ns()
+    assert sp["ts"] == pytest.approx(sp["start_ns"] / 1e9)
+    assert sp["end_ns"] - sp["start_ns"] >= 2_000_000
+    assert sp["dur"] == pytest.approx(
+        (sp["end_ns"] - sp["start_ns"]) / 1e9, abs=1e-3)
+    assert inner["parent"] == sp["span"] and inner["trace"] == sp["trace"]
+    assert (inner["start_ns"], inner["end_ns"]) == (t0 + 5, t0 + 1_000_005)
+    assert inner["dur"] == pytest.approx(1e-3) and inner["fun_name"] == "f"
+    assert inner in tracing.SPANS.snapshot()
+    # Chrome export places it by the nanoseconds
+    doc = export.chrome_trace([inner])
+    event = next(e for e in doc["traceEvents"] if e["ph"] == "X")
+    assert event["ts"] == pytest.approx((t0 + 5) / 1e3)
+    # telemetry off: nothing is recorded
+    fiber_tpu.init(telemetry_enabled=False)
+    assert tracing.record("unit.finished", t0, t0 + 1) is None
+
+
+def test_span_lands_in_store_and_in_a_capture_under_the_same_name(
+        tmp_path):
+    """With jax loaded a span is also a ``TraceAnnotation`` of the same
+    name carrying the span id, so a profiler capture (anyone's) holds
+    it in its host plane, on the device ops' own clock."""
+    import glob
+
+    import jax
+    import jax.numpy as jnp
+    from jax.profiler import ProfileData
+
+    out = str(tmp_path / "capture")
+    jax.profiler.start_trace(out)
+    try:
+        with tracing.span("unit.captured") as sp:
+            jnp.arange(32.0).sum().block_until_ready()
+    finally:
+        jax.profiler.stop_trace()
+    assert sp in tracing.SPANS.snapshot()
+    (path,) = glob.glob(out + "/plugins/profile/*/*.xplane.pb")
+    found = [e for plane in ProfileData.from_file(path).planes
+             for line in plane.lines for e in line.events
+             if e.name == "unit.captured"]
+    assert len(found) == 1
+    assert dict(found[0].stats)["span"] == sp["span"]
+    # the capture's duration is the span's, to the clock reads between
+    assert found[0].duration_ns == pytest.approx(
+        sp["end_ns"] - sp["start_ns"], abs=2_000_000)
+
+
+_NO_JAX_PROBE = (
+    "import sys; sys.path.insert(0, {repo!r}); "
+    "import fiber_tpu.telemetry.tracing as tracing; "
+    "import fiber_tpu.utils.profiling as profiling; "
+    "assert profiling.annotate is tracing.span; "
+    "ctx = tracing.span('probe'); sp = ctx.__enter__(); "
+    "ctx.__exit__(None, None, None); "
+    "assert sp['end_ns'] >= sp['start_ns'] > 0, sp; "
+    "assert tracing.SPANS.snapshot()[-1] is sp; "
+    "print('jax' in sys.modules)"
+)
+
+
+def test_span_primitive_does_not_import_jax():
+    """Lite workers and host agents must not pay for jax: the span
+    primitive (and ``annotate``, which is the same call) looks jax up
+    in ``sys.modules`` and never imports it."""
+    import os
+    import subprocess
+    import sys
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    proc = subprocess.run(
+        [sys.executable, "-c", _NO_JAX_PROBE.format(repo=repo)],
+        capture_output=True, text=True, timeout=120, cwd=repo)
+    assert proc.returncode == 0, proc.stderr
+    assert proc.stdout.split()[-1] == "False"
+
+
+def test_one_span_primitive_under_fiber_tpu():
+    """The only ``TraceAnnotation(`` call of the package is the span
+    primitive's."""
+    import os
+
+    root = os.path.dirname(os.path.abspath(fiber_tpu.__file__))
+    holders = []
+    for base, _dirs, files in os.walk(root):
+        for name in files:
+            if name.endswith(".py"):
+                path = os.path.join(base, name)
+                with open(path) as fh:
+                    if "TraceAnnotation(" in fh.read():
+                        holders.append(os.path.relpath(path, root))
+    assert holders in ([], ["telemetry/tracing.py"]), holders
+
+
 def test_chrome_trace_json_is_valid(tmp_path):
     with tracing.span("unit.root") as root:
         with tracing.span("unit.child"):
@@ -128,7 +236,9 @@ def test_chrome_trace_json_is_valid(tmp_path):
     export.write_chrome_trace(path, tracing.SPANS.snapshot())
     with open(path) as fh:
         doc = json.load(fh)
-    events = [e for e in doc["traceEvents"] if e["ph"] == "X"]
+    # (a sampler tick may land in the store beside them)
+    events = [e for e in doc["traceEvents"]
+              if e["ph"] == "X" and e["name"].startswith("unit.")]
     assert {e["name"] for e in events} == {"unit.root", "unit.child"}
     for event in events:
         for key in ("name", "ph", "ts", "dur", "pid", "tid"):

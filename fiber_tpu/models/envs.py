@@ -26,6 +26,22 @@ def _scan_unroll() -> int:
         return 1
 
 
+def _prepared(act, flat_params):
+    """The parameters a rollout's step scan closes over. ``act`` is
+    what the rollout will call on every step with them: where its owner
+    (a bound method's policy, or the policy itself) offers
+    ``unflatten``, the flat vector is cut into its layers here, once,
+    and the scan body reads the layers in place; a plain function keeps
+    the flat vector and cuts it on every step. A trace-time counter
+    says which of the two a rollout got (docs/observability.md)."""
+    from fiber_tpu.telemetry import device
+
+    owner = getattr(act, "__self__", act)
+    unflatten = getattr(owner, "unflatten", None)
+    device.rollout_traced(type(owner).__name__, unflatten is not None)
+    return flat_params if unflatten is None else unflatten(flat_params)
+
+
 def _mutate_bounded(env_params, key, low, high, scale):
     """Shared POET env mutation: clip-bounded gaussian perturbation of
     the parameter vector (one implementation for every Param* env)."""
@@ -136,9 +152,10 @@ class CartPole:
         masking inside the scan (static shapes, no early exit).
         """
         steps = max_steps or cls.max_steps
+        params = _prepared(act_fn, flat_params)
         return _survival_scan(
             cls.step,
-            lambda carry, state: (carry, act_fn(flat_params, state)),
+            lambda carry, state: (carry, act_fn(params, state)),
             cls.reset(key), (), steps,
         )
 
@@ -196,9 +213,10 @@ class ParamCartPole(CartPole):
         """Episode reward under a specific physics vector; jittable and
         vmappable over (env_params, flat_params) pairs."""
         steps = max_steps or cls.max_steps
+        params = _prepared(act_fn, flat_params)
         return _survival_scan(
             lambda state, action: cls.step_p(env_params, state, action),
-            lambda carry, state: (carry, act_fn(flat_params, state)),
+            lambda carry, state: (carry, act_fn(params, state)),
             cls.reset(key), (), steps,
         )
 
@@ -264,10 +282,11 @@ class Pendulum:
 
         steps = max_steps or cls.max_steps
         state0 = cls.reset(key)
+        params = _prepared(act_fn, flat_params)
 
         def scan_step(carry, _):
             state, total = carry
-            torque = act_fn(flat_params, cls.obs(state))
+            torque = act_fn(params, cls.obs(state))
             torque = jnp.reshape(torque, ())
             with jax.named_scope("env.step"):
                 new_state, reward = cls.step(state, torque)
@@ -327,12 +346,13 @@ class PixelChase:
             k2, (2,), minval=2.0, maxval=cls.H - 3.0
         )
         moves = jnp.asarray(cls._MOVES, dtype=jnp.float32)
+        params = _prepared(act_fn, flat_params)
 
         def scan_step(carry, _):
             agent, total = carry
             with jax.named_scope("env.step"):
                 obs = cls._render(agent, target)
-            action = act_fn(flat_params, obs)
+            action = act_fn(params, obs)
             with jax.named_scope("env.step"):
                 agent = jnp.clip(
                     agent + moves[action], 0.0, float(cls.H - 1)
@@ -385,10 +405,11 @@ class DeceptiveMaze:
         steps = max_steps or cls.max_steps
         pos0 = 0.05 * jax.random.normal(key, (2,))
         gx, gy = cls.GOAL
+        params = _prepared(apply_fn, flat_params)
 
         def scan_step(pos, _):
             obs = jnp.stack([pos[0], pos[1], gx - pos[0], gy - pos[1]])
-            v = jnp.tanh(apply_fn(flat_params, obs)) * cls.SPEED
+            v = jnp.tanh(apply_fn(params, obs)) * cls.SPEED
             with jax.named_scope("env.step"):
                 new = pos + v
                 # The wall blocks any step whose path crosses WALL_Y inside
@@ -487,6 +508,7 @@ class ParamHillWalker:
         steps = max_steps or cls.max_steps
         x0 = 0.1 * jax.random.normal(key, ())
         v0 = jnp.asarray(0.0)
+        params = _prepared(act_fn, flat_params)
 
         def scan_step(carry, _):
             x, v = carry
@@ -496,7 +518,7 @@ class ParamHillWalker:
                 cls.slope(env_params, x + 0.5),
                 cls.slope(env_params, x + 1.0),
             ])
-            action = act_fn(flat_params, obs)
+            action = act_fn(params, obs)
             with jax.named_scope("env.step"):
                 force = (action.astype(jnp.float32) - 1.0) * cls.force_mag
                 acc = force - cls.gravity * cls.slope(env_params, x) \
@@ -596,6 +618,7 @@ class ParamBipedWalker:
             0.0, y0, 0.0, 0.0, jitter[0], 0.0,
             0.15 + jitter[1], -0.15, 1.0, 1.0,
         ])
+        params = _prepared(act_fn, flat_params)
 
         def leg_forces(x, y, vx, vy, th, L, dth, dL, env):
             fx_pos = x + L * jnp.sin(th)
@@ -637,7 +660,7 @@ class ParamBipedWalker:
                     cls._slope(env_params, x + 0.8),
                     y - cls.height(env_params, x),
                 ])
-            action = act_fn(flat_params, obs)
+            action = act_fn(params, obs)
             with jax.named_scope("env.step"):
                 bit = lambda k: 2.0 * jnp.asarray(
                     (action >> k) & 1, jnp.float32) - 1.0
@@ -703,8 +726,9 @@ def rollout_recurrent(env_cls, policy, flat_params, key,
     ``_survival_scan``), with the policy's hidden state threaded through
     the carry — fully jittable and vmappable over (flat_params, key)."""
     steps = max_steps or env_cls.max_steps
+    params = _prepared(policy, flat_params)
     return _survival_scan(
         env_cls.step,
-        lambda h, state: policy.act_step(flat_params, h, state),
+        lambda h, state: policy.act_step(params, h, state),
         env_cls.reset(key), policy.init_carry(), steps,
     )

@@ -6,6 +6,16 @@ Reference parity: the reference's ES examples use small torch MLPs
 (examples/gecco-2020); here policies are pure JAX with a
 ``ravel``/``unravel`` pair so a whole population of parameter vectors is a
 single 2-D array.
+
+The ``unflatten`` contract (docs/api.md "Policies"): a policy offers
+``unflatten(flat_params)``, which cuts the flat vector into its layers
+(with the ``compute_dtype`` cast) once, and every method that takes
+parameters (``apply`` / ``act``, ``step`` / ``act_step``) takes either
+the flat vector or what ``unflatten`` returned. The rollouts of
+``models/envs.py`` unflatten once, before their step scan, when handed a
+bound method of such a policy; a plain function gets the flat vector on
+every step, as before. With ``compute_dtype`` unset the layer products
+are float32 on every backend (:func:`_dense`).
 """
 
 from __future__ import annotations
@@ -40,6 +50,53 @@ def _compute_dtype(explicit):
     if not name:
         return None
     return jnp.dtype(name)
+
+
+def _layers(policy, params):
+    """What ``policy.unflatten`` returns, whichever form ``params`` has:
+    the flat vector is an array, the layers are a tuple."""
+    return params if isinstance(params, tuple) else policy.unflatten(params)
+
+
+def _cut(flat, shapes):
+    """``flat`` cut into arrays of ``shapes``, in order."""
+    import math
+
+    out, offset = [], 0
+    for shape in shapes:
+        n = math.prod(shape)
+        out.append(flat[offset:offset + n].reshape(shape))
+        offset += n
+    return tuple(out)
+
+
+def _weights_and_biases(flat, weight_shapes, dt):
+    """``((w, b), ...)``, one pair per weight shape (the bias is as long
+    as the weight's last dimension), cast to the compute dtype."""
+    if dt is not None:
+        flat = flat.astype(dt)
+    shapes = []
+    for shape in weight_shapes:
+        shapes += [tuple(shape), tuple(shape[-1:])]
+    cut = _cut(flat, shapes)
+    return tuple(zip(cut[::2], cut[1::2]))
+
+
+def _precision(dt):
+    """With no compute dtype a layer product is pinned to float32: at
+    default precision it is the backend's to lower (the TPU rounds
+    loop-invariant float32 operands to bfloat16), and float32 policy
+    arithmetic is the contract."""
+    import jax
+
+    return jax.lax.Precision.HIGHEST if dt is None else None
+
+
+def _dense(x, w, dt):
+    """``x (..., n_in) @ w (n_in, n_out)`` in the compute dtype."""
+    import jax.numpy as jnp
+
+    return jnp.dot(x, w, precision=_precision(dt))
 
 
 class MLPPolicy:
@@ -78,34 +135,33 @@ class MLPPolicy:
             parts.append(b)
         return jnp.concatenate(parts)
 
+    def unflatten(self, flat_params):
+        """``((w, b), ...)`` per layer, cast to the compute dtype: the
+        cutting ``apply`` would do, done once."""
+        return _weights_and_biases(
+            flat_params, zip(self.sizes, self.sizes[1:]),
+            _compute_dtype(self.compute_dtype))
+
     @_policy_apply_scope
-    def apply(self, flat_params, obs):
-        """Logits for one observation; jittable / vmappable."""
+    def apply(self, params, obs):
+        """Logits for one observation; jittable / vmappable. ``params``
+        is the flat vector or ``unflatten`` of it."""
         import jax.numpy as jnp
 
         dt = _compute_dtype(self.compute_dtype)
-        x = obs
-        if dt is not None:
-            x = x.astype(dt)
-            flat_params = flat_params.astype(dt)
-        offset = 0
-        n_layers = len(self.sizes) - 1
-        for i in range(n_layers):
-            n_in, n_out = self.sizes[i], self.sizes[i + 1]
-            w = flat_params[offset:offset + n_in * n_out].reshape(n_in, n_out)
-            offset += n_in * n_out
-            b = flat_params[offset:offset + n_out]
-            offset += n_out
-            x = x @ w + b
-            if i < n_layers - 1:
+        layers = _layers(self, params)
+        x = obs if dt is None else obs.astype(dt)
+        for i, (w, b) in enumerate(layers):
+            x = _dense(x, w, dt) + b
+            if i < len(layers) - 1:
                 x = jnp.tanh(x)
         return x.astype(jnp.float32)
 
-    def act(self, flat_params, obs):
+    def act(self, params, obs):
         """Deterministic discrete action."""
         import jax.numpy as jnp
 
-        return jnp.argmax(self.apply(flat_params, obs))
+        return jnp.argmax(self.apply(params, obs))
 
 
 class ConvPolicy:
@@ -150,43 +206,44 @@ class ConvPolicy:
             parts.append(jnp.zeros((shape[-1],)))
         return jnp.concatenate(parts)
 
+    def unflatten(self, flat_params):
+        """``((w, b), ...)`` per layer, cast to the compute dtype."""
+        return _weights_and_biases(
+            flat_params, (shape for _, shape in self._specs),
+            _compute_dtype(self.compute_dtype))
+
     @_policy_apply_scope
-    def apply(self, flat_params, obs):
+    def apply(self, params, obs):
+        """Logits for one image; ``params`` is the flat vector or
+        ``unflatten`` of it."""
         import jax
         import jax.numpy as jnp
-        import numpy as np
 
         dt = _compute_dtype(self.compute_dtype)
+        layers = _layers(self, params)
         x = obs[None]  # NHWC with N=1
         if dt is not None:
             x = x.astype(dt)
-            flat_params = flat_params.astype(dt)
-        offset = 0
-        n = len(self._specs)
-        for i, (kind, shape) in enumerate(self._specs):
-            count = int(np.prod(shape))
-            w = flat_params[offset:offset + count].reshape(shape)
-            offset += count
-            b = flat_params[offset:offset + shape[-1]]
-            offset += shape[-1]
+        for i, ((kind, _), (w, b)) in enumerate(zip(self._specs, layers)):
             if kind == "conv":
                 x = jax.lax.conv_general_dilated(
                     x, w, window_strides=(2, 2), padding="SAME",
                     dimension_numbers=("NHWC", "HWIO", "NHWC"),
+                    precision=_precision(dt),
                 )
                 x = jnp.tanh(x + b)
             else:
                 if x.ndim > 2:
                     x = x.reshape(x.shape[0], -1)
-                x = x @ w + b
-                if i < n - 1:
+                x = _dense(x, w, dt) + b
+                if i < len(layers) - 1:
                     x = jnp.tanh(x)
         return x[0].astype(jnp.float32)
 
-    def act(self, flat_params, obs):
+    def act(self, params, obs):
         import jax.numpy as jnp
 
-        return jnp.argmax(self.apply(flat_params, obs))
+        return jnp.argmax(self.apply(params, obs))
 
 
 class GRUPolicy:
@@ -239,35 +296,28 @@ class GRUPolicy:
 
         return jnp.zeros((self.hidden,))
 
-    def _unpack(self, flat):
+    def unflatten(self, flat_params):
+        """The eleven arrays of the three gates and the readout."""
         o, h, a = self.obs_dim, self.hidden, self.act_dim
-        shapes = [(o, h), (h, h), (h,)] * 3 + [(h, a), (a,)]
-        out, offset = [], 0
-        for shape in shapes:
-            n = 1
-            for s in shape:
-                n *= s
-            out.append(flat[offset:offset + n].reshape(shape))
-            offset += n
-        return out
+        return _cut(flat_params, [(o, h), (h, h), (h,)] * 3 + [(h, a), (a,)])
 
     @_policy_apply_scope
-    def step(self, flat_params, carry, obs):
-        """(carry', logits) for one step; jittable/vmappable."""
+    def step(self, params, carry, obs):
+        """(carry', logits) for one step; jittable/vmappable. ``params``
+        is the flat vector or ``unflatten`` of it."""
         import jax
-
-        (wz, uz, bz, wr, ur, br, wh, uh, bh, wo, bo) = \
-            self._unpack(flat_params)
-        z = jax.nn.sigmoid(obs @ wz + carry @ uz + bz)
-        r = jax.nn.sigmoid(obs @ wr + carry @ ur + br)
         import jax.numpy as jnp
 
-        cand = jnp.tanh(obs @ wh + (r * carry) @ uh + bh)
+        (wz, uz, bz, wr, ur, br, wh, uh, bh, wo, bo) = _layers(self, params)
+        dot = functools.partial(_dense, dt=None)
+        z = jax.nn.sigmoid(dot(obs, wz) + dot(carry, uz) + bz)
+        r = jax.nn.sigmoid(dot(obs, wr) + dot(carry, ur) + br)
+        cand = jnp.tanh(dot(obs, wh) + dot(r * carry, uh) + bh)
         new_carry = (1.0 - z) * carry + z * cand
-        return new_carry, new_carry @ wo + bo
+        return new_carry, dot(new_carry, wo) + bo
 
-    def act_step(self, flat_params, carry, obs):
+    def act_step(self, params, carry, obs):
         import jax.numpy as jnp
 
-        new_carry, logits = self.step(flat_params, carry, obs)
+        new_carry, logits = self.step(params, carry, obs)
         return new_carry, jnp.argmax(logits)

@@ -86,6 +86,11 @@ _m_step_units = telemetry.counter(
     "device_step_units",
     "Work those calls asked for, by fn (ES: generations x population; "
     "LM: tokens)")
+_m_rollout_traces = telemetry.counter(
+    "policy_rollout_traces",
+    "Env rollouts traced, by policy class and params: 'prepared' "
+    "(unflattened once, before the step scan) or 'flat' (cut on every "
+    "step: the callable's owner offers no unflatten)")
 _g_hbm_in_use = telemetry.gauge(
     "device_hbm_bytes_in_use", "HBM bytes in use on the first local device")
 _g_hbm_limit = telemetry.gauge(
@@ -575,6 +580,15 @@ def step(fn: str, units: int, **attrs) -> Iterator[Optional[Dict]]:
         yield sp
     _m_steps.inc(fn=fn)
     _m_step_units.inc(units, fn=fn)
+
+
+def rollout_traced(policy: str, prepared: bool) -> None:
+    """One env rollout was traced (``models/envs.py``): with the
+    policy's parameters unflattened before the step scan, or with the
+    flat vector. Counts traces, not calls: a jitted rollout moves it
+    once per compilation."""
+    _m_rollout_traces.inc(
+        policy=policy, params="prepared" if prepared else "flat")
 
 
 def snapshot() -> Dict[str, Any]:

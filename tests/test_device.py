@@ -1629,18 +1629,84 @@ def _lowered_es_step():
     import jax
     import jax.numpy as jnp
 
-    from fiber_tpu.models import ParamBipedWalker
-
-    policy = MLPPolicy(ParamBipedWalker.obs_dim, ParamBipedWalker.act_dim,
-                       hidden=(8,))
-    course = jnp.zeros((len(ParamBipedWalker.PARAM_LOW),), jnp.float32)
-    es = EvolutionStrategy(
-        lambda p, k: ParamBipedWalker.rollout_p(
-            policy.act, course, p, k, 10),
-        dim=policy.dim, pop_size=16, optimizer="adam")
+    policy, rollout, _ = _walker_rollouts()
+    es = EvolutionStrategy(rollout, dim=policy.dim, pop_size=16,
+                           optimizer="adam")
     vec = jnp.zeros((policy.dim,))
     return es._step.lower(vec, vec, vec, jnp.asarray(0.0),
                           jax.random.PRNGKey(0))
+
+
+def _walker_rollouts(hidden=(8,), steps=10):
+    """(policy, rollout through ``policy.act``, rollout through a plain
+    function around it), each a function of (flat_params, key)."""
+    import jax.numpy as jnp
+
+    from fiber_tpu.models import ParamBipedWalker
+
+    policy = MLPPolicy(ParamBipedWalker.obs_dim, ParamBipedWalker.act_dim,
+                       hidden=hidden)
+    course = jnp.zeros((len(ParamBipedWalker.PARAM_LOW),), jnp.float32)
+    return (policy,
+            lambda p, k: ParamBipedWalker.rollout_p(
+                policy.act, course, p, k, steps),
+            lambda p, k: ParamBipedWalker.rollout_p(
+                lambda q, o: policy.act(q, o), course, p, k, steps))
+
+
+def _slices_of_width(jaxpr, width, in_scan=False):
+    """The ``slice`` / ``dynamic_slice`` equations inside any ``scan``
+    body of ``jaxpr`` whose operand's last dimension is ``width``."""
+    found = []
+    for eqn in jaxpr.eqns:
+        shape = getattr(eqn.invars[0].aval, "shape", ()) if eqn.invars else ()
+        if in_scan and eqn.primitive.name in ("slice", "dynamic_slice") \
+                and shape[-1:] == (width,):
+            found.append(eqn)
+        for sub in eqn.params.values():
+            sub = getattr(sub, "jaxpr", sub)  # ClosedJaxpr or Jaxpr
+            if hasattr(sub, "eqns"):
+                found += _slices_of_width(
+                    sub, width, in_scan or eqn.primitive.name == "scan")
+    return found
+
+
+@pytest.mark.parametrize("plain", [False, True],
+                         ids=["policy_act", "plain_function"])
+def test_walker_rollout_cuts_the_flat_vector_outside_the_scan(plain):
+    """Through ``policy.act`` the step scan of the walker's rollout holds
+    no slice of the ``(pop, dim)`` population (the layers are cut once,
+    before it); through a plain function every layer is cut inside it,
+    so the walk does find such slices."""
+    import jax
+    import jax.numpy as jnp
+
+    policy, *rollouts = _walker_rollouts()
+    thetas = jnp.zeros((8, policy.dim))
+    keys = jax.random.split(jax.random.PRNGKey(0), 8)
+    jaxpr = jax.make_jaxpr(jax.vmap(rollouts[plain]))(thetas, keys).jaxpr
+    want = 2 * (len(policy.sizes) - 1) if plain else 0
+    assert len(_slices_of_width(jaxpr, policy.dim)) == want
+
+
+def test_walker_policy_products_are_pinned_to_float32():
+    """With ``compute_dtype`` unset no product under ``policy.apply`` in
+    the lowered ES step is a default-precision ``dot_general`` (which
+    the TPU may round to bfloat16 once the weights are loop-invariant);
+    the gradient's ``w @ eps`` outside it still is one, so the search
+    does find default-precision dots."""
+    import re
+
+    text = _lowered_es_step().as_text(debug_info=True)
+    locs = dict(re.findall(r'^(#loc\d+) = loc\("([^"]*)"', text, re.M))
+    dots = [(locs.get(m.group(2), ""), "precision = [HIGHEST, HIGHEST]"
+             in m.group(1))
+            for m in re.finditer(
+                r"^(.*stablehlo\.dot_general.*) loc\((#loc\d+)\)$",
+                text, re.M)]
+    assert any("es.gradient" in name and not pinned for name, pinned in dots)
+    in_policy = [pinned for name, pinned in dots if "policy.apply" in name]
+    assert in_policy and all(in_policy), dots
 
 
 def _lowered_lm_step(attention, **kw):

@@ -7,7 +7,12 @@ from fiber_tpu.models.policies import (  # noqa: F401
     MLPPolicy,
 )
 from fiber_tpu.models.transformer import (  # noqa: F401
+    Block,
+    BlockLM,
+    Experts,
+    Rope,
     TinyLM,
+    Yarn,
     make_train_step,
 )
 from fiber_tpu.models.envs import (  # noqa: F401

@@ -15,83 +15,185 @@ flax): the framework's flagship workloads are population-based, and
 this exists to prove the sequence-parallel plane end to end —
 embedding -> [RMSNorm -> attention -> residual -> RMSNorm -> MLP ->
 residual] x L -> norm -> logits.
+
+A model is a description of its layers (:class:`Block`): each layer has
+its own query-head count, window, rope and feed-forward kind (ungated
+MLP, gated MLP, or sparse experts of which this program holds a share,
+:mod:`fiber_tpu.ops.moe`). :class:`BlockLM` runs any such description;
+:class:`TinyLM` is the uniform one.
 """
 
 from __future__ import annotations
 
-from typing import Optional
+import dataclasses
+from typing import Optional, Sequence, Tuple
 
 
-class TinyLM:
-    """Causal byte/token LM. ``attention`` picks the plane:
-    ``"ring"`` (sequence sharded via ppermute ring + online softmax),
-    ``"ulysses"`` (all-to-all head/seq swap; needs
-    ``heads % n_devices == 0``), ``"flash"`` (the Pallas
-    flash-attention kernels, forward AND backward — single device runs
-    them directly with the whole sequence in HBM and scores streamed
-    through VMEM; pass a multi-device ``mesh=`` and the sequence
-    shards over the ring with the kernel as every rotation's
-    per-device block), or
-    ``"reference"`` (full score matrix, single device — for parity
-    tests).
+# ---------------------------------------------------------------------
+# The description of a model's layers. A model is a sequence of
+# ``Block``s; ``TinyLM`` is the uniform one (every layer the same).
+# ---------------------------------------------------------------------
+@dataclasses.dataclass(frozen=True)
+class Yarn:
+    """YaRN scaling of a rope's frequencies (arXiv:2309.00071,
+    NTK-by-parts, as ``transformers`` computes it): per frequency a
+    linear ramp between the rope's own ``base^(-2i/r)`` and that over
+    ``factor``, the ramp's ends where a frequency makes ``beta_fast``
+    and ``beta_slow`` turns over ``original_max_position`` positions;
+    cos and sin times ``attention_factor`` (``0.1 ln(factor) + 1``
+    where none is given)."""
 
-    The flash kernels compile through Mosaic and need a TPU; on any
-    other platform ``attention="flash"`` is refused at construction
-    unless ``interpret=True`` asks for the Pallas interpreter (slow,
-    for CPU tests). The library never picks the interpreter itself.
+    factor: float
+    original_max_position: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    attention_factor: Optional[float] = None
 
-    ``pos`` picks the positional scheme: ``"learned"`` (absolute
-    table, the default) or ``"rope"`` (rotary embeddings on q/k per
-    layer — relative positions, the modern long-context choice; no
-    position table in the params).
 
-    ``apply(params, tokens (S,)) -> (S, vocab)`` logits;
-    ``loss(params, tokens)`` is mean next-token cross-entropy.
-    ``S`` must equal ``max_seq`` (static shapes; pad shorter text).
-    """
+@dataclasses.dataclass(frozen=True)
+class Rope:
+    """A layer's rotary embedding: ``base``, how many leading features
+    of each head rotate (``rotary``; None = the whole head; the rest
+    pass through), and an optional YaRN scaling."""
 
-    def __init__(
-        self,
-        vocab: int = 256,
-        dim: int = 64,
-        heads: int = 8,
-        layers: int = 2,
-        max_seq: int = 256,
-        mlp_mult: int = 4,
-        mesh=None,
-        attention: str = "ring",
-        kv_heads: Optional[int] = None,
-        pos: str = "learned",
-        window: Optional[int] = None,
-        interpret: bool = False,
-    ) -> None:
-        if dim % heads:
-            raise ValueError(f"dim {dim} not divisible by heads {heads}")
+    base: float = 10000.0
+    rotary: Optional[int] = None
+    yarn: Optional[Yarn] = None
+
+    def table(self, head_dim: int):
+        """(features that rotate, inverse frequencies or None for the
+        plain ``base^(-2i/r)``, factor on cos and sin)."""
+        r = head_dim if self.rotary is None else int(self.rotary)
+        if r < 2 or r % 2 or r > head_dim:
+            raise ValueError(
+                f"rope rotates {r} of {head_dim} features: need an even "
+                "count within the head")
+        if self.yarn is None:
+            return r, None, 1.0
+        import math
+
+        import numpy as np
+
+        y = self.yarn
+        extrapolation = 1.0 / (self.base ** (np.arange(0, r, 2) / r))
+        interpolation = extrapolation / y.factor
+
+        def turns_at(turns):
+            return (r * math.log(y.original_max_position
+                                 / (turns * 2 * math.pi))
+                    / (2 * math.log(self.base)))
+
+        low = max(math.floor(turns_at(y.beta_fast)), 0)
+        high = min(math.ceil(turns_at(y.beta_slow)), r - 1)
+        if low == high:
+            high += 0.001
+        ramp = np.clip((np.arange(r // 2) - low) / (high - low), 0.0, 1.0)
+        inv = interpolation * ramp + extrapolation * (1.0 - ramp)
+        factor = (y.attention_factor if y.attention_factor is not None
+                  else 0.1 * math.log(y.factor) + 1.0)
+        return r, inv.astype(np.float32), float(factor)
+
+
+@dataclasses.dataclass(frozen=True)
+class Experts:
+    """A sparse-expert feed-forward (``fiber_tpu.ops.moe``): ``total``
+    routed experts of ``width``, ``top_k`` a token (sigmoid scores,
+    weights renormalised over the taken and times ``scale``), one
+    shared expert of ``shared_width``. ``share = (index, shares)``:
+    this program holds experts ``[index * total / shares, (index + 1) *
+    total / shares)`` of an expert-parallel layer and computes their
+    part of the result; ``(0, 1)`` is the whole layer. ``chunk_rows``
+    sizes the dispatch's buffers (memory), never what is computed."""
+
+    total: int
+    top_k: int
+    width: int
+    shared_width: int
+    scale: float = 1.0
+    share: Tuple[int, int] = (0, 1)
+    chunk_rows: int = 4096
+
+
+@dataclasses.dataclass(frozen=True)
+class Block:
+    """One layer: ``heads`` query heads (of the model's ``head_dim``,
+    grouped over its ``kv_heads``), causal attention over the last
+    ``window`` positions (None = all), its ``rope`` (None with a learned
+    position table), and its feed-forward: ``ffn="mlp"`` (ungated
+    tanh-GELU of ``width`` with biases), ``"gated"`` (SwiGLU of
+    ``width``, no biases) or ``"experts"`` (``experts=``)."""
+
+    heads: int
+    window: Optional[int] = None
+    rope: Optional[Rope] = Rope()
+    ffn: str = "mlp"
+    width: int = 0
+    experts: Optional[Experts] = None
+
+    @property
+    def attn_kind(self) -> str:
+        return "window" if self.window is not None else "full"
+
+    @property
+    def kind(self) -> str:
+        return self.attn_kind + "/" + self.ffn
+
+
+class BlockLM:
+    """Causal LM built from a description of its layers (``blocks``, a
+    sequence of :class:`Block`): per layer its own query-head count,
+    window, rope and feed-forward kind, RMSNorm (1e-6) before each half,
+    no attention bias, an untied head. ``attention``, ``mesh`` and
+    ``interpret`` are :class:`TinyLM`'s (which is the uniform
+    description); a window needs the flash plane.
+
+    ``apply`` / ``loss`` / ``generate`` as :class:`TinyLM`;
+    ``routing(params, tokens)`` gives, for the expert layers, the taken
+    expert ids of every token and the load of each held expert."""
+
+    def __init__(self, blocks: Sequence[Block], *, vocab: int, dim: int,
+                 head_dim: int, kv_heads: int, max_seq: int,
+                 attention: str = "flash", pos: str = "rope", mesh=None,
+                 interpret: bool = False) -> None:
+        blocks = tuple(blocks)
         if attention not in ("ring", "ulysses", "flash", "reference"):
             raise ValueError(f"unknown attention {attention!r}")
         if pos not in ("learned", "rope"):
             raise ValueError(f"unknown positional scheme {pos!r}")
-        if pos == "rope" and (dim // heads) % 2:
-            raise ValueError("rope needs an even head_dim")
-        if window is not None:
-            if window < 1:
-                raise ValueError(f"window must be >= 1, got {window}")
-            if attention != "flash":
-                # The window lives in the flash kernels' block-skip
-                # grid; the XLA planes have no windowed engine and
-                # silently ignoring it would train a different model.
-                raise ValueError(
-                    "window= needs attention='flash' (the sliding "
-                    "window is a kernel feature)")
-        if kv_heads is not None and kv_heads < 1:
-            # 0 must not silently mean "full MHA" (a GQA A/B would
-            # quietly measure nothing) and negatives pass Python's
-            # modulo only to crash deep inside init().
+        if kv_heads < 1:
             raise ValueError(f"kv_heads must be >= 1, got {kv_heads}")
-        kv_heads = kv_heads or heads
-        if heads % kv_heads:
-            raise ValueError(
-                f"heads {heads} not divisible by kv_heads {kv_heads}")
+        for b in blocks:
+            if b.heads % kv_heads:
+                raise ValueError(
+                    f"heads {b.heads} not divisible by kv_heads {kv_heads}")
+            if (pos == "rope") != (b.rope is not None):
+                raise ValueError(
+                    "pos='rope' gives every block a rope and "
+                    "pos='learned' none")
+            if b.rope is not None:
+                b.rope.table(head_dim)
+            if b.window is not None:
+                if b.window < 1:
+                    raise ValueError(
+                        f"window must be >= 1, got {b.window}")
+                if attention != "flash":
+                    # The window lives in the flash kernels' block-skip
+                    # grid; the XLA planes have no windowed engine and
+                    # silently ignoring it would train a different model.
+                    raise ValueError(
+                        "window= needs attention='flash' (the sliding "
+                        "window is a kernel feature)")
+            if b.ffn not in ("mlp", "gated", "experts"):
+                raise ValueError(f"unknown feed-forward {b.ffn!r}")
+            if (b.ffn == "experts") != (b.experts is not None):
+                raise ValueError(
+                    "ffn='experts' comes with experts=, and no other does")
+            if b.ffn == "experts":
+                from fiber_tpu.ops.moe import held_experts
+
+                held_experts(b.experts.total, b.experts.share)
+            elif b.width < 1:
+                raise ValueError(f"feed-forward width {b.width}")
         self._flash_multi = False
         if mesh is not None:
             import numpy as np
@@ -111,7 +213,7 @@ class TinyLM:
                 # over the mesh AND every rotation streams scores
                 # through VMEM (ring_attention local="flash").
                 self._flash_multi = True
-        if window is not None and self._flash_multi:
+        if self._flash_multi and any(b.window is not None for b in blocks):
             raise ValueError(
                 "window= is single-device (a windowed partial's lse "
                 "is not ring-mergeable); drop the mesh or the window")
@@ -126,33 +228,54 @@ class TinyLM:
                     f"Mosaic and needs a TPU (platform is {platform!r}); "
                     "pass interpret=True to run them in the Pallas "
                     "interpreter instead")
+        self.blocks = blocks
         self.vocab = vocab
         self.dim = dim
-        self.heads = heads
         # kv_heads < heads is grouped-query attention: the flash plane
         # reads the small KV natively (kernel index maps share KV
         # blocks across each query group); the XLA planes broadcast KV
         # to full heads at attend time (compute identical, memory not
         # saved there — GQA's KV-cache/HBM win is a kernel property).
         self.kv_heads = kv_heads
-        self.head_dim = dim // heads
-        self.layers = layers
+        self.head_dim = head_dim
+        self.layers = len(blocks)
         self.max_seq = max_seq
-        self.mlp_mult = mlp_mult
         self.attention = attention
         # "learned": absolute position table added to embeddings.
         # "rope": rotary embeddings applied to q/k per attention layer
         # (relative positions; the modern long-context default — decays
         # gracefully past training lengths where a learned table ends).
         self.pos = pos
-        #: causal sliding window (flash plane only; None = full causal)
-        self.window = window
         #: flash plane only: run the kernels in the Pallas interpreter
         self.interpret = interpret
         self._mesh = mesh
+        self._probe = None
+
+    @property
+    def span_fields(self) -> dict:
+        """What the ``lm.train_step`` span says of the model: the layer
+        kinds in order and, with expert layers, how many experts are
+        held here, exist in all and are taken a token."""
+        fields = {"layers": ",".join(b.kind for b in self.blocks)}
+        experts = [b.experts for b in self.blocks if b.experts is not None]
+        if experts:
+            from fiber_tpu.ops.moe import held_experts
+
+            e = experts[0]
+            fields.update(experts_held=held_experts(e.total, e.share)[1],
+                          experts_total=e.total, top_k=e.top_k)
+        return fields
+
+
 
     # ------------------------------------------------------------------
     def init(self, key) -> dict:
+        """Weights 0.02 * normal, gains 1, biases 0. The stream: split
+        the key in four (embed, pos, out, rest); per layer split
+        ``rest`` in seven: 0 wq (or wqkv), 1 wo, 2 w1 / wg, 3 w2 / wd,
+        4 wkv, 5 wu (gated) or, split in seven again, the expert
+        layer's router, shared wg / wu / wd, held experts' wg / wu / wd
+        (each one draw of the stacked shape), 6 rest."""
         import jax
         import jax.numpy as jnp
 
@@ -169,32 +292,53 @@ class TinyLM:
         if self.pos == "learned":
             params["pos"] = scale * jax.random.normal(
                 k_pos, (self.max_seq, self.dim))
-        for _ in range(self.layers):
+
+        def normal(k, *shape):
+            return scale * jax.random.normal(k, shape)
+
+        for spec in self.blocks:
             keys = jax.random.split(key, 7)
             key = keys[6]
-            d, h = self.dim, self.mlp_mult * self.dim
+            d, q_dim = self.dim, spec.heads * self.head_dim
             blk = {
                 "norm1": jnp.ones((d,)),
-                "wo": scale * jax.random.normal(keys[1], (d, d)),
+                "wo": normal(keys[1], q_dim, d),
                 "norm2": jnp.ones((d,)),
-                "w1": scale * jax.random.normal(keys[2], (d, h)),
-                "b1": jnp.zeros((h,)),
-                "w2": scale * jax.random.normal(keys[3], (h, d)),
-                "b2": jnp.zeros((d,)),
             }
-            if self.kv_heads == self.heads:
-                blk["wqkv"] = scale * jax.random.normal(
-                    keys[0], (d, 3 * d))
+            if self.kv_heads == spec.heads:
+                blk["wqkv"] = normal(keys[0], d, 3 * q_dim)
             else:
                 kv_dim = self.kv_heads * self.head_dim
-                blk["wq"] = scale * jax.random.normal(keys[0], (d, d))
-                blk["wkv"] = scale * jax.random.normal(
-                    keys[4], (d, 2 * kv_dim))
+                blk["wq"] = normal(keys[0], d, q_dim)
+                blk["wkv"] = normal(keys[4], d, 2 * kv_dim)
+            if spec.ffn == "mlp":
+                h = spec.width
+                blk.update(w1=normal(keys[2], d, h), b1=jnp.zeros((h,)),
+                           w2=normal(keys[3], h, d), b2=jnp.zeros((d,)))
+            elif spec.ffn == "gated":
+                h = spec.width
+                blk.update(wg=normal(keys[2], d, h),
+                           wd=normal(keys[3], h, d),
+                           wu=normal(keys[5], d, h))
+            else:
+                from fiber_tpu.ops.moe import held_experts
+
+                e = spec.experts
+                held = held_experts(e.total, e.share)[1]
+                sub = jax.random.split(keys[5], 7)
+                blk.update(
+                    router=normal(sub[0], d, e.total),
+                    shared_wg=normal(sub[1], d, e.shared_width),
+                    shared_wu=normal(sub[2], d, e.shared_width),
+                    shared_wd=normal(sub[3], e.shared_width, d),
+                    experts_wg=normal(sub[4], held, d, e.width),
+                    experts_wu=normal(sub[5], held, d, e.width),
+                    experts_wd=normal(sub[6], held, e.width, d))
             params["blocks"].append(blk)
         return params
 
     # ------------------------------------------------------------------
-    def _attend(self, q, k, v):
+    def _attend(self, q, k, v, window=None):
         if k.shape[1] != q.shape[1] and self.attention != "flash":
             # GQA on the XLA planes: broadcast KV to full heads (repeat
             # order matches the kernel's ih // group sharing). Only the
@@ -218,7 +362,7 @@ class TinyLM:
                     q, k, v, mesh=self._mesh, causal=True,
                     local="flash", interpret=self.interpret)
             return flash_attention(q, k, v, causal=True,
-                                   window=self.window,
+                                   window=window,
                                    interpret=self.interpret)
         if self.attention == "ulysses":
             from fiber_tpu.ops.ulysses_attention import ulysses_attention
@@ -236,90 +380,165 @@ class TinyLM:
         return g * x / jnp.sqrt(
             jnp.mean(x * x, axis=-1, keepdims=True) + 1e-6)
 
-    def _project_qkv(self, blk, h):
+    @staticmethod
+    def _project_qkv(blk, h):
         """Pre-attention projections, flat head layout. Works on (S,
         dim) rows and single (dim,) vectors alike — SHARED by apply()
         and _decode_step() so the block structure cannot silently
         diverge between the training and decode paths."""
         import jax.numpy as jnp
 
-        if self.kv_heads == self.heads:
+        if "wqkv" in blk:
             return jnp.split(h @ blk["wqkv"], 3, axis=-1)
         q = h @ blk["wq"]
         k, v = jnp.split(h @ blk["wkv"], 2, axis=-1)
         return q, k, v
 
     @staticmethod
-    def _rope_angles(positions, dh):
+    def _rope_angles(positions, dh, base=10000.0, inv_freq=None,
+                     factor=1.0):
         """cos/sin tables for rotary embeddings at ``positions``
-        (scalar or (S,)): shape (..., dh/2), base 10000."""
+        (scalar or (S,)): shape (..., dh/2). Frequencies ``base^(-2i /
+        dh)``, or the table ``inv_freq`` (a scaled rope's); ``factor``
+        multiplies both (YaRN's attention factor)."""
         import jax.numpy as jnp
 
-        inv = 1.0 / (10000.0 ** (jnp.arange(0, dh, 2) / dh))
+        inv = (1.0 / (base ** (jnp.arange(0, dh, 2) / dh))
+               if inv_freq is None else jnp.asarray(inv_freq))
         ang = jnp.asarray(positions, jnp.float32)[..., None] * inv
+        if factor != 1.0:
+            return factor * jnp.cos(ang), factor * jnp.sin(ang)
         return jnp.cos(ang), jnp.sin(ang)
+
+    def _rope_tables(self, positions):
+        """{rope: (cos, sin)} for each distinct rope of the blocks."""
+        tables = {}
+        for spec in self.blocks:
+            if spec.rope is not None and spec.rope not in tables:
+                r, inv, factor = spec.rope.table(self.head_dim)
+                tables[spec.rope] = self._rope_angles(
+                    positions, r, spec.rope.base, inv, factor)
+        return tables
 
     @staticmethod
     def _rope_rotate(x, cos, sin):
-        """Rotate feature pairs (half-split convention); cos/sin
-        broadcast against x's leading axes. The result keeps x's dtype:
-        f32 cos/sin must not silently promote a bf16 stream (which
-        would also let decode's cache cast rotated keys back DOWN,
-        drifting incremental decode away from full-apply)."""
+        """Rotate feature pairs (half-split convention) of the leading
+        ``2 * cos.shape[-1]`` features of x's last axis; the rest pass
+        through. cos/sin broadcast against x's leading axes. The result
+        keeps x's dtype: f32 cos/sin must not silently promote a bf16
+        stream (which would also let decode's cache cast rotated keys
+        back DOWN, drifting incremental decode away from full-apply)."""
         import jax.numpy as jnp
 
+        r = 2 * cos.shape[-1]
+        if r < x.shape[-1]:
+            return jnp.concatenate(
+                [BlockLM._rope_rotate(x[..., :r], cos, sin), x[..., r:]],
+                axis=-1)
         x1, x2 = jnp.split(x, 2, axis=-1)
         return jnp.concatenate(
             [x1 * cos - x2 * sin, x2 * cos + x1 * sin],
             axis=-1).astype(x.dtype)
 
-    def _block_tail(self, blk, x, attn_flat):
-        """Post-attention residual + MLP (shared like _project_qkv)."""
+    def _block_tail(self, spec, blk, x, attn_flat, taps=None):
+        """Post-attention residual + feed-forward (shared like
+        _project_qkv)."""
         import jax
 
-        with jax.named_scope("lm.attn"), jax.named_scope("out"):
+        with jax.named_scope("lm.attn"), \
+                jax.named_scope(spec.attn_kind), jax.named_scope("out"):
             x = x + attn_flat @ blk["wo"]
-        with jax.named_scope("lm.mlp"):
+        if spec.ffn == "mlp":
+            with jax.named_scope("lm.mlp"):
+                h = self._rms(x, blk["norm2"])
+                return x + jax.nn.gelu(h @ blk["w1"] + blk["b1"]) \
+                    @ blk["w2"] + blk["b2"]
+        from fiber_tpu.ops import moe
+
+        if spec.ffn == "gated":
+            with jax.named_scope("lm.mlp"):
+                h = self._rms(x, blk["norm2"])
+                return x + moe.swiglu(h, blk["wg"], blk["wu"], blk["wd"])
+        with jax.named_scope("lm.moe"), jax.named_scope("norm"):
             h = self._rms(x, blk["norm2"])
-            return x + jax.nn.gelu(h @ blk["w1"] + blk["b1"]) \
-                @ blk["w2"] + blk["b2"]
+        e = spec.experts
+        rows = h.reshape(-1, h.shape[-1])       # decode hands one vector
+        y = moe.moe_ffn(
+            rows, blk, total=e.total, top_k=e.top_k, scale=e.scale,
+            first=moe.held_experts(e.total, e.share)[0],
+            chunk_rows=e.chunk_rows, taps=taps)
+        return x + y.reshape(x.shape)
 
     def apply(self, params, tokens):
         """tokens (max_seq,) int -> logits (max_seq, vocab).
 
-        The named scopes (``lm.embed``, ``lm.attn`` with ``qkv``,
-        ``kernel``, ``out``, ``lm.mlp``, ``lm.head_loss``) are metadata:
-        every op's ``op_name`` in a profile starts with its phase."""
+        The named scopes (``lm.embed``, ``lm.attn`` with ``window`` or
+        ``full`` and under it ``qkv``, ``kernel``, ``out``, ``lm.mlp``,
+        ``lm.moe``, ``lm.head_loss``) are metadata: every op's
+        ``op_name`` in a profile starts with its phase."""
+        return self._forward(params, tokens)
+
+    def _forward(self, params, tokens, taps=None):
         import jax
         import jax.numpy as jnp
 
-        S, H, Dh = self.max_seq, self.heads, self.head_dim
+        S, Dh = self.max_seq, self.head_dim
         KVH = self.kv_heads
         with jax.named_scope("lm.embed"):
             x = params["embed"][tokens]                      # (S, dim)
-            rope = None
             if self.pos == "learned":
                 x = x + params["pos"]
-            else:
-                cos, sin = self._rope_angles(jnp.arange(S), Dh)
-                rope = (cos[:, None, :], sin[:, None, :])    # (S, 1, dh/2)
-        for blk in params["blocks"]:
-            with jax.named_scope("lm.attn"):
+            ropes = {rope: (cos[:, None, :], sin[:, None, :])  # (S, 1, r/2)
+                     for rope, (cos, sin)
+                     in self._rope_tables(jnp.arange(S)).items()}
+        for spec, blk in zip(self.blocks, params["blocks"]):
+            with jax.named_scope("lm.attn"), \
+                    jax.named_scope(spec.attn_kind):
                 with jax.named_scope("qkv"):
                     h = self._rms(x, blk["norm1"])
                     q, k, v = self._project_qkv(blk, h)
-                    q = q.reshape(S, H, Dh)
+                    q = q.reshape(S, spec.heads, Dh)
                     k = k.reshape(S, KVH, Dh)
                     v = v.reshape(S, KVH, Dh)
-                    if rope is not None:
-                        q = self._rope_rotate(q, *rope)
-                        k = self._rope_rotate(k, *rope)
+                    if spec.rope is not None:
+                        q = self._rope_rotate(q, *ropes[spec.rope])
+                        k = self._rope_rotate(k, *ropes[spec.rope])
                 with jax.named_scope("kernel"):
-                    attn = self._attend(q, k, v).reshape(S, -1)
-            x = self._block_tail(blk, x, attn)
+                    attn = self._attend(q, k, v, spec.window).reshape(S, -1)
+            x = self._block_tail(spec, blk, x, attn, taps)
         with jax.named_scope("lm.head_loss"):
             x = self._rms(x, params["final_norm"])
             return x @ params["out"]
+
+    def routing(self, params, tokens):
+        """For one sequence of tokens, what the expert layers' routers
+        decide: ``{"ids": (expert layers, S, top_k) int32, the experts
+        each token takes (of all ``total``); "load": (expert layers,
+        held) int32, the tokens each held expert gets}``. A whole
+        forward pass; for set-up and checks, not for the step."""
+        import jax.numpy as jnp
+
+        taps = []
+        self._forward(params, tokens, taps)
+        if not taps:
+            raise ValueError("the model has no expert layer")
+        return {"ids": jnp.stack([ids for ids, _ in taps]),
+                "load": jnp.stack([load for _, load in taps])}
+
+    def probe_routing(self, params, tokens) -> dict:
+        """``routing`` jitted and fetched to the host (numpy arrays),
+        with each held expert's load recorded in the registry's
+        ``moe_expert_load_max`` / ``moe_expert_load_mean`` gauges, by
+        expert layer. Host-side; call it outside a timed loop."""
+        import jax
+
+        from fiber_tpu.telemetry import device as device_telemetry
+
+        if self._probe is None:
+            self._probe = jax.jit(self.routing)
+        found = jax.device_get(self._probe(params, tokens))
+        device_telemetry.moe_load(found["load"])
+        return found
 
     def loss(self, params, tokens):
         """Mean next-token cross-entropy over positions 0..S-2."""
@@ -348,25 +567,22 @@ class TinyLM:
         import jax
         import jax.numpy as jnp
 
-        H, KVH, Dh = self.heads, self.kv_heads, self.head_dim
-        group = H // KVH
+        KVH, Dh = self.kv_heads, self.head_dim
         x = params["embed"][tok]                             # (dim,)
-        rope = None
         if self.pos == "learned":
             x = x + params["pos"][pos]
-        else:
-            rope = self._rope_angles(pos, Dh)                # (dh/2,)
+        ropes = self._rope_tables(pos)                       # (r/2,) each
         new_caches = []
-        for blk, cache in zip(params["blocks"], caches):
+        for spec, blk, cache in zip(self.blocks, params["blocks"], caches):
             h = self._rms(x, blk["norm1"])
             q, k, v = self._project_qkv(blk, h)
-            q = q.reshape(KVH, group, Dh)
+            q = q.reshape(KVH, spec.heads // KVH, Dh)
             k = k.reshape(KVH, Dh)
-            if rope is not None:
+            if spec.rope is not None:
                 # Rotate q and k at THIS position; the cache stores
                 # post-rotation keys (standard RoPE decode).
-                q = self._rope_rotate(q, *rope)
-                k = self._rope_rotate(k, *rope)
+                q = self._rope_rotate(q, *ropes[spec.rope])
+                k = self._rope_rotate(k, *ropes[spec.rope])
             k_cache = cache["k"].at[pos].set(k)
             v_cache = cache["v"].at[pos].set(v.reshape(KVH, Dh))
             new_caches.append({"k": k_cache, "v": v_cache})
@@ -377,15 +593,16 @@ class TinyLM:
             s = s / (Dh ** 0.5)
             kv_pos = jnp.arange(k_cache.shape[0])
             mask = kv_pos <= pos
-            if self.window is not None:
+            if spec.window is not None:
                 # A windowed model must decode windowed, or inference
                 # silently runs a different model than training.
-                mask = mask & (kv_pos > pos - self.window)
+                mask = mask & (kv_pos > pos - spec.window)
             s = jnp.where(mask[None, None, :], s, -jnp.inf)
             p = jax.nn.softmax(s, axis=-1)
             attn = jnp.einsum("kgs,skd->kgd", p.astype(v_cache.dtype),
                               v_cache, preferred_element_type=jnp.float32)
-            x = self._block_tail(blk, x, attn.astype(x.dtype).reshape(-1))
+            x = self._block_tail(spec, blk, x,
+                                 attn.astype(x.dtype).reshape(-1))
         x = self._rms(x, params["final_norm"])
         return new_caches, x @ params["out"]
 
@@ -456,19 +673,90 @@ class TinyLM:
         return jnp.concatenate([prompt, out])
 
 
-def make_train_step(model: TinyLM, optimizer, batched: bool = False):
+class TinyLM(BlockLM):
+    """Causal byte/token LM. ``attention`` picks the plane:
+    ``"ring"`` (sequence sharded via ppermute ring + online softmax),
+    ``"ulysses"`` (all-to-all head/seq swap; needs
+    ``heads % n_devices == 0``), ``"flash"`` (the Pallas
+    flash-attention kernels, forward AND backward — single device runs
+    them directly with the whole sequence in HBM and scores streamed
+    through VMEM; pass a multi-device ``mesh=`` and the sequence
+    shards over the ring with the kernel as every rotation's
+    per-device block), or
+    ``"reference"`` (full score matrix, single device — for parity
+    tests).
+
+    The flash kernels compile through Mosaic and need a TPU; on any
+    other platform ``attention="flash"`` is refused at construction
+    unless ``interpret=True`` asks for the Pallas interpreter (slow,
+    for CPU tests). The library never picks the interpreter itself.
+
+    ``pos`` picks the positional scheme: ``"learned"`` (absolute
+    table, the default) or ``"rope"`` (rotary embeddings on q/k per
+    layer — relative positions, the modern long-context choice; no
+    position table in the params).
+
+    ``apply(params, tokens (S,)) -> (S, vocab)`` logits;
+    ``loss(params, tokens)`` is mean next-token cross-entropy.
+    ``S`` must equal ``max_seq`` (static shapes; pad shorter text).
+    """
+
+    def __init__(
+        self,
+        vocab: int = 256,
+        dim: int = 64,
+        heads: int = 8,
+        layers: int = 2,
+        max_seq: int = 256,
+        mlp_mult: int = 4,
+        mesh=None,
+        attention: str = "ring",
+        kv_heads: Optional[int] = None,
+        pos: str = "learned",
+        window: Optional[int] = None,
+        interpret: bool = False,
+    ) -> None:
+        if dim % heads:
+            raise ValueError(f"dim {dim} not divisible by heads {heads}")
+        if pos == "rope" and (dim // heads) % 2:
+            raise ValueError("rope needs an even head_dim")
+        if kv_heads is not None and kv_heads < 1:
+            # 0 must not silently mean "full MHA" (a GQA A/B would
+            # quietly measure nothing) and negatives pass Python's
+            # modulo only to crash deep inside init().
+            raise ValueError(f"kv_heads must be >= 1, got {kv_heads}")
+        kv_heads = kv_heads or heads
+        block = Block(heads=heads, window=window,
+                      rope=Rope() if pos == "rope" else None,
+                      ffn="mlp", width=mlp_mult * dim)
+        super().__init__([block] * layers, vocab=vocab, dim=dim,
+                         head_dim=dim // heads, kv_heads=kv_heads,
+                         max_seq=max_seq, attention=attention, pos=pos,
+                         mesh=mesh, interpret=interpret)
+        self.heads = heads
+        self.mlp_mult = mlp_mult
+        #: causal sliding window (flash plane only; None = full causal)
+        self.window = window
+
+
+def make_train_step(model: BlockLM, optimizer, batched: bool = False,
+                    donate: bool = False):
     """(params, opt_state, tokens) -> (params, opt_state, loss), jitted.
     ``optimizer`` is any optax-style (init, update) pair. With
     ``batched=True`` tokens is (B, max_seq) and the loss is the batch
     mean — the batch axis vmaps straight over the sequence-sharded
-    attention (each sequence still spans the mesh).
+    attention (each sequence still spans the mesh). With
+    ``donate=True`` the step donates ``params`` and ``opt_state``: the
+    new state is written over the old one (which the caller must not
+    touch again), so one copy of the state is live in a step, not two.
 
     What comes back is a plain function around the jitted step: each
     call is one ``lm.train_step`` span (docs/observability.md; compile
     spans hang from it, device idle time is charged to it) and moves
     the ``device_steps`` / ``device_step_units`` counters by one call
-    and the argument's token count. It keeps the jitted step's
-    ``lower`` and ``__name__``."""
+    and the argument's token count; the span also carries the model's
+    ``span_fields`` (layer kinds, the expert share). It keeps the jitted
+    step's ``lower`` and ``__name__``."""
     import math
 
     import jax
@@ -477,6 +765,13 @@ def make_train_step(model: TinyLM, optimizer, batched: bool = False):
     from fiber_tpu.utils.jaxcompat import ensure_compile_cache
 
     ensure_compile_cache()
+    if batched and any(b.ffn == "experts" for b in model.blocks):
+        # jax.lax.ragged_dot has no batching rule over its rows; without
+        # this check the mistake surfaces as a NotImplementedError deep
+        # inside the first trace.
+        raise ValueError(
+            "batched=True cannot vmap over an expert layer's dispatch; "
+            "give a model with expert layers one sequence a step")
     if batched:
         def loss_fn(params, tokens):
             import jax.numpy as jnp
@@ -499,12 +794,14 @@ def make_train_step(model: TinyLM, optimizer, batched: bool = False):
                 lambda p, u: p + u, params, updates)
         return params, opt_state, loss
 
-    jitted = jax.jit(step)
+    jitted = jax.jit(step, donate_argnums=(0, 1) if donate else ())
+    fields = model.span_fields
 
     def spanned(call):
         def run(params, opt_state, tokens):
             n = math.prod(tokens.shape)
-            with device_telemetry.step("lm.train_step", n, tokens=n):
+            with device_telemetry.step("lm.train_step", n, tokens=n,
+                                       **fields):
                 return call(params, opt_state, tokens)
 
         run.__name__ = call.__name__

@@ -91,6 +91,18 @@ _m_rollout_traces = telemetry.counter(
     "Env rollouts traced, by policy class and params: 'prepared' "
     "(unflattened once, before the step scan) or 'flat' (cut on every "
     "step: the callable's owner offers no unflatten)")
+_m_moe_traces = telemetry.counter(
+    "moe_layers_traced",
+    "Sparse-expert layers traced, by experts held here, experts in "
+    "all and experts a token takes")
+_g_moe_load_max = telemetry.gauge(
+    "moe_expert_load_max",
+    "Tokens of the last probed batch on the most loaded held expert, "
+    "by expert layer")
+_g_moe_load_mean = telemetry.gauge(
+    "moe_expert_load_mean",
+    "Tokens of the last probed batch on a held expert, mean over the "
+    "held experts, by expert layer")
 _g_hbm_in_use = telemetry.gauge(
     "device_hbm_bytes_in_use", "HBM bytes in use on the first local device")
 _g_hbm_limit = telemetry.gauge(
@@ -589,6 +601,22 @@ def rollout_traced(policy: str, prepared: bool) -> None:
     once per compilation."""
     _m_rollout_traces.inc(
         policy=policy, params="prepared" if prepared else "flat")
+
+
+def moe_traced(held: int, total: int, top_k: int) -> None:
+    """One sparse-expert layer was traced (``ops/moe.py``). Counts
+    traces, not calls, like ``rollout_traced``: an operator reads off it
+    which share of the experts the compiled program holds."""
+    _m_moe_traces.inc(held=str(held), total=str(total), top_k=str(top_k))
+
+
+def moe_load(loads) -> None:
+    """The load of each held expert on one probed batch
+    (``BlockLM.probe_routing``): ``loads[layer][expert]`` tokens."""
+    for layer, load in enumerate(loads):
+        load = [float(x) for x in load]
+        _g_moe_load_max.set(max(load), layer=str(layer))
+        _g_moe_load_mean.set(sum(load) / len(load), layer=str(layer))
 
 
 def snapshot() -> Dict[str, Any]:

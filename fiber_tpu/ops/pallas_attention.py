@@ -37,7 +37,7 @@ def _run_window(iq, ik, block_q, block_kv, causal, window):
     """Static-shape block-skip predicate: False when the (q-block,
     kv-block) pair can contribute nothing — above the causal diagonal,
     or (with a sliding window) entirely older than every q row's
-    window. Skipped blocks are what turn O(S^2) into O(S*window)."""
+    window. `_kv_span` / `_q_span` are its runs, which are contiguous."""
     import jax.numpy as jnp
 
     if not causal:
@@ -65,25 +65,94 @@ def _keep_mask(iq, ik, block_q, block_kv, window):
     return keep
 
 
-def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref,
-                acc_ref, *, block_q: int, block_kv: int, n_kv: int,
-                causal: bool, scale: float, window=None):
-    """One (head, q-block, kv-block) grid step.
+# The band: the blocks `_run_window` lets run for one q-block (or, in
+# dkv, for one kv-block) are contiguous, so each kernel's innermost grid
+# axis spans only the widest such run, and inner step j names block
+# first + j. The spans below are `_run_window` solved for the other
+# index, on program ids, in the kernels and in the index maps alike.
+# They bind `lax` primitives directly: a kernel and its index maps
+# evaluate a span a dozen times a layer, and each `jnp` operator on a
+# tracer is a jit lookup (it showed as two seconds of set-up).
 
-    Grid = (heads, S/block_q, S/block_kv), kv innermost: the VMEM
-    scratch accumulators (m, l, acc) persist across the kv sweep of one
-    (head, q-block) and are re-initialized when kv==0. At kv==n_kv-1 the
-    normalized output block and the logsumexp (the backward residual)
-    are written once.
+
+def _kv_span(iq, block_q, block_kv, causal, window):
+    """Oldest and newest kv-block that q-block ``iq`` attends; None
+    without a causal mask (every block, in the grid's own order)."""
+    from jax import lax
+
+    if not causal:
+        return None
+    row0 = lax.mul(iq, block_q)
+    last = lax.div(lax.add(row0, block_q - 1), block_kv)
+    if window is None:
+        return 0, last
+    return lax.div(lax.max(lax.sub(row0, window - 1), 0), block_kv), last
+
+
+def _q_span(ik, block_q, block_kv, n_q, causal, window):
+    """Earliest and latest q-block that attends kv-block ``ik``; None
+    without a causal mask."""
+    from jax import lax
+
+    if not causal:
+        return None
+    col0 = lax.mul(ik, block_kv)
+    first = lax.div(col0, block_q)
+    if window is None:
+        return first, n_q - 1
+    return first, lax.min(
+        lax.div(lax.add(col0, block_kv + window - 2), block_q), n_q - 1)
+
+
+def _band_block(j, span):
+    """Inner grid step ``j`` of a band -> (block index, live). A step
+    past the end of a short span (early rows of a window, steps above
+    the causal diagonal) repeats the span's last block: its index equals
+    its neighbour's, so the pipeline fetches nothing, and ``live`` is
+    False, so nothing is computed. With no span, step j is block j."""
+    from jax import lax
+
+    if span is None:
+        return j, True
+    first, last = span
+    at = lax.add(j, first)
+    return lax.min(at, last), lax.le(at, last)
+
+
+def _band_extents(n_q, n_kv, block_q, block_kv, causal, window):
+    """(widest kv span of a q-block, widest q span of a kv-block,
+    block pairs that run), from `_run_window` itself over all pairs."""
+    import numpy as np
+
+    if not causal:
+        return n_kv, n_q, n_q * n_kv
+    run = _run_window(np.arange(n_q)[:, None], np.arange(n_kv)[None, :],
+                      block_q, block_kv, causal, window)
+    return (int(run.sum(axis=1).max()), int(run.sum(axis=0).max()),
+            int(run.sum()))
+
+
+def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref,
+                acc_ref, *, block_q: int, block_kv: int, n_band: int,
+                causal: bool, scale: float, window=None):
+    """One (head, q-block, band step) grid step.
+
+    Grid = (heads, S/block_q, n_band), the band of kv-blocks innermost:
+    the VMEM scratch accumulators (m, l, acc) persist across the kv
+    sweep of one (head, q-block) and are re-initialized at its first
+    step. At its last step the normalized output block and the
+    logsumexp (the backward residual) are written once.
     """
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
 
     iq = pl.program_id(1)
-    ik = pl.program_id(2)
+    j = pl.program_id(2)
+    ik, live = _band_block(
+        j, _kv_span(iq, block_q, block_kv, causal, window))
 
-    @pl.when(ik == 0)
+    @pl.when(j == 0)
     def _init():
         m_ref[:] = jnp.full_like(m_ref, _NEG_INF)
         l_ref[:] = jnp.zeros_like(l_ref)
@@ -92,7 +161,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref,
     # Causal: KV blocks strictly above the diagonal contribute nothing;
     # a sliding window also skips blocks entirely older than the
     # window. (Skipped BLOCKS; boundary blocks mask elementwise.)
-    run = _run_window(iq, ik, block_q, block_kv, causal, window)
+    run = live & _run_window(iq, ik, block_q, block_kv, causal, window)
 
     @pl.when(run)
     def _accumulate():
@@ -122,7 +191,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref,
         )
         m_ref[:] = m_new
 
-    @pl.when(ik == n_kv - 1)
+    @pl.when(j == n_band - 1)
     def _finalize():
         l = l_ref[:]
         safe_l = jnp.where(l == 0.0, 1.0, l)         # fully-masked rows
@@ -172,20 +241,22 @@ def _bwd_p_ds(q, k, v, do, lse, delta, iq, ik, *, block_q, block_kv,
 
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                    dq_ref, dq_acc, *, block_q: int, block_kv: int,
-                   n_kv: int, causal: bool, scale: float, window=None):
-    """Grid (heads, n_q, n_kv), kv innermost: accumulate dq for one
-    q-block across the KV sweep."""
+                   n_band: int, causal: bool, scale: float, window=None):
+    """Grid (heads, n_q, n_band), the band of kv-blocks innermost:
+    accumulate dq for one q-block across its KV sweep."""
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
 
     iq = pl.program_id(1)
-    ik = pl.program_id(2)
+    j = pl.program_id(2)
+    ik, live = _band_block(
+        j, _kv_span(iq, block_q, block_kv, causal, window))
 
-    @pl.when(ik == 0)
+    @pl.when(j == 0)
     def _init():
         dq_acc[:] = jnp.zeros_like(dq_acc)
 
-    run = _run_window(iq, ik, block_q, block_kv, causal, window)
+    run = live & _run_window(iq, ik, block_q, block_kv, causal, window)
 
     @pl.when(run)
     def _accumulate():
@@ -203,33 +274,36 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             preferred_element_type=jnp.float32,
         )
 
-    @pl.when(ik == n_kv - 1)
+    @pl.when(j == n_band - 1)
     def _finalize():
         dq_ref[0] = (dq_acc[:] * scale).astype(dq_ref.dtype)
 
 
 def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                     dk_ref, dv_ref, dk_acc, dv_acc, *, block_q: int,
-                    block_kv: int, n_q: int, group: int, causal: bool,
-                    scale: float, window=None):
-    """Grid (kv_heads, n_kv, group, n_q), (group, q) innermost:
-    accumulate dk and dv for one kv-block across the Q sweep of EVERY
-    query head sharing that KV head (GQA: ``group`` query heads per KV
-    head; MHA is group == 1). The two inner grid axes keep each output
-    block's revisits contiguous — the TPU accumulation-grid rule."""
+                    block_kv: int, n_q: int, n_band: int, group: int,
+                    causal: bool, scale: float, window=None):
+    """Grid (kv_heads, n_kv, group, n_band), (group, band of q-blocks)
+    innermost: accumulate dk and dv for one kv-block across the Q sweep
+    of EVERY query head sharing that KV head (GQA: ``group`` query heads
+    per KV head; MHA is group == 1). The two inner grid axes keep each
+    output block's revisits contiguous — the TPU accumulation-grid
+    rule."""
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
 
     ik = pl.program_id(1)
     g = pl.program_id(2)
-    iq = pl.program_id(3)
+    j = pl.program_id(3)
+    iq, live = _band_block(
+        j, _q_span(ik, block_q, block_kv, n_q, causal, window))
 
-    @pl.when((g == 0) & (iq == 0))
+    @pl.when((g == 0) & (j == 0))
     def _init():
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
 
-    run = _run_window(iq, ik, block_q, block_kv, causal, window)
+    run = live & _run_window(iq, ik, block_q, block_kv, causal, window)
 
     @pl.when(run)
     def _accumulate():
@@ -251,7 +325,7 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             preferred_element_type=jnp.float32,
         )
 
-    @pl.when((g == group - 1) & (iq == n_q - 1))
+    @pl.when((g == group - 1) & (j == n_band - 1))
     def _finalize():
         dk_ref[0] = (dk_acc[:] * scale).astype(dk_ref.dtype)
         dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
@@ -286,10 +360,11 @@ def flash_attention(q, k, v, *, causal: bool = False,
     Returns (S, heads, head_dim) in q's dtype.
 
     ``window`` (requires ``causal=True``) restricts every position to
-    the last ``window`` tokens (self included): KV blocks entirely
-    outside the window are skipped at the grid level, so compute drops
-    from O(S^2) to O(S*window) — the standard local-attention layer of
-    sliding-window transformers. Composes with GQA.
+    the last ``window`` tokens (self included): the kernels' grids hold
+    only the band of KV blocks inside the window, so compute and the
+    blocks moved drop from O(S^2) to O(S*window) — the standard
+    local-attention layer of sliding-window transformers. Composes with
+    GQA.
 
     The kernels compile through Mosaic and need a TPU: off-TPU the
     default raises (Pallas refuses to compile for the platform).
@@ -337,8 +412,11 @@ def _build_calls(shape, dtype, causal, block_q, block_kv, interpret,
     shared by the out-only and the (out, lse) entry points.
 
     ``window`` (causal only) restricts attention to the last
-    ``window`` positions — whole KV blocks outside every q row's
-    window are SKIPPED, turning O(S^2) into O(S*window).
+    ``window`` positions. Each kernel's innermost grid axis spans only
+    the band of blocks that `_run_window` lets run (2 kv-blocks for
+    window 512 at 512-blocks, all of them without a window), so blocks
+    outside every q row's window cost neither arithmetic nor a copy:
+    O(S*window) for O(S^2). A non-causal call has the square grid.
 
     ``kv_heads`` < heads enables grouped-query attention: K/V carry
     kv_heads heads and every group of ``heads // kv_heads`` query heads
@@ -350,6 +428,7 @@ def _build_calls(shape, dtype, causal, block_q, block_kv, interpret,
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
+    from fiber_tpu.telemetry import device as device_telemetry
     from fiber_tpu.utils.jaxcompat import ensure_compile_cache
 
     ensure_compile_cache()
@@ -369,20 +448,38 @@ def _build_calls(shape, dtype, causal, block_q, block_kv, interpret,
     n_kv = s // bk
     scale = 1.0 / (d ** 0.5)
 
-    qkv_spec_q = pl.BlockSpec((1, bq, d), lambda ih, iq, ik: (ih, iq, 0))
+    # Inner extents: the widest band any q-block (kv-block, for dkv)
+    # has; the index maps name a band step's block as the kernels do.
+    n_band_kv, n_band_q, run_pairs = _band_extents(
+        n_q, n_kv, bq, bk, causal, window)
+    # flash_grid_steps{kernel, state}: the inner steps one head makes
+    # (dkv: one kv-head, its group's sweeps), run and idle.
+    for kernel, run, steps in (
+            ("flash_attn_fwd", run_pairs, n_q * n_band_kv),
+            ("flash_attn_dq", run_pairs, n_q * n_band_kv),
+            ("flash_attn_dkv", group * run_pairs, group * n_kv * n_band_q)):
+        device_telemetry.flash_grid_built(kernel, run, steps - run)
+
+    def kv_of(iq, j):
+        return _band_block(j, _kv_span(iq, bq, bk, causal, window))[0]
+
+    def q_of(ik, j):
+        return _band_block(j, _q_span(ik, bq, bk, n_q, causal, window))[0]
+
+    qkv_spec_q = pl.BlockSpec((1, bq, d), lambda ih, iq, j: (ih, iq, 0))
     qkv_spec_k = pl.BlockSpec(
-        (1, bk, d), lambda ih, iq, ik: (ih // group, ik, 0))
+        (1, bk, d), lambda ih, iq, j: (ih // group, kv_of(iq, j), 0))
     # Per-row statistics (lse, delta) travel as (h, s, 1) columns:
     # Mosaic wants the last two block dims divisible by (8, 128) or
     # equal to the array's, which a (1, bq) row block of an (h, s)
     # array is not — and the kernels consume them as columns anyway.
-    row_spec_q = pl.BlockSpec((1, bq, 1), lambda ih, iq, ik: (ih, iq, 0))
+    row_spec_q = pl.BlockSpec((1, bq, 1), lambda ih, iq, j: (ih, iq, 0))
 
     fwd_call = pl.pallas_call(
         functools.partial(_fwd_kernel, block_q=bq, block_kv=bk,
-                          n_kv=n_kv, causal=causal, scale=scale,
+                          n_band=n_band_kv, causal=causal, scale=scale,
                           window=window),
-        grid=(h, n_q, n_kv),
+        grid=(h, n_q, n_band_kv),
         in_specs=[qkv_spec_q, qkv_spec_k, qkv_spec_k],
         out_specs=[qkv_spec_q, row_spec_q],
         out_shape=[jax.ShapeDtypeStruct((h, s, d), dtype),
@@ -398,9 +495,9 @@ def _build_calls(shape, dtype, causal, block_q, block_kv, interpret,
 
     dq_call = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, block_q=bq, block_kv=bk,
-                          n_kv=n_kv, causal=causal, scale=scale,
+                          n_band=n_band_kv, causal=causal, scale=scale,
                           window=window),
-        grid=(h, n_q, n_kv),
+        grid=(h, n_q, n_band_kv),
         in_specs=[qkv_spec_q, qkv_spec_k, qkv_spec_k, qkv_spec_q,
                   row_spec_q, row_spec_q],
         out_specs=qkv_spec_q,
@@ -410,20 +507,22 @@ def _build_calls(shape, dtype, causal, block_q, block_kv, interpret,
         name="flash_attn_dq",
     )
 
-    # dkv grid is (kv_heads, n_kv, group, n_q): program ids land as
-    # (ikv, ik, g, iq); (g, iq) innermost so each (ikv, ik) output
+    # dkv grid is (kv_heads, n_kv, group, n_band_q): program ids land as
+    # (ikv, ik, g, j); (g, j) innermost so each (ikv, ik) output
     # block's revisits are contiguous.
     dkv_q_spec = pl.BlockSpec(
-        (1, bq, d), lambda ikv, ik, g, iq: (ikv * group + g, iq, 0))
+        (1, bq, d),
+        lambda ikv, ik, g, j: (ikv * group + g, q_of(ik, j), 0))
     dkv_k_spec = pl.BlockSpec(
-        (1, bk, d), lambda ikv, ik, g, iq: (ikv, ik, 0))
+        (1, bk, d), lambda ikv, ik, g, j: (ikv, ik, 0))
     dkv_row_spec = pl.BlockSpec(
-        (1, bq, 1), lambda ikv, ik, g, iq: (ikv * group + g, iq, 0))
+        (1, bq, 1),
+        lambda ikv, ik, g, j: (ikv * group + g, q_of(ik, j), 0))
     dkv_call = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, block_q=bq, block_kv=bk,
-                          n_q=n_q, group=group, causal=causal,
-                          scale=scale, window=window),
-        grid=(kvh, n_kv, group, n_q),
+                          n_q=n_q, n_band=n_band_q, group=group,
+                          causal=causal, scale=scale, window=window),
+        grid=(kvh, n_kv, group, n_band_q),
         in_specs=[dkv_q_spec, dkv_k_spec, dkv_k_spec, dkv_q_spec,
                   dkv_row_spec, dkv_row_spec],
         out_specs=[dkv_k_spec, dkv_k_spec],

@@ -95,6 +95,11 @@ _m_moe_traces = telemetry.counter(
     "moe_layers_traced",
     "Sparse-expert layers traced, by experts held here, experts in "
     "all and experts a token takes")
+_m_flash_grid_steps = telemetry.counter(
+    "flash_grid_steps",
+    "Inner grid steps a head makes in the flash-attention programs "
+    "built (for dkv a kv-head), by kernel and state: 'run' computes, "
+    "'idle' only exists")
 _g_moe_load_max = telemetry.gauge(
     "moe_expert_load_max",
     "Tokens of the last probed batch on the most loaded held expert, "
@@ -608,6 +613,17 @@ def moe_traced(held: int, total: int, top_k: int) -> None:
     traces, not calls, like ``rollout_traced``: an operator reads off it
     which share of the experts the compiled program holds."""
     _m_moe_traces.inc(held=str(held), total=str(total), top_k=str(top_k))
+
+
+def flash_grid_built(kernel: str, run: int, idle: int) -> None:
+    """One flash-attention kernel was built
+    (``ops/pallas_attention.py``) whose inner grid axis makes, for one
+    head, ``run`` steps that compute and ``idle`` steps that only
+    exist. Counts programs built, not calls: an operator reads off it
+    whether a new shape (other blocks, an unaligned window) fell back
+    to a wide band."""
+    _m_flash_grid_steps.inc(run, kernel=kernel, state="run")
+    _m_flash_grid_steps.inc(idle, kernel=kernel, state="idle")
 
 
 def moe_load(loads) -> None:

@@ -538,29 +538,63 @@ def _windowed_reference(q, k, v, window):
     return jnp.einsum("hqk,khd->qhd", p, v)
 
 
-@pytest.mark.parametrize("window", [64, 100, 256])
-def test_flash_sliding_window_matches_reference(window):
+def _windowed_lse(q, k, v, window):
+    """The windowed logsumexp (heads, S) of the scaled scores."""
+    s = jnp.einsum("qhd,khd->hqk", q, k) / jnp.sqrt(
+        jnp.asarray(q.shape[-1], q.dtype))
+    qpos = jnp.arange(q.shape[0])[:, None]
+    kpos = jnp.arange(q.shape[0])[None, :]
+    keep = (qpos >= kpos) & (qpos - kpos < window)
+    return jax.nn.logsumexp(jnp.where(keep[None], s, -jnp.inf), axis=-1)
+
+
+def _rand_gqa(s, h, kvh, d, seed=13):
+    kq, kk, kv = jax.random.split(jax.random.PRNGKey(seed), 3)
+    return (jax.random.normal(kq, (s, h, d)),
+            jax.random.normal(kk, (s, kvh, d)),
+            jax.random.normal(kv, (s, kvh, d)))
+
+
+# (S, block_q, block_kv, window): the first three are one-block-wide
+# sequences of 4 blocks; in the others the band of blocks that run is
+# narrower than the row (n_band < n_kv), with aligned (128), unaligned
+# (100, 300) windows and block_q != block_kv both ways.
+_WINDOW_CASES = [
+    (512, 128, 128, 64), (512, 128, 128, 100), (512, 128, 128, 256),
+    (1024, 128, 128, 128), (1024, 128, 128, 100), (1024, 128, 128, 300),
+    (1024, 128, 256, 100), (1024, 256, 128, 100), (1024, 128, 256, 300),
+    (1024, 256, 128, 300),
+]
+
+
+@pytest.mark.parametrize("s,block_q,block_kv,window", _WINDOW_CASES)
+def test_flash_sliding_window_matches_reference(s, block_q, block_kv,
+                                                window):
     """window= restricts attention to the last `window` positions;
-    block-aligned (64), unaligned (100), and wider-than-one-block (256)
-    windows must all match explicit masking — the block-skip predicate
-    AND the elementwise boundary mask are both load-bearing."""
-    q, k, v = _rand_qkv(512, 2, 32)
+    block-aligned, unaligned and wider-than-one-block windows must all
+    match explicit masking — the band's offset and clamp, the block-skip
+    predicate AND the elementwise boundary mask are all load-bearing."""
+    q, k, v = _rand_qkv(s, 2, 32)
     got = np.asarray(flash_attention(
-        q, k, v, causal=True, window=window, block_q=128, block_kv=128,
-        interpret=True))
+        q, k, v, causal=True, window=window, block_q=block_q,
+        block_kv=block_kv, interpret=True))
     want = np.asarray(_windowed_reference(q, k, v, window))
     assert np.abs(got - want).max() < 2e-5
 
 
-def test_flash_sliding_window_gradients():
+@pytest.mark.parametrize(
+    "s,block_q,block_kv,window",
+    [(384, 128, 128, 100)] + _WINDOW_CASES[3:])
+def test_flash_sliding_window_gradients(s, block_q, block_kv, window):
     """Windowed backward: dq/dk/dv match differentiating the explicit
-    mask (the skip predicate must not drop boundary contributions)."""
-    q, k, v = _rand_qkv(384, 2, 32)
-    window = 100
+    mask (neither the skip predicate nor the band's clamp may drop a
+    boundary contribution, in dq's kv sweep or in dkv's q sweep)."""
+    q, k, v = _rand_qkv(s, 2, 32)
 
     def loss_flash(q, k, v):
         o = flash_attention(q, k, v, causal=True, window=window,
-                            block_q=128, block_kv=128, interpret=True)
+                            block_q=block_q, block_kv=block_kv,
+                            interpret=True)
         return jnp.sum(o ** 2)
 
     def loss_ref(q, k, v):
@@ -582,19 +616,337 @@ def test_flash_window_validation():
         flash_attention(q, k, v, causal=True, window=0, interpret=True)
 
 
-def test_flash_window_with_gqa():
-    """Sliding window composes with grouped-query attention."""
-    S, H, KVH, D = 256, 4, 2, 32
-    kq, kk, kv = jax.random.split(jax.random.PRNGKey(13), 3)
-    q = jax.random.normal(kq, (S, H, D))
-    k = jax.random.normal(kk, (S, KVH, D))
-    v = jax.random.normal(kv, (S, KVH, D))
-    got = np.asarray(flash_attention(
-        q, k, v, causal=True, window=96, block_q=128, block_kv=128,
-        interpret=True))
-    want = np.asarray(_windowed_reference(
-        q, jnp.repeat(k, 2, axis=1), jnp.repeat(v, 2, axis=1), 96))
-    assert np.abs(got - want).max() < 2e-5
+@pytest.mark.parametrize("s,h,kvh,window,block_q,block_kv", [
+    (256, 4, 2, 96, 128, 128),
+    (1024, 8, 2, 100, 128, 128),
+    (1024, 4, 1, 300, 256, 128),
+])
+def test_flash_window_with_gqa(s, h, kvh, window, block_q, block_kv):
+    """Sliding window composes with grouped-query attention, forward
+    and backward: dkv's (group, band) sweep accumulates every query
+    head of the group over the band of q-blocks that reach a kv-block."""
+    group = h // kvh
+    q, k, v = _rand_gqa(s, h, kvh, 32)
+
+    def flash(q, k, v):
+        return flash_attention(
+            q, k, v, causal=True, window=window, block_q=block_q,
+            block_kv=block_kv, interpret=True)
+
+    def ref(q, k, v):
+        return _windowed_reference(
+            q, jnp.repeat(k, group, axis=1), jnp.repeat(v, group, axis=1),
+            window)
+
+    assert np.abs(np.asarray(flash(q, k, v))
+                  - np.asarray(ref(q, k, v))).max() < 2e-5
+    gf = jax.grad(lambda *a: jnp.sum(flash(*a) ** 2), (0, 1, 2))(q, k, v)
+    gr = jax.grad(lambda *a: jnp.sum(ref(*a) ** 2), (0, 1, 2))(q, k, v)
+    for name, a, b in zip("qkv", gf, gr):
+        assert a.shape == b.shape, (name, a.shape, b.shape)
+        rel = float(jnp.abs(a - b).max() / (jnp.abs(b).max() + 1e-9))
+        assert rel < 1e-4, (name, rel)
+
+
+@pytest.mark.parametrize("s,h,kvh,window,block_q,block_kv", [
+    (1024, 2, 2, 100, 128, 128),
+    (1024, 4, 1, 300, 128, 256),
+])
+def test_flash_window_lse_and_its_cotangent(s, h, kvh, window, block_q,
+                                            block_kv):
+    """flash_attention_lse under a window: the lse is the windowed
+    logsumexp, and a non-zero lse cotangent reaches dq, dk and dv
+    through the banded backward kernels (delta - dlse)."""
+    from fiber_tpu.ops.pallas_attention import flash_attention_lse
+
+    group = h // kvh
+    q, k, v = _rand_gqa(s, h, kvh, 32, seed=21)
+    w = jax.random.normal(jax.random.PRNGKey(5), (h, s))
+
+    def loss_flash(q, k, v):
+        o, lse = flash_attention_lse(
+            q, k, v, causal=True, window=window, block_q=block_q,
+            block_kv=block_kv, interpret=True)
+        return jnp.sum(o ** 2) + jnp.sum(w * lse)
+
+    def loss_ref(q, k, v):
+        k, v = (jnp.repeat(x, group, axis=1) for x in (k, v))
+        return (jnp.sum(_windowed_reference(q, k, v, window) ** 2)
+                + jnp.sum(w * _windowed_lse(q, k, v, window)))
+
+    _, lse = flash_attention_lse(
+        q, k, v, causal=True, window=window, block_q=block_q,
+        block_kv=block_kv, interpret=True)
+    want = _windowed_lse(q, jnp.repeat(k, group, axis=1), None, window)
+    assert np.abs(np.asarray(lse) - np.asarray(want)).max() < 2e-5
+    gf = jax.grad(loss_flash, (0, 1, 2))(q, k, v)
+    gr = jax.grad(loss_ref, (0, 1, 2))(q, k, v)
+    for name, a, b in zip("qkv", gf, gr):
+        rel = float(jnp.abs(a - b).max() / (jnp.abs(b).max() + 1e-9))
+        assert rel < 1e-4, (name, rel)
+
+
+@pytest.mark.parametrize("block_q,block_kv", [
+    (128, 128), (128, 256), (256, 128), (384, 128), (128, 384), (512, 512)])
+def test_band_spans_are_the_runs_of_run_window(block_q, block_kv):
+    """`_kv_span` / `_q_span` (what the kernels and index maps evaluate
+    on program ids) name exactly the blocks `_run_window` lets run, for
+    every q-block and kv-block, aligned or not, and `_band_extents`
+    (what sizes the grids) is their widest."""
+    from fiber_tpu.ops.pallas_attention import (
+        _band_extents, _kv_span, _q_span, _run_window)
+
+    s = 3072
+    n_q, n_kv = s // block_q, s // block_kv
+    iq, ik = np.arange(n_q, dtype=np.int32), np.arange(n_kv, dtype=np.int32)
+    for window in (None, 1, 2, 100, 127, 128, 129, 256, 300, 513, 1000,
+                   3071, 3072, 5000):
+        run = np.asarray(_run_window(iq[:, None], ik[None, :], block_q,
+                                     block_kv, True, window))
+        first, last = (np.broadcast_to(np.asarray(x), iq.shape) for x in
+                       _kv_span(jnp.asarray(iq), block_q, block_kv, True, window))
+        want = (ik[None, :] >= first[:, None]) & (ik[None, :] <= last[:, None])
+        assert np.array_equal(run, want), ("kv", window)
+        first, last = (np.broadcast_to(np.asarray(x), ik.shape) for x in
+                       _q_span(jnp.asarray(ik), block_q, block_kv, n_q, True,
+                               window))
+        want = (iq[:, None] >= first[None, :]) & (iq[:, None] <= last[None, :])
+        assert np.array_equal(run, want), ("q", window)
+        assert _band_extents(n_q, n_kv, block_q, block_kv, True, window) == (
+            run.sum(1).max(), run.sum(0).max(), run.sum())
+    assert _band_extents(n_q, n_kv, block_q, block_kv, False, None) == (
+        n_kv, n_q, n_q * n_kv)
+
+
+def _inner_extents(s, h, kvh, causal, window, block=512):
+    """The innermost grid extent of the three built programs."""
+    from fiber_tpu.ops.pallas_attention import _build_calls
+
+    calls = _build_calls((s, h, 128), "float32", causal, block, block,
+                         True, kvh, window)
+    x = jax.ShapeDtypeStruct((h, s, 128), jnp.float32)
+    kv = jax.ShapeDtypeStruct((kvh or h, s, 128), jnp.float32)
+    col = jax.ShapeDtypeStruct((h, s, 1), jnp.float32)
+    args = [(x, kv, kv), (x, kv, kv, x, col, col), (x, kv, kv, x, col, col)]
+    grids = [jax.make_jaxpr(call)(*a).eqns[0].params["grid_mapping"].grid
+             for call, a in zip(calls, args)]
+    assert grids[0][:2] == grids[1][:2] == (h, s // block)
+    assert grids[2][:3] == (kvh or h, s // block, h // (kvh or h))
+    return [g[-1] for g in grids]
+
+
+@pytest.mark.parametrize("causal,window,extent", [
+    (True, 512, 2), (True, 4096, 9), (True, 500, 2), (True, 513, 2), (True, 514, 3),
+    (True, None, 16), (False, None, 16),
+])
+def test_flash_grids_are_the_band(causal, window, extent):
+    """At 8,192 tokens and 512-blocks the inner axis of all three grids
+    spans the band: 2 blocks for window 512, 9 for 4,096, all 16 without
+    a window (causal: the diagonal row needs them; non-causal: the grid
+    it always had). An unaligned window costs one more block at most."""
+    assert _inner_extents(8192, 8, 2, causal, window) == [extent] * 3
+
+
+def test_flash_grid_steps_counter():
+    """Building a program adds its inner steps a head to
+    flash_grid_steps{kernel, state}: window 512 at 8,192 tokens runs 31
+    of the band's 32 steps (256 on the square grid), dkv the same for
+    each of its kv-head's 4 query heads."""
+    import fiber_tpu
+    from fiber_tpu import telemetry
+    from fiber_tpu.ops.pallas_attention import _build_calls
+
+    fiber_tpu.init()
+    counter = telemetry.counter("flash_grid_steps")
+    kernels = ("flash_attn_fwd", "flash_attn_dq", "flash_attn_dkv")
+
+    def read():
+        return {(k, st): counter.value(kernel=k, state=st)
+                for k in kernels for st in ("run", "idle")}
+
+    def built(window, causal=True):
+        _build_calls.cache_clear()
+        before = read()
+        _build_calls((8192, 8, 128), "float32", causal, 512, 512, True, 2,
+                     window)
+        after = read()
+        return [(int(after[k, "run"] - before[k, "run"]),
+                 int(after[k, "idle"] - before[k, "idle"]))
+                for k in kernels]
+
+    assert built(512) == [(31, 1), (31, 1), (124, 4)]
+    assert built(4096) == [(108, 36), (108, 36), (432, 144)]
+    assert built(None) == [(136, 120), (136, 120), (544, 480)]
+    assert built(None, causal=False) == [(256, 0), (256, 0), (1024, 0)]
+
+
+def _square_grid_calls(shape, causal, bq, bk, kv_heads, window):
+    """The three programs as they were built before the band: every
+    kernel on the full (n_q, n_kv) square, a step outside the band
+    skipped by ``pl.when`` alone. Kept here, and not in the library, as
+    the yardstick the banded programs must equal bit for bit. Shares
+    the library's per-step arithmetic (`_run_window`, `_keep_mask`,
+    `_bwd_p_ds`), which the band did not touch."""
+    import functools
+
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    from fiber_tpu.ops.pallas_attention import (
+        _NEG_INF, _bwd_p_ds, _keep_mask, _run_window)
+
+    s, h, d = shape
+    kvh = kv_heads or h
+    group = h // kvh
+    n_q, n_kv = s // bq, s // bk
+    scale = 1.0 / (d ** 0.5)
+    f32 = jnp.float32
+    dot = functools.partial(jax.lax.dot_general,
+                            preferred_element_type=f32)
+    step = dict(block_q=bq, block_kv=bk, causal=causal, scale=scale,
+                window=window)
+
+    def fwd(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref):
+        iq, ik = pl.program_id(1), pl.program_id(2)
+
+        @pl.when(ik == 0)
+        def _():
+            m_ref[:] = jnp.full_like(m_ref, _NEG_INF)
+            l_ref[:] = jnp.zeros_like(l_ref)
+            acc_ref[:] = jnp.zeros_like(acc_ref)
+
+        @pl.when(_run_window(iq, ik, bq, bk, causal, window))
+        def _():
+            q, k, v = (r[0].astype(f32) for r in (q_ref, k_ref, v_ref))
+            sc = dot(q, k, (((1,), (1,)), ((), ()))) * scale
+            keep = None
+            if causal:
+                keep = _keep_mask(iq, ik, bq, bk, window)
+                sc = jnp.where(keep, sc, _NEG_INF)
+            m_prev = m_ref[:]
+            m_new = jnp.maximum(m_prev, jnp.max(sc, axis=-1, keepdims=True))
+            p = jnp.exp(sc - m_new)
+            if keep is not None:
+                p = jnp.where(keep, p, 0.0)
+            corr = jnp.exp(m_prev - m_new)
+            l_ref[:] = l_ref[:] * corr + jnp.sum(p, axis=-1, keepdims=True)
+            acc_ref[:] = acc_ref[:] * corr + dot(
+                p, v, (((1,), (0,)), ((), ())))
+            m_ref[:] = m_new
+
+        @pl.when(ik == n_kv - 1)
+        def _():
+            l = l_ref[:]
+            safe_l = jnp.where(l == 0.0, 1.0, l)
+            o_ref[0] = (acc_ref[:] / safe_l).astype(o_ref.dtype)
+            lse_ref[0] = m_ref[:] + jnp.log(safe_l)
+
+    def dq(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, acc):
+        iq, ik = pl.program_id(1), pl.program_id(2)
+
+        @pl.when(ik == 0)
+        def _():
+            acc[:] = jnp.zeros_like(acc)
+
+        @pl.when(_run_window(iq, ik, bq, bk, causal, window))
+        def _():
+            q, k, v, do = (r[0].astype(f32)
+                           for r in (q_ref, k_ref, v_ref, do_ref))
+            _, ds = _bwd_p_ds(q, k, v, do, lse_ref[0], delta_ref[0], iq,
+                              ik, **step)
+            acc[:] += dot(ds, k, (((1,), (0,)), ((), ())))
+
+        @pl.when(ik == n_kv - 1)
+        def _():
+            dq_ref[0] = (acc[:] * scale).astype(dq_ref.dtype)
+
+    def dkv(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref,
+            dv_ref, dk_acc, dv_acc):
+        ik, g, iq = (pl.program_id(i) for i in (1, 2, 3))
+
+        @pl.when((g == 0) & (iq == 0))
+        def _():
+            dk_acc[:] = jnp.zeros_like(dk_acc)
+            dv_acc[:] = jnp.zeros_like(dv_acc)
+
+        @pl.when(_run_window(iq, ik, bq, bk, causal, window))
+        def _():
+            q, k, v, do = (r[0].astype(f32)
+                           for r in (q_ref, k_ref, v_ref, do_ref))
+            p, ds = _bwd_p_ds(q, k, v, do, lse_ref[0], delta_ref[0], iq,
+                              ik, **step)
+            dv_acc[:] += dot(p, do, (((0,), (0,)), ((), ())))
+            dk_acc[:] += dot(ds, q, (((0,), (0,)), ((), ())))
+
+        @pl.when((g == group - 1) & (iq == n_q - 1))
+        def _():
+            dk_ref[0] = (dk_acc[:] * scale).astype(dk_ref.dtype)
+            dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
+
+    spec_q = pl.BlockSpec((1, bq, d), lambda ih, iq, ik: (ih, iq, 0))
+    spec_k = pl.BlockSpec((1, bk, d), lambda ih, iq, ik: (ih // group, ik, 0))
+    row_q = pl.BlockSpec((1, bq, 1), lambda ih, iq, ik: (ih, iq, 0))
+    hsd = jax.ShapeDtypeStruct((h, s, d), f32)
+    kvsd = jax.ShapeDtypeStruct((kvh, s, d), f32)
+    fwd_call = pl.pallas_call(
+        fwd, grid=(h, n_q, n_kv), in_specs=[spec_q, spec_k, spec_k],
+        out_specs=[spec_q, row_q],
+        out_shape=[hsd, jax.ShapeDtypeStruct((h, s, 1), f32)],
+        scratch_shapes=[pltpu.VMEM((bq, 1), f32), pltpu.VMEM((bq, 1), f32),
+                        pltpu.VMEM((bq, d), f32)],
+        interpret=True)
+    dq_call = pl.pallas_call(
+        dq, grid=(h, n_q, n_kv),
+        in_specs=[spec_q, spec_k, spec_k, spec_q, row_q, row_q],
+        out_specs=spec_q, out_shape=hsd,
+        scratch_shapes=[pltpu.VMEM((bq, d), f32)], interpret=True)
+    dkv_q = pl.BlockSpec(
+        (1, bq, d), lambda ikv, ik, g, iq: (ikv * group + g, iq, 0))
+    dkv_k = pl.BlockSpec((1, bk, d), lambda ikv, ik, g, iq: (ikv, ik, 0))
+    dkv_row = pl.BlockSpec(
+        (1, bq, 1), lambda ikv, ik, g, iq: (ikv * group + g, iq, 0))
+    dkv_call = pl.pallas_call(
+        dkv, grid=(kvh, n_kv, group, n_q),
+        in_specs=[dkv_q, dkv_k, dkv_k, dkv_q, dkv_row, dkv_row],
+        out_specs=[dkv_k, dkv_k], out_shape=[kvsd, kvsd],
+        scratch_shapes=[pltpu.VMEM((bk, d), f32), pltpu.VMEM((bk, d), f32)],
+        interpret=True)
+    return fwd_call, dq_call, dkv_call
+
+
+@pytest.mark.parametrize("causal,window,block_q,block_kv", [
+    (True, 100, 128, 128),      # windowed GQA, band 2 of 8
+    (True, 300, 128, 256),      # block_q != block_kv, unaligned window
+    (True, 300, 256, 128),
+    (True, None, 128, 128),     # full causal: steps above the diagonal
+    (False, None, 128, 128),    # the ring's past blocks: grid unchanged
+])
+def test_banded_kernels_equal_square_grid_bit_for_bit(causal, window,
+                                                      block_q, block_kv):
+    """The band changes which grid steps exist, never a sum or its
+    order: out, lse, dq, dk and dv of the banded programs equal the
+    square-grid programs' bit for bit (interpret mode, GQA group 2)."""
+    from fiber_tpu.ops.pallas_attention import _build_calls
+
+    S, H, KVH, D = 1024, 4, 2, 32
+    keys = jax.random.split(jax.random.PRNGKey(3), 5)
+    q, do = (jax.random.normal(key, (H, S, D)) for key in keys[:2])
+    k, v = (jax.random.normal(key, (KVH, S, D)) for key in keys[2:4])
+    dlse = jax.random.normal(keys[4], (H, S, 1))
+    outs = []
+    for fwd_call, dq_call, dkv_call in (
+            _build_calls((S, H, D), "float32", causal, block_q, block_kv,
+                         True, KVH, window),
+            _square_grid_calls((S, H, D), causal, block_q, block_kv, KVH,
+                               window)):
+        out, lse = fwd_call(q, k, v)
+        delta = jnp.sum(do * out, axis=-1, keepdims=True) - dlse
+        dq = dq_call(q, k, v, do, lse, delta)
+        dk, dv = dkv_call(q, k, v, do, lse, delta)
+        outs.append([np.asarray(x) for x in (out, lse, dq, dk, dv)])
+    for name, got, want in zip(("out", "lse", "dq", "dk", "dv"), *outs):
+        assert np.isfinite(want).all() and np.abs(want).max() > 0, name
+        assert np.array_equal(got, want), name
 
 
 def test_tiny_lm_rope_planes_and_decode():

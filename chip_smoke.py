@@ -44,7 +44,7 @@ import numpy as np
 import fiber_tpu
 
 # ---------------------------------------------------------------------------
-# Sizes. FULL is what the repo ships today (bench.py's shapes); REHEARSAL
+# Sizes. FULL is the smoke's own shapes (small: it checks results); REHEARSAL
 # cuts every one of them so the CPU and the Pallas interpreter finish in
 # about a minute.
 # ---------------------------------------------------------------------------

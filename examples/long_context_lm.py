@@ -21,8 +21,7 @@ kernels: on one device directly (whole sequence in HBM, scores
 streamed through VMEM), and on a multi-device mesh as the RING's
 per-device block — every rotation runs the kernel and the partial
 (out, lse) pairs merge exactly, so context length still scales with
-device count while the kernel does the math (`bench.py --lm` and
-`--attention` A/B the paths on chip).
+device count while the kernel does the math.
 """
 
 import os as _os

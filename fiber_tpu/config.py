@@ -58,7 +58,7 @@ DEFAULTS: Dict[str, Any] = {
     # Pool handout policy: "adaptive" = locality-aware placement + fair
     # multi-map queueing (and, when enabled below, straggler
     # speculation); "fifo" = the reference's plain arrival-order
-    # handout (also the bench.py --sched A/B baseline).
+    # handout.
     "sched_policy": "adaptive",
     # Prefer handing ref-bearing chunks to workers on hosts whose store
     # already caches the referenced objects.
@@ -113,8 +113,7 @@ DEFAULTS: Dict[str, Any] = {
     # ingress like pool result streams): how many frames a sender may
     # run ahead of the consumer. Large enough to never throttle by
     # default; lower it to bound per-peer master memory (window x frame
-    # size) — bench.py --transport also lowers it to pace its pushers
-    # into a steady stream.
+    # size).
     "transport_credit_window": 4096,
     # --- object store (docs/objectstore.md) ---
     # By-reference task data plane: pool args/results whose serialized
@@ -199,10 +198,9 @@ DEFAULTS: Dict[str, Any] = {
     "metrics_port": 0,
     # Flight recorder (docs/observability.md): per-process ring buffer
     # of structured plane events (pool/sched/store/transport/health) —
-    # the black box `fiber-tpu explain`, postmortem bundles and the
-    # cluster bench read. Near-zero when off; fully on it is gated
-    # <= 5% by `make bench-telemetry`'s flightrec arm. Requires
-    # telemetry_enabled too (one master switch for the whole plane).
+    # the black box `fiber-tpu explain` and postmortem bundles read.
+    # Near-zero when off. Requires telemetry_enabled too (one master
+    # switch for the whole plane).
     "flightrec_enabled": True,
     # Events kept in the ring before the oldest fall out (each is a
     # small dict; 2048 bounds a long-lived master to ~1 MB).
@@ -221,9 +219,7 @@ DEFAULTS: Dict[str, Any] = {
     # Wall-clock sampling profiler (telemetry/profiler.py): > 0 arms a
     # per-process sampler at this many stack samples per second,
     # aggregated as flamegraph folded stacks; pool workers ship theirs
-    # back on the result stream. 0 (default) = off, zero cost. The
-    # armed cost is gated <= 5% by `make bench-telemetry`'s profiler
-    # arm at ~100 Hz.
+    # back on the result stream. 0 (default) = off, zero cost.
     "profiler_hz": 0.0,
     # Anomaly watchdog rules (telemetry/monitor.py). tasks/s dropping
     # more than this fraction below its trailing-window mean (with
@@ -243,8 +239,7 @@ DEFAULTS: Dict[str, Any] = {
     # deserialize, device_map plan, checkpoint restore), jax.monitoring
     # compile listeners, HBM/live-array gauges and the live pool_map_mfu
     # gauge. Requires telemetry_enabled; off, every hook is one
-    # attribute check. Gated <= 5% by `make bench-telemetry`'s device
-    # arm.
+    # attribute check.
     "device_telemetry_enabled": True,
     # Recompiles of ONE fingerprint inside the window that raise the
     # `recompile_storm` watchdog rule (shape churn, not progress):
@@ -277,8 +272,7 @@ DEFAULTS: Dict[str, Any] = {
     # Per-map/per-tenant cost attribution: billing keys ride the task
     # envelope tail, workers ship cumulative ("cost", ...) frames, and
     # Pool.cost()/`fiber-tpu cost` render per-job CostReports. Requires
-    # telemetry_enabled; off, every hook is one attribute check. Gated
-    # <= 5% by `make bench-accounting`.
+    # telemetry_enabled; off, every hook is one attribute check.
     "accounting_enabled": True,
     # Tenant label billed for every map this process submits (the serve
     # tier will stamp it per client); bounded per-job metric labels ride

@@ -13,19 +13,6 @@ from __future__ import annotations
 from typing import Callable
 
 
-def _scan_unroll() -> int:
-    """FIBER_ROLLOUT_UNROLL trades compiled-code size for fewer loop
-    iterations in every env rollout scan (read at trace time; TPU scans
-    with tiny bodies often gain from 2-8). Sweepable without API churn:
-    tune_es/bench runs set the env var."""
-    import os
-
-    try:
-        return max(1, int(os.environ.get("FIBER_ROLLOUT_UNROLL", "1")))
-    except ValueError:
-        return 1
-
-
 def _prepared(act, flat_params):
     """The parameters a rollout's step scan closes over. ``act`` is
     what the rollout will call on every step with them: where its owner
@@ -88,7 +75,7 @@ def _survival_scan(step_fn, act_step_fn, state0, carry0, steps):
     (_, _, _, total), _ = jax.lax.scan(
         scan_step,
         (state0, carry0, jnp.asarray(False), jnp.asarray(0.0)),
-        None, length=steps, unroll=_scan_unroll(),
+        None, length=steps,
     )
     return total
 
@@ -294,7 +281,6 @@ class Pendulum:
 
         (_, total), _ = jax.lax.scan(
             scan_step, (state0, jnp.asarray(0.0)), None, length=steps,
-            unroll=_scan_unroll()
         )
         return total
 
@@ -363,7 +349,6 @@ class PixelChase:
 
         (_, total), _ = jax.lax.scan(
             scan_step, (agent0, jnp.asarray(0.0)), None, length=steps,
-            unroll=_scan_unroll()
         )
         return total
 
@@ -436,9 +421,7 @@ class DeceptiveMaze:
                 new_y = jnp.where(crosses, stop_y, new[1])
             return jnp.stack([new_x, new_y]), None
 
-        pos, _ = jax.lax.scan(
-            scan_step, pos0, None, length=steps, unroll=_scan_unroll()
-        )
+        pos, _ = jax.lax.scan(scan_step, pos0, None, length=steps)
         return pos
 
     @classmethod
@@ -529,7 +512,6 @@ class ParamHillWalker:
 
         (x, _v), _ = jax.lax.scan(
             scan_step, (x0, v0), None, length=steps,
-            unroll=_scan_unroll()
         )
         return x
 
@@ -702,7 +684,7 @@ class ParamBipedWalker:
 
         (_, _, best_x), _ = jax.lax.scan(
             scan_step, (state0, jnp.asarray(False), jnp.asarray(0.0)),
-            None, length=steps, unroll=_scan_unroll(),
+            None, length=steps,
         )
         return best_x
 

@@ -38,18 +38,12 @@ def _policy_apply_scope(method):
 
 
 def _compute_dtype(explicit):
-    """Policy matmul precision: explicit kwarg, else the
-    FIBER_POLICY_DTYPE env var (trace-time, so hardware sweeps need no
-    API churn), else float32. bfloat16 halves policy HBM/MXU cost on
-    TPU; params/logits stay float32 at the boundary."""
-    import os
-
+    """Policy matmul precision: the ``compute_dtype`` keyword, else
+    float32. bfloat16 halves policy HBM/MXU cost on TPU; params/logits
+    stay float32 at the boundary."""
     import jax.numpy as jnp
 
-    name = explicit or os.environ.get("FIBER_POLICY_DTYPE", "")
-    if not name:
-        return None
-    return jnp.dtype(name)
+    return jnp.dtype(explicit) if explicit else None
 
 
 def _layers(policy, params):
@@ -102,7 +96,7 @@ def _dense(x, w, dt):
 class MLPPolicy:
     """Tanh MLP: obs -> hidden* -> logits, as flat parameter vectors.
 
-    ``compute_dtype`` (or env ``FIBER_POLICY_DTYPE``) runs the matmuls
+    ``compute_dtype`` runs the matmuls
     in reduced precision (e.g. "bfloat16") while params and outputs
     stay float32."""
 

@@ -148,8 +148,8 @@ class _Entry:
         #: filled-but-unyielded values: live payloads stay
         #: O(stream_window) and per-task bookkeeping is ~0.125 bytes —
         #: a million-task stream costs the master ~128KB, not an
-        #: O(n) pointer list. That IS the constant-memory claim the
-        #: `make bench-stream` RSS gate enforces.
+        #: O(n) pointer list. That IS the constant-memory claim
+        #: (tests/test_stream.py holds the window bound).
         self.values: List[Any] = [] if stream else [_UNSET] * n
         self.bits: Optional[bytearray] = bytearray() if stream else None
         self.pending: Optional[Dict[int, Any]] = {} if stream else None
@@ -3627,7 +3627,7 @@ class Pool:
         # flops=<per item>) — utils/flops.py counters supply the
         # number) lands its achieved MFU in the pool_map_mfu gauge
         # whenever the device peak resolves; CPU runs record None
-        # honestly, exactly the bench-cluster posture.
+        # honestly.
         if flops_meta and items:
             from fiber_tpu.telemetry.device import DEVICE
 

@@ -32,8 +32,8 @@ The scheduler IS the pool's task queue: it keeps the ``put`` /
 already speak (items stay ``(payload, (seq, base))`` tuples, ``None``
 stays the shutdown sentinel), so the resubmission paths — death
 reclaim, storemiss inline resend, reply-failure requeue — route through
-policy unchanged. ``policy="fifo"`` degrades to a plain queue for A/B
-benchmarking (``bench.py --sched``).
+policy unchanged. ``policy="fifo"`` degrades to a plain queue
+(tests/test_sched.py holds its arrival order).
 """
 
 from __future__ import annotations

@@ -114,12 +114,20 @@ class _OOBPickler(pickle.Pickler):
         self.dispatch_table.update(ForkingPickler._extra_reducers)
 
 
-def dumps_oob(obj: Any) -> Tuple[bytes, List[memoryview]]:
-    """Serialize to ``(pickle_bytes, out_of_band_buffers)``. The buffers
-    are zero-copy views into the caller's objects — valid only while
-    those objects live and are not mutated. Raises the usual pickling
-    errors; callers that want the cloudpickle fallback use :func:`dumps`.
-    """
+def _stdlib_pickle(obj: Any, buffer_callback) -> bytes:
+    buf = io.BytesIO()
+    _OOBPickler(buf, buffer_callback).dump(obj)
+    return buf.getvalue()
+
+
+def _cloud_pickle(obj: Any, buffer_callback) -> bytes:
+    return cloudpickle.dumps(obj, protocol=5,
+                             buffer_callback=buffer_callback)
+
+
+def _pickle_oob(obj: Any, pickler) -> Tuple[bytes, List[memoryview]]:
+    # Whichever pickler gets the same callback, so there is one
+    # out-of-band format.
     register_jax_reducers()
     buffers: List[memoryview] = []
 
@@ -135,9 +143,16 @@ def dumps_oob(obj: Any) -> Tuple[bytes, List[memoryview]]:
         buffers.append(view)
         return False
 
-    buf = io.BytesIO()
-    _OOBPickler(buf, keep_oob).dump(obj)
-    return buf.getvalue(), buffers
+    return pickler(obj, keep_oob), buffers
+
+
+def dumps_oob(obj: Any) -> Tuple[bytes, List[memoryview]]:
+    """Serialize to ``(pickle_bytes, out_of_band_buffers)``. The buffers
+    are zero-copy views into the caller's objects — valid only while
+    those objects live and are not mutated. Raises the usual pickling
+    errors; callers that want the cloudpickle fallback use :func:`dumps`.
+    """
+    return _pickle_oob(obj, _stdlib_pickle)
 
 
 def pack_envelope(data, buffers) -> bytes:
@@ -180,18 +195,17 @@ def unpack_envelope(data) -> Tuple[memoryview, List[memoryview]]:
 
 
 def dumps(obj: Any) -> bytes:
-    """Serialize with the stdlib reducer (protocol 5, out-of-band buffer
-    envelope for large arrays); cloudpickle on failure or in interactive
-    sessions."""
-    register_jax_reducers()
-    if cloudpickle is not None and is_in_interactive_console():
-        return cloudpickle.dumps(obj)
+    """Serialize with the stdlib reducer, or with cloudpickle on failure
+    and in interactive sessions; either way protocol 5 with the
+    out-of-band buffer envelope for large arrays."""
+    by_value = cloudpickle is not None and is_in_interactive_console()
     try:
-        data, buffers = dumps_oob(obj)
+        data, buffers = _pickle_oob(
+            obj, _cloud_pickle if by_value else _stdlib_pickle)
     except (pickle.PicklingError, AttributeError, TypeError):
-        if cloudpickle is None:
+        if cloudpickle is None or by_value:
             raise
-        return cloudpickle.dumps(obj)
+        data, buffers = _pickle_oob(obj, _cloud_pickle)
     if not buffers:
         return data
     return pack_envelope(data, buffers)

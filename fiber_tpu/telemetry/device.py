@@ -4,8 +4,8 @@
 The five CPU planes are deeply observable, but the thing this framework
 exists to drive — the device plane — was a black box: every
 ``jax.device_put`` untimed, HBM usage invisible, a recompile storm
-indistinguishable from slow compute, MFU only computed inside
-``make bench-cluster``. This module is the missing instrument panel:
+indistinguishable from slow compute, MFU computed nowhere.
+This module is the missing instrument panel:
 
 * **Transfer accounting** — :func:`transfer` wraps the host→device
   boundary (store resolution, serialization deserialize, the device_map
@@ -37,14 +37,11 @@ indistinguishable from slow compute, MFU only computed inside
 Design constraints, mirrored from the rest of the plane:
 
 * **Near-zero when off** — ``device_telemetry_enabled=False`` (or the
-  telemetry master switch) reduces every hook to one attribute check;
-  the fully-on cost is gated ≤ 5% by ``make bench-telemetry``'s
-  ``device`` arm.
+  telemetry master switch) reduces every hook to one attribute check.
 * **Null-safe everywhere** — no probe may *initialize* a jax backend
   (``jax`` absent from ``sys.modules`` means every device field is
   ``None``), and a CPU ``memory_stats()`` returning None/empty records
-  ``None`` honestly instead of raising — the bench-cluster MFU
-  posture.
+  ``None`` honestly instead of raising.
 * **Picklable snapshots** — :func:`snapshot` is the payload of the
   host agent's ``device_snapshot`` op, ``cluster_devices()`` on both
   backends, the worker's ``("dev", …)`` result-stream frames, and

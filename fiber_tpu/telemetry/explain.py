@@ -29,7 +29,7 @@ The verdict is a **ranked budget**: seconds attributed per category,
 plus ``primary`` — the top category with nonzero blame (or
 ``"compute"`` when nothing above explains the wall clock, i.e. the map
 was simply busy). All inputs are artifacts (the Chrome trace written by
-``Pool.trace_dump`` / ``bench.py --cluster`` and the flight-event JSON
+``Pool.trace_dump`` and the flight-event JSON
 from ``Pool.flight_dump``), so the CLI runs offline against any
 recorded run.
 """

@@ -17,8 +17,7 @@ Design constraints, mirrored from the span store:
   read + branch on :attr:`FlightRecorder.enabled`; fully off, the hot
   paths pay a single load.
 * **Lock-cheap when enabled** — one lock around a ``deque.append``; no
-  I/O, no serialization, no per-event syscalls. The ``bench.py
-  --telemetry`` flightrec arm gates the fully-on cost at <= 5%.
+  I/O, no serialization, no per-event syscalls.
 * **Bounded** — capacity follows ``flightrec_buffer_size``; the oldest
   events fall out and are counted in :attr:`FlightRecorder.dropped`.
 

@@ -6,8 +6,7 @@ aggregates **collapsed stacks**: ``root;caller;…;leaf -> sample
 count``, the flamegraph folded format (Gregg's ``flamegraph.pl``,
 speedscope, and Perfetto's flamegraph view all ingest it). Because
 sampling reads frames without tracing, the profiled code pays nothing
-between samples — at the default-off setting it pays nothing at all,
-and `make bench-telemetry`'s profiler arm gates the armed cost ≤ 5%.
+between samples — at the default-off setting it pays nothing at all.
 
 Cluster story (docs/observability.md):
 

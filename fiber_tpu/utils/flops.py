@@ -2,9 +2,8 @@
 
 The reference framework publishes only relative numbers (its
 mkdocs/performance.md is a TODO), so fiber_tpu sets the absolute bar
-itself: every throughput metric bench.py emits carries an ``mfu`` field —
-analytic model FLOPs per second divided by the aggregate peak matmul
-FLOPs of the devices the measurement ran on.
+itself: ``mfu`` is analytic model FLOPs per second divided by the
+aggregate peak matmul FLOPs of the devices the measurement ran on.
 
 Counting conventions (stated so the numbers are auditable):
 

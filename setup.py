@@ -12,7 +12,7 @@ setup(
     packages=find_packages(include=["fiber_tpu", "fiber_tpu.*"]),
     python_requires=">=3.10",
     install_requires=[
-        "cloudpickle",
+        "cloudpickle>=1.3",  # buffer_callback= (serialization.dumps)
         "psutil",
     ],
     extras_require={

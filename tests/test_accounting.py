@@ -596,75 +596,6 @@ def test_log_ring_tail_in_postmortem_and_explain(tmp_path, capsys):
     assert "recent log tail" in out and "two log line" in out
 
 
-def test_bench_check_flags_gated_regressions(tmp_path, capsys):
-    """scripts/bench_check.py: a latest gated value >10% worse than
-    BOTH the median of prior records and the most recent prior fails
-    (a step change at this commit); within tolerance of either passes
-    (box drift moves adjacent records together); unknown metrics are
-    listed, never gated; a single outlier-good record does not ratchet
-    the bar."""
-    import importlib.util
-
-    spec = importlib.util.spec_from_file_location(
-        "bench_check",
-        os.path.join(os.path.dirname(__file__), "..", "scripts",
-                     "bench_check.py"))
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    hist = tmp_path / "h.jsonl"
-
-    def write(latest_overhead, latest_evals):
-        lines = [
-            {"metric": "pool_accounting_overhead", "value": 1.02},
-            {"metric": "cluster_evals_per_sec", "value": 140.0},
-            {"metric": "some_new_metric", "value": 1.0},
-            {"metric": "pool_accounting_overhead",
-             "value": latest_overhead, "sha": "abc"},
-            {"metric": "cluster_evals_per_sec", "value": latest_evals},
-        ]
-        hist.write_text("\n".join(json.dumps(ln) for ln in lines))
-
-    write(1.30, 100.0)   # overhead worse AND throughput collapsed
-    assert mod.check(str(hist), 0.10) == 1
-    out = capsys.readouterr().out
-    assert "REGRESSION pool_accounting_overhead" in out
-    assert "REGRESSION cluster_evals_per_sec" in out
-    assert "some_new_metric" in out  # listed as unknown, not gated
-    write(1.05, 139.0)   # within tolerance
-    assert mod.check(str(hist), 0.10) == 0
-
-    # Median reference: one lucky record (box-weather outlier) must not
-    # ratchet the bar — best-ever 1.9 would flag 1.55, median 1.66
-    # keeps it green.
-    lines = [
-        {"metric": "ici_broadcast_wall_ratio", "value": 1.9},
-        {"metric": "ici_broadcast_wall_ratio", "value": 1.66},
-        {"metric": "ici_broadcast_wall_ratio", "value": 1.66},
-        {"metric": "ici_broadcast_wall_ratio", "value": 1.55},
-    ]
-    hist.write_text("\n".join(json.dumps(ln) for ln in lines))
-    assert mod.check(str(hist), 0.10) == 0
-    out = capsys.readouterr().out
-    assert "median 1.66" in out
-    # ...but a genuine collapse (a step below BOTH the median and the
-    # previous record) still fails.
-    lines[-1] = {"metric": "ici_broadcast_wall_ratio", "value": 1.2}
-    hist.write_text("\n".join(json.dumps(ln) for ln in lines))
-    assert mod.check(str(hist), 0.10) == 1
-    capsys.readouterr()
-    # Gradual box drift: the latest record is >10% under the median but
-    # within tolerance of the record just before it — adjacent records
-    # moved together, so no step change is attributed to this commit.
-    lines = [
-        {"metric": "cluster_evals_per_sec", "value": 220.0},
-        {"metric": "cluster_evals_per_sec", "value": 217.0},
-        {"metric": "cluster_evals_per_sec", "value": 182.0},
-        {"metric": "cluster_evals_per_sec", "value": 179.0},
-    ]
-    hist.write_text("\n".join(json.dumps(ln) for ln in lines))
-    assert mod.check(str(hist), 0.10) == 0
-
-
 def test_log_ring_is_bounded():
     from fiber_tpu.utils.logging import LogRing
 

@@ -899,11 +899,9 @@ def test_poet_on_biped_walker():
 
 
 def test_policy_compute_dtype_bf16():
-    """compute_dtype (kwarg or FIBER_POLICY_DTYPE env) runs policy
-    matmuls in bfloat16 while keeping a float32 boundary, without
-    changing the argmax action contract materially."""
-    import os
-
+    """compute_dtype runs policy matmuls in bfloat16 while keeping a
+    float32 boundary, without changing the argmax action contract
+    materially."""
     import jax
     import jax.numpy as jnp
 
@@ -916,17 +914,6 @@ def test_policy_compute_dtype_bf16():
     assert out32.dtype == jnp.float32 and outbf.dtype == jnp.float32
     # bf16 matmuls agree to bf16 tolerance
     assert jnp.allclose(out32, outbf, atol=0.05), (out32, outbf)
-
-    prev = os.environ.get("FIBER_POLICY_DTYPE")
-    os.environ["FIBER_POLICY_DTYPE"] = "bfloat16"
-    try:
-        out_env = MLPPolicy(4, 3, hidden=(16,)).apply(params, obs)
-    finally:
-        if prev is None:
-            del os.environ["FIBER_POLICY_DTYPE"]
-        else:
-            os.environ["FIBER_POLICY_DTYPE"] = prev
-    assert jnp.allclose(out_env, outbf, atol=1e-6)
 
 
 def test_knn_novelty_matches_numpy():

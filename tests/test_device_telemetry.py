@@ -560,6 +560,10 @@ def test_traces_inside_a_trace_fold_into_the_outermost_span():
     def unit_outer(x):
         return unit_inner(x) + unit_inner(x + 1.0).sum()
 
+    # jnp's own jits traced beforehand, so that the traces inside
+    # unit_outer are one deep (a trace two deep is in `nested_s` twice)
+    warm = jnp.sin(jnp.asarray(np.arange(5.0)) + 1.0) * 2.0
+    (warm + warm.sum()).block_until_ready()
     tracing.SPANS.clear()
     before = DEVICE.snapshot()["compile_seconds"]
     jax.jit(unit_outer)(np.arange(5.0)).block_until_ready()

@@ -181,7 +181,12 @@ def test_watchdog_hbm_fill_demotes_and_repromotes(monkeypatch):
     from fiber_tpu.meta import meta
     from fiber_tpu.telemetry import monitor as monitormod
 
-    fiber_tpu.init(flightrec_enabled=True)
+    # The drill drives a watchdog of its own by hand. The process's
+    # sampler thread is kept off: where the pool below outlives a tick
+    # (a loaded machine), the global watchdog sees the patched 95%
+    # too, raises the rule itself and becomes the only one allowed to
+    # revert it.
+    fiber_tpu.init(flightrec_enabled=True, monitor_enabled=False)
     tier = storemod.device_store_tier()
     assert tier is not None
     arr = _mb(0.25, 17)

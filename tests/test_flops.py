@@ -1,4 +1,4 @@
-"""Analytic FLOP counters + MFU accounting (bench.py's mfu fields)."""
+"""Analytic FLOP counters + MFU accounting (utils/flops.py)."""
 
 import pytest
 

@@ -53,8 +53,7 @@ def test_weak_scaling_record_structure():
     """The scaling entry (VERDICT r3 #8 + r4 #6) records BOTH curves:
     weak (pop grows with n) and strong (constant total pop — the
     contention-free overhead signal on a shared-core mesh) — tiny
-    config so the suite stays fast; the full record is
-    `make weakscale`."""
+    config so the suite stays fast."""
     import __graft_entry__ as ge
 
     rec = ge.weak_scaling(mesh_sizes=(1, 2), gens=2, per_device_pop=8,

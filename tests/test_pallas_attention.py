@@ -1,6 +1,6 @@
 """Flash-attention Pallas kernel: exactness against the full-matrix
-reference, via the Pallas interpreter on CPU (the chip A/B lives in
-bench.py --attention; Mosaic compilation is hardware-gated)."""
+reference, via the Pallas interpreter on CPU (Mosaic compilation:
+tests/test_chip_smoke.py; on the chip: chip_smoke.py)."""
 
 import numpy as np
 import pytest

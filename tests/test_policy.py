@@ -17,6 +17,7 @@ from fiber_tpu.telemetry.monitor import AnomalyWatchdog, WATCHDOG
 from fiber_tpu.telemetry.policy import POLICY
 from fiber_tpu.telemetry.timeseries import TIMESERIES
 from tests import targets
+from tests._chains import assert_linked_chain
 
 
 @pytest.fixture(autouse=True)
@@ -110,6 +111,7 @@ def test_tx_queue_high_tightens_then_reverts_on_clear():
     assert int(evloop.TX_HIGH_WATER) == max(4 << 20, before // 2)
     act = POLICY.recent_actions()[-1]
     assert act["rule"] == "tx_queue_high" and act["applied"]
+    assert_linked_chain("tx_queue_high")
     # clear edge restores the previous high-water
     dog.observe(_sample(tx_queue_bytes=0.0))
     assert int(evloop.TX_HIGH_WATER) == before
@@ -129,6 +131,7 @@ def test_recompile_storm_pins_and_unpins_fingerprint(monkeypatch):
     pins = dmap.pinned_fingerprints()
     assert pins == [storm["fingerprint"][:48]]
     assert dmap._pinned_locked(storm["fingerprint"])
+    assert_linked_chain("recompile_storm")
     storm["storm"] = False
     dog.observe(_sample())
     assert dmap.pinned_fingerprints() == []
@@ -152,6 +155,7 @@ def test_store_disk_fill_sheds_to_target(tmp_path):
         act = POLICY.recent_actions()[-1]
         assert act["rule"] == "store_disk_fill" and act["applied"]
         assert st.disk_usage() <= int(0.7 * st.max_disk_bytes)
+        assert_linked_chain("store_disk_fill")
     finally:
         storemod._store = monkey_prev
 
@@ -176,6 +180,7 @@ def test_straggler_rules_boost_speculation_and_drive_replication():
         while not calls and time.monotonic() < deadline:
             time.sleep(0.01)
         assert calls == ["heartbeat_age"]  # throwaway-thread drive ran
+        assert_linked_chain("heartbeat_age")
         dog.observe(_sample(heartbeat_age_s=0.0))     # clear edge
         assert sched._quantile == pytest.approx(4.0)  # restored
     finally:
@@ -209,6 +214,7 @@ def test_budget_exceeded_throttles_registered_pools():
     assert pool.throttled == [(("acme", "train-7", "m3"), 4.0)]
     act = POLICY.recent_actions()[-1]
     assert act["applied"] and "2 in-flight map(s)" in act["detail"]
+    assert_linked_chain("budget_exceeded")
     dog.external_clear("budget_exceeded")
     assert pool.restored == [("acme", "train-7", "m3")]
 

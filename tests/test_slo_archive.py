@@ -44,8 +44,10 @@ from fiber_tpu.telemetry.archive import (ARCHIVE, ARCHIVE_VERSION,
                                          MetricsArchive)
 from fiber_tpu.telemetry.flightrec import FLIGHT
 from fiber_tpu.telemetry.monitor import WATCHDOG
+from fiber_tpu.telemetry.policy import POLICY
 from fiber_tpu.telemetry.slo import SLO, _Hist, BUCKETS, SloTracker
 from tests import targets
+from tests._chains import assert_linked_chain
 
 REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -59,6 +61,7 @@ def _slo_isolation():
     SLO.clear()
     WATCHDOG.clear()
     FLIGHT.clear()
+    POLICY.reset()
     yield
     ARCHIVE.disable()
     ARCHIVE.clear()
@@ -124,6 +127,7 @@ def test_archive_kinds_labels_and_ranges(tmp_path):
     ARCHIVE.append("slo_obs", {"tenant": "alice", "state": "done",
                                "ts": now - 10})
     ARCHIVE.append("cost", {"job_id": "j1", "total": 4.2})
+    FLIGHT.record("policy", "outcome", cause_id="c-1", outcome="resolved")
     ARCHIVE.on_sample({"wall": now, "tasks_per_s": 7.5,
                        "note": "non-numeric fields are dropped"})
 
@@ -146,6 +150,11 @@ def test_archive_kinds_labels_and_ranges(tmp_path):
     # non-numeric sample fields never landed
     assert ARCHIVE.query("note") == []
     assert len(ARCHIVE.query("cost")) == 1
+    # the tick drained the flight ring: a chain's event reads back out
+    # of the archive with its link
+    evs = ARCHIVE.query("event", labels={"plane": "policy",
+                                         "cause_id": "c-1"})
+    assert [e["event"] for e in evs] == ["outcome"]
     stats = ARCHIVE.stats()
     assert stats["enabled"] and stats["segments"] == 1
     assert stats["torn_lines"] == 0
@@ -307,6 +316,10 @@ def test_evaluate_raises_refreshes_and_clears_slo_burn():
     assert "slo_burn" in active
     assert active["slo_burn"]["tenant"] == "bob"
     assert active["slo_burn"]["burn"] == 10.0
+    # the policy plane answered, and the ring holds the whole chain:
+    # slo_burn -> boost_and_throttle -> outcome, linked by cause_id
+    chain = assert_linked_chain("slo_burn")
+    assert chain["actions"][0]["kind"] == "boost_and_throttle"
     # still burning -> refresh (no second anomaly), then age out -> clear
     assert t.evaluate(now + 1) is not None
     assert t.evaluate(now + 3600) is None

@@ -46,7 +46,13 @@ def unique_array(mbytes: float = 8.0) -> np.ndarray:
 # ---------------------------------------------------------------------------
 
 
-def test_oob_envelope_roundtrip_and_legacy_compat():
+@pytest.mark.parametrize("session", ["script", "interactive"])
+def test_oob_envelope_roundtrip_and_legacy_compat(monkeypatch, session):
+    # An interactive session (a notebook, `python -c`, an xdist worker:
+    # no `__main__.__file__`) pickles by value with cloudpickle; it
+    # must produce the same envelope as the stdlib pickler.
+    monkeypatch.setattr(serialization, "is_in_interactive_console",
+                        lambda: session == "interactive")
     arr = np.arange(200_000, dtype=np.float32)
     blob = serialization.dumps(arr)
     # Out-of-band: the envelope costs bytes(header) over raw, never the
@@ -56,6 +62,13 @@ def test_oob_envelope_roundtrip_and_legacy_compat():
     back = serialization.loads(blob)
     assert np.array_equal(back, arr)
     assert back.flags.writeable  # loads must not hand out frozen views
+    # A graph the stdlib pickler refuses (a lambda) goes by value, and
+    # its array out-of-band all the same.
+    by_value = serialization.dumps((lambda: 7, arr))
+    assert serialization.is_envelope(by_value)
+    assert len(by_value) < arr.nbytes + 4096
+    fn, back = serialization.loads(by_value)
+    assert fn() == 7 and np.array_equal(back, arr)
     # Small payloads stay plain pickles; plain pickles keep loading.
     small = serialization.dumps({"k": [1, 2, 3]})
     assert not serialization.is_envelope(small)

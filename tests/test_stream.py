@@ -69,9 +69,23 @@ def _config_restore():
 
 def test_imap_streams_a_generator_ordered():
     fiber_tpu.init(stream_window=4)
+    pulled = [0]
+
+    def counted(n):
+        for i in _gen(n):
+            pulled[0] += 1
+            yield i
+
     with fiber_tpu.Pool(2) as pool:
-        out = list(pool.imap(targets.square, _gen(300), chunksize=8))
+        out, ahead = [], 0
+        for v in pool.imap(targets.square, counted(300), chunksize=8):
+            ahead = max(ahead, pulled[0] - len(out))
+            out.append(v)
         assert out == [i * i for i in range(300)]
+        # the window bounds what is pulled from the producer and not
+        # yet handed to the consumer: at most `stream_window` chunks,
+        # and the one the admission loop holds while it waits for room
+        assert ahead <= (4 + 1) * 8, ahead
         st = pool.stats()
         assert st["tasks_submitted"] == 300
         assert st["tasks_completed"] == 300
@@ -275,9 +289,7 @@ def test_master_rss_stays_flat_across_big_result_stream():
     """Satellite-2 regression: master peak RSS for a LONG unordered
     stream of 1MB results is bounded by the window, not the stream —
     compared against a SHORT run in its own interpreter (ru_maxrss is a
-    lifetime peak, so each arm needs a fresh process). Full-scale
-    (100k-task) enforcement rides `make bench-stream`; this keeps the
-    mechanism honest at tier-1 cost."""
+    lifetime peak, so each arm needs a fresh process)."""
     script = (
         "import sys, resource, fiber_tpu\n"
         "from tests import targets\n"

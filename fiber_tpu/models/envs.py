@@ -20,13 +20,30 @@ def _prepared(act, flat_params):
     ``unflatten``, the flat vector is cut into its layers here, once,
     and the scan body reads the layers in place; a plain function keeps
     the flat vector and cuts it on every step. A trace-time counter
-    says which of the two a rollout got (docs/observability.md)."""
+    says which of the two a rollout got (docs/observability.md).
+
+    ``flat_params`` is the member's flat ``(dim,)`` vector or, from
+    ``ops/es.py``, the member as a ``PairParams`` (shared base, the
+    antithetic pair's noise, the signed sigma), which only an
+    ``unflatten`` can take apart: an owner whose ``unflatten`` refuses
+    it, and a plain function, raise ``TypeError`` here, before anything
+    is counted, and the engine hands the rollout dense vectors instead.
+    The counter's third value, ``pair``, says the pair went through."""
+    from fiber_tpu.models.policies import PairParams
     from fiber_tpu.telemetry import device
 
     owner = getattr(act, "__self__", act)
     unflatten = getattr(owner, "unflatten", None)
-    device.rollout_traced(type(owner).__name__, unflatten is not None)
-    return flat_params if unflatten is None else unflatten(flat_params)
+    pair = isinstance(flat_params, PairParams)
+    if unflatten is not None:
+        prepared, form = unflatten(flat_params), "pair" if pair else "prepared"
+    elif pair:
+        raise TypeError(f"{type(owner).__name__} offers no unflatten to "
+                        "take a PairParams apart")
+    else:
+        prepared, form = flat_params, "flat"
+    device.rollout_traced(type(owner).__name__, form)
+    return prepared
 
 
 def _mutate_bounded(env_params, key, low, high, scale):
@@ -682,8 +699,16 @@ class ParamBipedWalker:
                 new_best = jnp.where(done, best_x, jnp.maximum(best_x, nx))
             return (keep, done | fell, new_best), None
 
+        # The loop starts from the walker's own state: not yet fallen
+        # (|phi| is the jitter's 0.02 sigma) and its best x the x it
+        # stands at (0). The values are the constants False and 0.0;
+        # read off ``state0`` they are batched wherever the state is, so
+        # a ``vmap`` batches the loop's body in one pass and not two
+        # (its rule re-batches the body until the carry's batching
+        # stops changing), per level of ``vmap``: the ES engine's pair
+        # nests two, and set-up pays each pass in tracing time.
         (_, _, best_x), _ = jax.lax.scan(
-            scan_step, (state0, jnp.asarray(False), jnp.asarray(0.0)),
+            scan_step, (state0, jnp.abs(state0[4]) > 1.2, state0[0]),
             None, length=steps,
         )
         return best_x

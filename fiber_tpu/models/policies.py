@@ -46,9 +46,62 @@ def _compute_dtype(explicit):
     return jnp.dtype(explicit) if explicit else None
 
 
+class PairParams:
+    """One member of an antithetic pair, kept as its parts: the member's
+    flat vector is ``base + scale * noise``, never formed whole. ``base``
+    is the population's shared vector, ``noise`` the pair's draw (both
+    ``(dim,)`` float32) and ``scale`` the member's signed sigma.
+    ``ops/es.py`` hands a rollout this where it used to hand a row of
+    ``thetas``; :meth:`MLPPolicy.unflatten` cuts it into layers of the
+    same three parts, and the layer product (:func:`_value`) adds them
+    inside the rollout's step, so a ``vmap`` over the sign with ``noise``
+    held unbatched reads each pair's noise once for both members. A
+    pytree, so it passes through ``jit`` / ``vmap`` / ``scan`` like an
+    array; it offers no arithmetic of its own, so code that computes on
+    ``theta`` directly fails with a ``TypeError`` at trace time and the
+    engine hands it dense ``thetas`` instead."""
+
+    __slots__ = ("base", "noise", "scale")
+
+    def __init__(self, base, noise, scale):
+        _register_pair_pytree()
+        self.base, self.noise, self.scale = base, noise, scale
+
+    def cut(self, shapes):
+        """One ``PairParams`` per shape, in order: ``base`` and ``noise``
+        cut alike, ``scale`` shared."""
+        return tuple(
+            PairParams(b, n, self.scale)
+            for b, n in zip(_cut(self.base, shapes), _cut(self.noise, shapes)))
+
+
+@functools.cache
+def _register_pair_pytree():
+    # on first use, not at import: nothing here loads jax before it has to
+    import jax
+
+    jax.tree_util.register_pytree_node(
+        PairParams,
+        lambda p: ((p.base, p.noise, p.scale), None),
+        lambda _, parts: PairParams(*parts))
+
+
+def _value(w, dt):
+    """The array a layer's product reads: ``w`` itself, or for one cut of
+    a :class:`PairParams` the sum of its parts, rounded as the dense
+    ``params + sigma * eps`` is (float32 sum, then the compute-dtype
+    cast), formed where it is used so that it lives only inside the
+    step's fusion."""
+    if not isinstance(w, PairParams):
+        return w
+    value = w.base + w.scale * w.noise
+    return value if dt is None else value.astype(dt)
+
+
 def _layers(policy, params):
     """What ``policy.unflatten`` returns, whichever form ``params`` has:
-    the flat vector is an array, the layers are a tuple."""
+    the flat vector is an array (or a :class:`PairParams`), the layers
+    are a tuple."""
     return params if isinstance(params, tuple) else policy.unflatten(params)
 
 
@@ -64,16 +117,17 @@ def _cut(flat, shapes):
     return tuple(out)
 
 
-def _weights_and_biases(flat, weight_shapes, dt):
+def _weights_and_biases(flat, weight_shapes, dt, cut=_cut):
     """``((w, b), ...)``, one pair per weight shape (the bias is as long
-    as the weight's last dimension), cast to the compute dtype."""
+    as the weight's last dimension), cast to the compute dtype; ``cut``
+    is what cuts ``flat`` into arrays of the shapes."""
     if dt is not None:
         flat = flat.astype(dt)
     shapes = []
     for shape in weight_shapes:
         shapes += [tuple(shape), tuple(shape[-1:])]
-    cut = _cut(flat, shapes)
-    return tuple(zip(cut[::2], cut[1::2]))
+    parts = cut(flat, shapes)
+    return tuple(zip(parts[::2], parts[1::2]))
 
 
 def _precision(dt):
@@ -90,7 +144,7 @@ def _dense(x, w, dt):
     """``x (..., n_in) @ w (n_in, n_out)`` in the compute dtype."""
     import jax.numpy as jnp
 
-    return jnp.dot(x, w, precision=_precision(dt))
+    return jnp.dot(x, _value(w, dt), precision=_precision(dt))
 
 
 class MLPPolicy:
@@ -131,10 +185,15 @@ class MLPPolicy:
 
     def unflatten(self, flat_params):
         """``((w, b), ...)`` per layer, cast to the compute dtype: the
-        cutting ``apply`` would do, done once."""
+        cutting ``apply`` would do, done once. A :class:`PairParams` is
+        cut part by part and keeps float32: its cast follows its sum, in
+        the layer's product."""
+        shapes = zip(self.sizes, self.sizes[1:])
+        if isinstance(flat_params, PairParams):
+            return _weights_and_biases(flat_params, shapes, None,
+                                       cut=PairParams.cut)
         return _weights_and_biases(
-            flat_params, zip(self.sizes, self.sizes[1:]),
-            _compute_dtype(self.compute_dtype))
+            flat_params, shapes, _compute_dtype(self.compute_dtype))
 
     @_policy_apply_scope
     def apply(self, params, obs):
@@ -146,7 +205,7 @@ class MLPPolicy:
         layers = _layers(self, params)
         x = obs if dt is None else obs.astype(dt)
         for i, (w, b) in enumerate(layers):
-            x = _dense(x, w, dt) + b
+            x = _dense(x, w, dt) + _value(b, dt)
             if i < len(layers) - 1:
                 x = jnp.tanh(x)
         return x.astype(jnp.float32)

@@ -147,12 +147,55 @@ def centered_rank(x):
     return ranks.astype(jnp.float32) / (n - 1) - 0.5
 
 
+def pair_fitness(eval_fn, params, eps, sigma, keys):
+    """Fitness ``(2 * pairs,)`` of the members ``params +- sigma * eps``,
+    rows ``[plus half; minus half]``, member ``i`` under ``keys[i]``,
+    with each member handed to ``eval_fn`` as a ``PairParams`` (shared
+    ``params``, its pair's ``eps`` row, ``+-sigma``) and ``thetas`` never
+    formed: a ``vmap`` over the pairs (noise and the pair's two keys
+    batched, ``params`` not) of a ``vmap`` over the sign (scale and key
+    batched, the noise **not**), so a policy that sums the parts inside
+    its layer product reads a pair's noise once for both members.
+    Returns ``None`` where ``eval_fn`` cannot take the pair apart (it
+    computes on ``theta`` itself, or its policy's ``unflatten`` refuses
+    it): that is a ``TypeError`` / ``AttributeError`` at trace time,
+    from the first thing done to ``theta``."""
+    import jax
+    import jax.numpy as jnp
+
+    from fiber_tpu.models.policies import PairParams
+
+    pairs = eps.shape[0]
+    scales = jnp.asarray([sigma, -sigma], eps.dtype)
+
+    def one_pair(noise, keys2):
+        return jax.vmap(
+            lambda scale, key: eval_fn(PairParams(params, noise, scale), key)
+        )(scales, keys2)
+
+    try:
+        return jax.vmap(one_pair, in_axes=(0, 1), out_axes=1)(
+            eps, keys.reshape(2, pairs, *keys.shape[1:])).reshape(-1)
+    except (TypeError, AttributeError):
+        return None
+
+
 class EvolutionStrategy(_FusedRunMixin):
     """OpenAI-ES with antithetic sampling and rank shaping, compiled as one
     jitted SPMD step over a mesh.
 
-    ``eval_fn(flat_params, key) -> scalar fitness`` must be pure and
-    jittable (e.g. a policy rollout from fiber_tpu.models).
+    ``eval_fn(theta, key) -> scalar fitness`` must be pure and jittable
+    (e.g. a policy rollout from fiber_tpu.models). ``theta`` is one
+    member. Where ``eval_fn`` hands it untouched to a rollout of
+    ``models/envs.py`` whose policy can take an antithetic pair apart
+    (``MLPPolicy.act`` / ``.apply``), it is a ``PairParams``: the
+    rollout's step reads the pair's noise once for both members and the
+    ``(pop, dim)`` matrix of perturbed vectors is never formed
+    (:func:`pair_fitness`). Anything else (arithmetic on ``theta``, a
+    plain ``act`` function, ``ConvPolicy``, ``GRUPolicy``) gets the flat
+    ``(dim,)`` float32 vector ``params +- sigma * eps``. The step
+    observes which at trace time; fitness, ranks, gradient and update
+    are the same numbers either way.
     """
 
     def __init__(
@@ -224,12 +267,17 @@ class EvolutionStrategy(_FusedRunMixin):
                 eps_key, eval_key = jax.random.split(dev_key)
 
                 eps = jax.random.normal(eps_key, (pairs, dim))
-                thetas = jnp.concatenate(
-                    [params + sigma * eps, params - sigma * eps], axis=0
-                )  # (2*pairs, dim)
             with jax.named_scope("es.rollout"):
                 eval_keys = jax.random.split(eval_key, 2 * pairs)
-                fitness = jax.vmap(eval_fn)(thetas, eval_keys)  # (2*pairs,)
+                fitness = pair_fitness(eval_fn, params, eps, sigma,
+                                       eval_keys)           # (2*pairs,)
+            if fitness is None:  # eval_fn wants the vector itself
+                with jax.named_scope("es.perturb"):
+                    thetas = jnp.concatenate(
+                        [params + sigma * eps, params - sigma * eps], axis=0
+                    )  # (2*pairs, dim)
+                with jax.named_scope("es.rollout"):
+                    fitness = jax.vmap(eval_fn)(thetas, eval_keys)
 
             # Global rank shaping: gather all fitness (tiny), rank
             # identically on every device.

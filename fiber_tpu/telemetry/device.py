@@ -86,8 +86,10 @@ _m_step_units = telemetry.counter(
 _m_rollout_traces = telemetry.counter(
     "policy_rollout_traces",
     "Env rollouts traced, by policy class and params: 'prepared' "
-    "(unflattened once, before the step scan) or 'flat' (cut on every "
-    "step: the callable's owner offers no unflatten)")
+    "(unflattened once, before the step scan), 'pair' (an antithetic "
+    "pair's parts unflattened there, summed in the step: the ES engine "
+    "reads each pair's noise once) or 'flat' (cut on every step: the "
+    "callable's owner offers no unflatten)")
 _m_moe_traces = telemetry.counter(
     "moe_layers_traced",
     "Sparse-expert layers traced, by experts held here, experts in "
@@ -596,13 +598,14 @@ def step(fn: str, units: int, **attrs) -> Iterator[Optional[Dict]]:
     _m_step_units.inc(units, fn=fn)
 
 
-def rollout_traced(policy: str, prepared: bool) -> None:
+def rollout_traced(policy: str, params: str) -> None:
     """One env rollout was traced (``models/envs.py``): with the
-    policy's parameters unflattened before the step scan, or with the
-    flat vector. Counts traces, not calls: a jitted rollout moves it
+    policy's parameters unflattened before the step scan
+    (``params="prepared"``), with an antithetic pair's parts unflattened
+    there and summed in the step (``"pair"``), or with the flat vector
+    (``"flat"``). Counts traces, not calls: a jitted rollout moves it
     once per compilation."""
-    _m_rollout_traces.inc(
-        policy=policy, params="prepared" if prepared else "flat")
+    _m_rollout_traces.inc(policy=policy, params=params)
 
 
 def moe_traced(held: int, total: int, top_k: int) -> None:

@@ -172,6 +172,43 @@ import re
 print("KERNELS", " ".join(sorted(re.findall(
     r"^\s*%([\w.]+) = [^\n]*custom_call_target=\"tpu_custom_call\"",
     compiled.as_text(), re.M))))
+
+# The ES engine's pair step (ops/es.py pair_fitness), one generation as
+# run_fused compiles it: 128 pairs of a 14-128-128-16 policy.
+import numpy as np
+from jax.sharding import Mesh, NamedSharding, PartitionSpec
+from fiber_tpu.models import MLPPolicy, ParamBipedWalker
+from fiber_tpu.ops.es import EvolutionStrategy, build_fused_runner
+PAIRS = 128
+policy = MLPPolicy(ParamBipedWalker.obs_dim, ParamBipedWalker.act_dim,
+                   hidden=(128, 128))
+course = jnp.zeros((6,), jnp.float32)
+es = EvolutionStrategy(
+    lambda theta, key: ParamBipedWalker.rollout_p(
+        policy.act, course, theta, key, 20),
+    policy.dim, 2 * PAIRS, optimizer="adam",
+    mesh=Mesh(np.asarray(topo.devices[:1]), ("pool",)))
+everywhere = NamedSharding(es.mesh, PartitionSpec())
+vec = jax.ShapeDtypeStruct((policy.dim,), jnp.float32, sharding=everywhere)
+text = build_fused_runner(es._device_step_fn, es.mesh, 4, 1).lower(
+    vec, vec, vec,
+    jax.ShapeDtypeStruct((), jnp.float32, sharding=everywhere),
+    jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=everywhere),
+).compile().as_text()
+# every member's own vector or layer, in either order of a layer's axes
+whole = {{f"f32[{{2 * PAIRS}},{{policy.dim}}]", f"f32[{{PAIRS}},2,{{policy.dim}}]"}}
+for a, b in zip(policy.sizes, policy.sizes[1:]):
+    whole |= {{f"f32[{{2 * PAIRS}},{{a}},{{b}}]", f"f32[{{2 * PAIRS}},{{b}},{{a}}]",
+              f"f32[{{PAIRS}},2,{{a}},{{b}}]", f"f32[{{PAIRS}},2,{{b}},{{a}}]"}}
+in_fusion, held = False, 0
+for line in text.splitlines():
+    if line.endswith("{{"):
+        in_fusion = "fused_computation" in line.split("(")[0]
+    made = re.match(r"\s*(?:ROOT )?%[\w.\-]+ = (\w+\[[\d,]*\])", line)
+    held += bool(made and not in_fusion and made.group(1) in whole)
+loops = [line for line in text.splitlines() if " while(" in line]
+print("ES_PAIR_STEP whole_arrays", held, "loops_carrying_noise",
+      sum(f"f32[{{PAIRS}},128,128]" in line for line in loops))
 """
 
 
@@ -195,6 +232,20 @@ def test_flash_kernels_compile_through_mosaic_for_v5e(aot_v5e):
     (GQA 8/2, head_dim 32, sliding window: the awkward corners.)"""
     assert "CUSTOM_CALLS 3" in aot_v5e, aot_v5e
     assert "COMPILED TPU v5" in aot_v5e, aot_v5e
+
+
+def test_es_pair_step_compiled_for_v5e_holds_no_member_sized_array(aot_v5e):
+    """In the ES engine's pair step as the v5e's compiler leaves it no
+    instruction outside a fusion makes an array with a row per member
+    (``thetas`` or a layer of it, as ``(2 * pairs, ...)`` or ``(pairs,
+    2, ...)``), and the rollout's loop carries the noise's 128x128
+    layer, ``(pairs, 128, 128)``: the sum ``base + scale * noise`` lives
+    inside the step's fusion and was not hoisted out of the loop, where
+    it would be every member's weights again (PERF.md, PR 30)."""
+    (line,) = [ln for ln in aot_v5e.splitlines()
+               if ln.startswith("ES_PAIR_STEP")]
+    assert line.split()[1:] == ["whole_arrays", "0",
+                                "loops_carrying_noise", "1"], line
 
 
 def test_flash_kernels_are_named_in_the_compiled_v5e_program(aot_v5e):

@@ -11,6 +11,7 @@ from fiber_tpu.models.transformer import (  # noqa: F401
     BlockLM,
     Experts,
     Rope,
+    StateSpace,
     TinyLM,
     Yarn,
     make_train_step,

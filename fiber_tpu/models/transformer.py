@@ -17,10 +17,11 @@ embedding -> [RMSNorm -> attention -> residual -> RMSNorm -> MLP ->
 residual] x L -> norm -> logits.
 
 A model is a description of its layers (:class:`Block`): each layer has
-its own query-head count, window, rope and feed-forward kind (ungated
+a mixer (attention with its own query-head count, window and rope, or a
+state-space mixer, :mod:`fiber_tpu.ops.ssm`), a feed-forward (ungated
 MLP, gated MLP, or sparse experts of which this program holds a share,
-:mod:`fiber_tpu.ops.moe`). :class:`BlockLM` runs any such description;
-:class:`TinyLM` is the uniform one.
+:mod:`fiber_tpu.ops.moe`), or one of the two alone. :class:`BlockLM`
+runs any such description; :class:`TinyLM` is the uniform one.
 """
 
 from __future__ import annotations
@@ -99,7 +100,9 @@ class Experts:
     """A sparse-expert feed-forward (``fiber_tpu.ops.moe``): ``total``
     routed experts of ``width``, ``top_k`` a token (sigmoid scores,
     weights renormalised over the taken and times ``scale``), one
-    shared expert of ``shared_width``. ``share = (index, shares)``:
+    shared expert of ``shared_width``; ``kind`` is every expert's form:
+    ``"swiglu"`` (gated silu, three matrices) or ``"relu2"`` (ungated
+    ``relu(h Wu)^2 Wd``, two). ``share = (index, shares)``:
     this program holds experts ``[index * total / shares, (index + 1) *
     total / shares)`` of an expert-parallel layer and computes their
     part of the result; ``(0, 1)`` is the whole layer. ``chunk_rows``
@@ -112,23 +115,62 @@ class Experts:
     scale: float = 1.0
     share: Tuple[int, int] = (0, 1)
     chunk_rows: int = 4096
+    kind: str = "swiglu"
+
+
+@dataclasses.dataclass(frozen=True)
+class StateSpace:
+    """A Mamba-2 mixer (``fiber_tpu.ops.ssm``): ``heads`` heads of
+    ``head_dim`` with a state of ``state`` a feature, ``B`` and ``C``
+    shared by the heads of each of ``groups`` groups, a causal depthwise
+    convolution of ``conv`` positions over x, B and C, the scan in
+    blocks of ``chunk`` positions. ``recompute`` wraps the mixer in
+    ``jax.checkpoint``: the backward pass keeps the layer's input and
+    recomputes the blocks' intermediates (memory, never what is
+    computed). ``dt_min`` / ``dt_max`` / ``dt_floor``: ``init`` draws
+    the step sizes log-uniformly in between and floors them."""
+
+    heads: int
+    head_dim: int
+    state: int
+    groups: int = 1
+    conv: int = 4
+    chunk: int = 128
+    recompute: bool = True
+    dt_min: float = 0.001
+    dt_max: float = 0.1
+    dt_floor: float = 1e-4
+
+    @property
+    def inner(self) -> int:
+        return self.heads * self.head_dim
+
+    @property
+    def conv_dim(self) -> int:
+        """Channels the convolution runs over: x, B and C."""
+        return self.inner + 2 * self.groups * self.state
 
 
 @dataclasses.dataclass(frozen=True)
 class Block:
-    """One layer: ``heads`` query heads (of the model's ``head_dim``,
-    grouped over its ``kv_heads``), causal attention over the last
-    ``window`` positions (None = all), its ``rope`` (None with a learned
-    position table), and its feed-forward: ``ffn="mlp"`` (ungated
+    """One layer: a mixer, a feed-forward, or one of the two alone, each
+    part with its own RMSNorm and residual. ``mixer="attention"``:
+    ``heads`` query heads (of the model's ``head_dim``, grouped over its
+    ``kv_heads``), causal attention over the last ``window`` positions
+    (None = all), its ``rope`` (None with a learned position table or
+    with no position scheme at all); ``"ssm"``: the state-space mixer
+    ``ssm=``; None: no mixer. The feed-forward: ``ffn="mlp"`` (ungated
     tanh-GELU of ``width`` with biases), ``"gated"`` (SwiGLU of
-    ``width``, no biases) or ``"experts"`` (``experts=``)."""
+    ``width``, no biases), ``"experts"`` (``experts=``) or None."""
 
-    heads: int
+    heads: int = 0
     window: Optional[int] = None
     rope: Optional[Rope] = Rope()
-    ffn: str = "mlp"
+    ffn: Optional[str] = "mlp"
     width: int = 0
     experts: Optional[Experts] = None
+    mixer: Optional[str] = "attention"
+    ssm: Optional[StateSpace] = None
 
     @property
     def attn_kind(self) -> str:
@@ -136,16 +178,22 @@ class Block:
 
     @property
     def kind(self) -> str:
-        return self.attn_kind + "/" + self.ffn
+        mixer = {"attention": self.attn_kind, "ssm": "ssm"}.get(self.mixer)
+        return "/".join(part for part in (mixer, self.ffn) if part)
 
 
 class BlockLM:
     """Causal LM built from a description of its layers (``blocks``, a
-    sequence of :class:`Block`): per layer its own query-head count,
-    window, rope and feed-forward kind, RMSNorm (1e-6) before each half,
-    no attention bias, an untied head. ``attention``, ``mesh`` and
-    ``interpret`` are :class:`TinyLM`'s (which is the uniform
-    description); a window needs the flash plane.
+    sequence of :class:`Block`): per layer its own mixer (attention
+    with its query-head count, window and rope, or a state-space mixer)
+    and feed-forward kind, or one part alone; RMSNorm (``norm_eps``)
+    before each part, no attention bias, an untied head. ``attention``,
+    ``mesh`` and ``interpret`` are :class:`TinyLM`'s (which is the
+    uniform description); a window needs the flash plane. ``pos``:
+    ``"rope"`` (every attention block has a rope), ``"learned"`` (a
+    position table, no ropes) or ``"none"`` (no position scheme at all:
+    the state-space layers carry position). A state-space layer runs on
+    one device and over whole chunks of positions.
 
     ``apply`` / ``loss`` / ``generate`` as :class:`TinyLM`;
     ``routing(params, tokens)`` gives, for the expert layers, the taken
@@ -154,51 +202,60 @@ class BlockLM:
     def __init__(self, blocks: Sequence[Block], *, vocab: int, dim: int,
                  head_dim: int, kv_heads: int, max_seq: int,
                  attention: str = "flash", pos: str = "rope", mesh=None,
-                 interpret: bool = False) -> None:
+                 interpret: bool = False, norm_eps: float = 1e-6) -> None:
         blocks = tuple(blocks)
         if attention not in ("ring", "ulysses", "flash", "reference"):
             raise ValueError(f"unknown attention {attention!r}")
-        if pos not in ("learned", "rope"):
+        if pos not in ("learned", "rope", "none"):
             raise ValueError(f"unknown positional scheme {pos!r}")
         if kv_heads < 1:
             raise ValueError(f"kv_heads must be >= 1, got {kv_heads}")
         for b in blocks:
-            if b.heads % kv_heads:
+            if b.mixer not in ("attention", "ssm", None):
+                raise ValueError(f"unknown mixer {b.mixer!r}")
+            if b.mixer is None and b.ffn is None:
                 raise ValueError(
-                    f"heads {b.heads} not divisible by kv_heads {kv_heads}")
-            if (pos == "rope") != (b.rope is not None):
+                    "a layer with no part: give the block a mixer or a "
+                    "feed-forward")
+            if (b.mixer == "ssm") != (b.ssm is not None):
                 raise ValueError(
-                    "pos='rope' gives every block a rope and "
-                    "pos='learned' none")
-            if b.rope is not None:
-                b.rope.table(head_dim)
-            if b.window is not None:
-                if b.window < 1:
+                    "mixer='ssm' comes with ssm=, and no other does")
+            if b.mixer == "ssm":
+                if b.ssm.heads % b.ssm.groups:
                     raise ValueError(
-                        f"window must be >= 1, got {b.window}")
-                if attention != "flash":
-                    # The window lives in the flash kernels' block-skip
-                    # grid; the XLA planes have no windowed engine and
-                    # silently ignoring it would train a different model.
+                        f"{b.ssm.heads} state-space heads do not divide "
+                        f"into {b.ssm.groups} groups")
+                if max_seq % b.ssm.chunk:
                     raise ValueError(
-                        "window= needs attention='flash' (the sliding "
-                        "window is a kernel feature)")
-            if b.ffn not in ("mlp", "gated", "experts"):
+                        f"a sequence of {max_seq} positions is not whole "
+                        f"chunks of {b.ssm.chunk}")
+            if b.mixer == "attention":
+                self._check_attention(b, kv_heads, head_dim, pos, attention)
+            if b.ffn not in ("mlp", "gated", "experts", None):
                 raise ValueError(f"unknown feed-forward {b.ffn!r}")
             if (b.ffn == "experts") != (b.experts is not None):
                 raise ValueError(
                     "ffn='experts' comes with experts=, and no other does")
             if b.ffn == "experts":
-                from fiber_tpu.ops.moe import held_experts
+                from fiber_tpu.ops.moe import EXPERT_MATRICES, held_experts
 
+                if b.experts.kind not in EXPERT_MATRICES:
+                    raise ValueError(
+                        f"unknown expert kind {b.experts.kind!r}")
                 held_experts(b.experts.total, b.experts.share)
-            elif b.width < 1:
+            elif b.ffn is not None and b.width < 1:
                 raise ValueError(f"feed-forward width {b.width}")
         self._flash_multi = False
+        attends = any(b.mixer == "attention" for b in blocks)
         if mesh is not None:
             import numpy as np
 
             multi = int(np.prod(list(mesh.shape.values()))) > 1
+            if multi and any(b.mixer == "ssm" for b in blocks):
+                raise ValueError(
+                    "a state-space layer runs on one device: the "
+                    "sequence-parallel plane hands keys and values on, "
+                    "not state; drop the mesh")
             if multi and "pool" not in mesh.shape:
                 # Loud, at construction: the sequence-parallel planes
                 # shard over the mesh's "pool" axis — without this
@@ -217,7 +274,7 @@ class BlockLM:
             raise ValueError(
                 "window= is single-device (a windowed partial's lse "
                 "is not ring-mergeable); drop the mesh or the window")
-        if attention == "flash" and not interpret:
+        if attention == "flash" and attends and not interpret:
             import jax
 
             platform = (mesh.devices.flat[0] if mesh is not None
@@ -245,11 +302,39 @@ class BlockLM:
         # "rope": rotary embeddings applied to q/k per attention layer
         # (relative positions; the modern long-context default — decays
         # gracefully past training lengths where a learned table ends).
+        # "none": neither (state-space layers carry position).
         self.pos = pos
+        #: the epsilon of every RMSNorm (the group norm of a state-space
+        #: mixer too)
+        self.norm_eps = norm_eps
         #: flash plane only: run the kernels in the Pallas interpreter
         self.interpret = interpret
         self._mesh = mesh
         self._probe = None
+
+    @staticmethod
+    def _check_attention(b, kv_heads, head_dim, pos, attention):
+        """Refuse an attention block the model cannot run."""
+        if b.heads < 1 or b.heads % kv_heads:
+            raise ValueError(
+                f"heads {b.heads} not divisible by kv_heads {kv_heads}")
+        if (pos == "rope") != (b.rope is not None):
+            raise ValueError(
+                "pos='rope' gives every block a rope and "
+                "pos='learned' or 'none' none")
+        if b.rope is not None:
+            b.rope.table(head_dim)
+        if b.window is not None:
+            if b.window < 1:
+                raise ValueError(
+                    f"window must be >= 1, got {b.window}")
+            if attention != "flash":
+                # The window lives in the flash kernels' block-skip
+                # grid; the XLA planes have no windowed engine and
+                # silently ignoring it would train a different model.
+                raise ValueError(
+                    "window= needs attention='flash' (the sliding "
+                    "window is a kernel feature)")
 
     @property
     def span_fields(self) -> dict:
@@ -266,8 +351,6 @@ class BlockLM:
                           experts_total=e.total, top_k=e.top_k)
         return fields
 
-
-
     # ------------------------------------------------------------------
     def init(self, key) -> dict:
         """Weights 0.02 * normal, gains 1, biases 0. The stream: split
@@ -275,7 +358,14 @@ class BlockLM:
         ``rest`` in seven: 0 wq (or wqkv), 1 wo, 2 w1 / wg, 3 w2 / wd,
         4 wkv, 5 wu (gated) or, split in seven again, the expert
         layer's router, shared wg / wu / wd, held experts' wg / wu / wd
-        (each one draw of the stacked shape), 6 rest."""
+        (each one draw of the stacked shape; an ungated expert has no
+        wg and skips its draws), 6 rest. A state-space mixer splits 0 in
+        five: in_proj, conv_w, conv_b (uniform in +-conv^-0.5), the step
+        sizes (``dt_bias`` is the inverse softplus of ``dt`` drawn
+        log-uniformly in [dt_min, dt_max], floored at dt_floor),
+        out_proj; ``A_log = log(1..heads)``, ``D = 1``. A part the layer
+        does not have draws nothing and has no leaf (``norm1`` is the
+        mixer's gain, ``norm2`` the feed-forward's)."""
         import jax
         import jax.numpy as jnp
 
@@ -300,17 +390,21 @@ class BlockLM:
             keys = jax.random.split(key, 7)
             key = keys[6]
             d, q_dim = self.dim, spec.heads * self.head_dim
-            blk = {
-                "norm1": jnp.ones((d,)),
-                "wo": normal(keys[1], q_dim, d),
-                "norm2": jnp.ones((d,)),
-            }
-            if self.kv_heads == spec.heads:
-                blk["wqkv"] = normal(keys[0], d, 3 * q_dim)
-            else:
-                kv_dim = self.kv_heads * self.head_dim
-                blk["wq"] = normal(keys[0], d, q_dim)
-                blk["wkv"] = normal(keys[4], d, 2 * kv_dim)
+            blk = {}
+            if spec.mixer == "attention":
+                blk.update(norm1=jnp.ones((d,)),
+                           wo=normal(keys[1], q_dim, d))
+                if self.kv_heads == spec.heads:
+                    blk["wqkv"] = normal(keys[0], d, 3 * q_dim)
+                else:
+                    kv_dim = self.kv_heads * self.head_dim
+                    blk["wq"] = normal(keys[0], d, q_dim)
+                    blk["wkv"] = normal(keys[4], d, 2 * kv_dim)
+            elif spec.mixer == "ssm":
+                blk.update(norm1=jnp.ones((d,)),
+                           **self._init_ssm(spec.ssm, keys[0], normal))
+            if spec.ffn is not None:
+                blk["norm2"] = jnp.ones((d,))
             if spec.ffn == "mlp":
                 h = spec.width
                 blk.update(w1=normal(keys[2], d, h), b1=jnp.zeros((h,)),
@@ -320,22 +414,53 @@ class BlockLM:
                 blk.update(wg=normal(keys[2], d, h),
                            wd=normal(keys[3], h, d),
                            wu=normal(keys[5], d, h))
-            else:
-                from fiber_tpu.ops.moe import held_experts
+            elif spec.ffn == "experts":
+                from fiber_tpu.ops.moe import EXPERT_MATRICES, held_experts
 
                 e = spec.experts
                 held = held_experts(e.total, e.share)[1]
                 sub = jax.random.split(keys[5], 7)
-                blk.update(
-                    router=normal(sub[0], d, e.total),
-                    shared_wg=normal(sub[1], d, e.shared_width),
-                    shared_wu=normal(sub[2], d, e.shared_width),
-                    shared_wd=normal(sub[3], e.shared_width, d),
-                    experts_wg=normal(sub[4], held, d, e.width),
-                    experts_wu=normal(sub[5], held, d, e.width),
-                    experts_wd=normal(sub[6], held, e.width, d))
+
+                def matrix(k, m, width, *lead):
+                    return normal(k, *lead, *((width, d) if m == "wd"
+                                              else (d, width)))
+
+                blk["router"] = normal(sub[0], d, e.total)
+                for i, m in enumerate(("wg", "wu", "wd")):
+                    if m in EXPERT_MATRICES[e.kind]:
+                        blk["shared_" + m] = matrix(sub[1 + i], m,
+                                                    e.shared_width)
+                        blk["experts_" + m] = matrix(sub[4 + i], m,
+                                                     e.width, held)
             params["blocks"].append(blk)
         return params
+
+    def _init_ssm(self, ssm, key, normal) -> dict:
+        """A state-space mixer's leaves (``init`` says the stream)."""
+        import math
+
+        import jax
+        import jax.numpy as jnp
+
+        k_in, k_w, k_b, k_dt, k_out = jax.random.split(key, 5)
+        bound = ssm.conv ** -0.5
+        dt = jnp.exp(jax.random.uniform(
+            k_dt, (ssm.heads,), minval=math.log(ssm.dt_min),
+            maxval=math.log(ssm.dt_max)))
+        dt = jnp.maximum(dt, ssm.dt_floor)
+        return {
+            "in_proj": normal(k_in, self.dim,
+                              ssm.inner + ssm.conv_dim + ssm.heads),
+            "conv_w": jax.random.uniform(
+                k_w, (ssm.conv_dim, ssm.conv), minval=-bound, maxval=bound),
+            "conv_b": jax.random.uniform(
+                k_b, (ssm.conv_dim,), minval=-bound, maxval=bound),
+            "dt_bias": dt + jnp.log(-jnp.expm1(-dt)),   # softplus^-1(dt)
+            "A_log": jnp.log(jnp.arange(1.0, ssm.heads + 1.0)),
+            "D": jnp.ones((ssm.heads,)),
+            "ssm_norm": jnp.ones((ssm.inner,)),
+            "out_proj": normal(k_out, ssm.inner, self.dim),
+        }
 
     # ------------------------------------------------------------------
     def _attend(self, q, k, v, window=None):
@@ -373,12 +498,11 @@ class BlockLM:
 
         return ring_attention(q, k, v, mesh=self._mesh, causal=True)
 
-    @staticmethod
-    def _rms(x, g):
+    def _rms(self, x, g):
         import jax.numpy as jnp
 
         return g * x / jnp.sqrt(
-            jnp.mean(x * x, axis=-1, keepdims=True) + 1e-6)
+            jnp.mean(x * x, axis=-1, keepdims=True) + self.norm_eps)
 
     @staticmethod
     def _project_qkv(blk, h):
@@ -414,7 +538,8 @@ class BlockLM:
         """{rope: (cos, sin)} for each distinct rope of the blocks."""
         tables = {}
         for spec in self.blocks:
-            if spec.rope is not None and spec.rope not in tables:
+            if (spec.mixer == "attention" and spec.rope is not None
+                    and spec.rope not in tables):
                 r, inv, factor = spec.rope.table(self.head_dim)
                 tables[spec.rope] = self._rope_angles(
                     positions, r, spec.rope.base, inv, factor)
@@ -440,14 +565,89 @@ class BlockLM:
             [x1 * cos - x2 * sin, x2 * cos + x1 * sin],
             axis=-1).astype(x.dtype)
 
-    def _block_tail(self, spec, blk, x, attn_flat, taps=None):
-        """Post-attention residual + feed-forward (shared like
-        _project_qkv)."""
+    def _ssm_parts(self, ssm, blk, x):
+        """A state-space mixer's input side, on (S, dim) rows and single
+        (dim,) vectors alike (shared by apply() and _decode_step() like
+        _project_qkv): (gate z, the convolution's input xBC, dt before
+        its bias)."""
+        import jax
+        import jax.numpy as jnp
+
+        with jax.named_scope("in_proj"):
+            h = self._rms(x, blk["norm1"]) @ blk["in_proj"]
+            return jnp.split(h, [ssm.inner, ssm.inner + ssm.conv_dim],
+                             axis=-1)
+
+    def _ssm_scanned(self, ssm, blk, xbc, dt, scan):
+        """From the convolved and activated ``xbc`` to the scan's
+        result: split x, B and C, ``dt = softplus(dt + dt_bias)``, ``A =
+        -exp(A_log)``, then ``scan(x, dt, A, B, C, D)`` (the chunked
+        scan, or one step of the recurrence)."""
+        import jax
+        import jax.numpy as jnp
+
+        lead = xbc.shape[:-1]
+        x, B, C = jnp.split(
+            xbc, [ssm.inner, ssm.inner + ssm.groups * ssm.state], axis=-1)
+        dt = jax.nn.softplus(dt.astype(jnp.float32) + blk["dt_bias"])
+        return scan(x.reshape(lead + (ssm.heads, ssm.head_dim)), dt,
+                    -jnp.exp(blk["A_log"].astype(jnp.float32)),
+                    B.reshape(lead + (ssm.groups, ssm.state)),
+                    C.reshape(lead + (ssm.groups, ssm.state)), blk["D"])
+
+    def _ssm_out(self, ssm, blk, y, z):
+        """Gate, group norm and the projection back to the stream."""
         import jax
 
-        with jax.named_scope("lm.attn"), \
-                jax.named_scope(spec.attn_kind), jax.named_scope("out"):
-            x = x + attn_flat @ blk["wo"]
+        from fiber_tpu.ops import ssm as ops
+
+        with jax.named_scope("gate_norm"):
+            y = ops.gated_group_norm(y.reshape(z.shape), z, blk["ssm_norm"],
+                                     ssm.groups, self.norm_eps)
+        with jax.named_scope("out"):
+            return y @ blk["out_proj"]
+
+    def _ssm_mix(self, ssm, blk, x):
+        """The state-space mixer on the stream ``x`` (S, dim) -> what it
+        adds to it (S, dim): norm, in-projection, causal convolution,
+        chunked scan, gated group norm, out-projection."""
+        import jax
+
+        from fiber_tpu.ops import ssm as ops
+        from fiber_tpu.telemetry import device as device_telemetry
+
+        device_telemetry.ssm_traced(ssm.heads, ssm.state, ssm.groups,
+                                    ssm.chunk, ssm.recompute)
+
+        def mix(blk, x):
+            z, xbc, dt = self._ssm_parts(ssm, blk, x)
+            with jax.named_scope("conv"):
+                xbc = jax.nn.silu(
+                    ops.causal_conv(xbc, blk["conv_w"], blk["conv_b"]))
+            with jax.named_scope("scan"):
+                y = self._ssm_scanned(
+                    ssm, blk, xbc, dt,
+                    lambda *a: ops.ssd_scan(*a, chunk=ssm.chunk))
+            return self._ssm_out(ssm, blk, y, z)
+
+        with jax.named_scope("lm.ssm"):
+            return (jax.checkpoint(mix) if ssm.recompute else mix)(blk, x)
+
+    def _block_tail(self, spec, blk, x, mixed, taps=None):
+        """The mixer's residual (``mixed``: the attention's heads, flat,
+        before their out-projection, or what a state-space mixer adds;
+        None without a mixer) + the feed-forward, if the layer has one
+        (shared like _project_qkv)."""
+        import jax
+
+        if spec.mixer == "attention":
+            with jax.named_scope("lm.attn"), \
+                    jax.named_scope(spec.attn_kind), jax.named_scope("out"):
+                x = x + mixed @ blk["wo"]
+        elif spec.mixer == "ssm":
+            x = x + mixed
+        if spec.ffn is None:
+            return x
         if spec.ffn == "mlp":
             with jax.named_scope("lm.mlp"):
                 h = self._rms(x, blk["norm2"])
@@ -466,15 +666,16 @@ class BlockLM:
         y = moe.moe_ffn(
             rows, blk, total=e.total, top_k=e.top_k, scale=e.scale,
             first=moe.held_experts(e.total, e.share)[0],
-            chunk_rows=e.chunk_rows, taps=taps)
+            chunk_rows=e.chunk_rows, kind=e.kind, taps=taps)
         return x + y.reshape(x.shape)
 
     def apply(self, params, tokens):
         """tokens (max_seq,) int -> logits (max_seq, vocab).
 
         The named scopes (``lm.embed``, ``lm.attn`` with ``window`` or
-        ``full`` and under it ``qkv``, ``kernel``, ``out``, ``lm.mlp``,
-        ``lm.moe``, ``lm.head_loss``) are metadata: every op's
+        ``full`` and under it ``qkv``, ``kernel``, ``out``, ``lm.ssm``
+        with ``in_proj``, ``conv``, ``scan``, ``gate_norm``, ``out``,
+        ``lm.mlp``, ``lm.moe``, ``lm.head_loss``) are metadata: every op's
         ``op_name`` in a profile starts with its phase."""
         return self._forward(params, tokens)
 
@@ -492,20 +693,25 @@ class BlockLM:
                      for rope, (cos, sin)
                      in self._rope_tables(jnp.arange(S)).items()}
         for spec, blk in zip(self.blocks, params["blocks"]):
-            with jax.named_scope("lm.attn"), \
-                    jax.named_scope(spec.attn_kind):
-                with jax.named_scope("qkv"):
-                    h = self._rms(x, blk["norm1"])
-                    q, k, v = self._project_qkv(blk, h)
-                    q = q.reshape(S, spec.heads, Dh)
-                    k = k.reshape(S, KVH, Dh)
-                    v = v.reshape(S, KVH, Dh)
-                    if spec.rope is not None:
-                        q = self._rope_rotate(q, *ropes[spec.rope])
-                        k = self._rope_rotate(k, *ropes[spec.rope])
-                with jax.named_scope("kernel"):
-                    attn = self._attend(q, k, v, spec.window).reshape(S, -1)
-            x = self._block_tail(spec, blk, x, attn, taps)
+            mixed = None
+            if spec.mixer == "ssm":
+                mixed = self._ssm_mix(spec.ssm, blk, x)
+            elif spec.mixer == "attention":
+                with jax.named_scope("lm.attn"), \
+                        jax.named_scope(spec.attn_kind):
+                    with jax.named_scope("qkv"):
+                        h = self._rms(x, blk["norm1"])
+                        q, k, v = self._project_qkv(blk, h)
+                        q = q.reshape(S, spec.heads, Dh)
+                        k = k.reshape(S, KVH, Dh)
+                        v = v.reshape(S, KVH, Dh)
+                        if spec.rope is not None:
+                            q = self._rope_rotate(q, *ropes[spec.rope])
+                            k = self._rope_rotate(k, *ropes[spec.rope])
+                    with jax.named_scope("kernel"):
+                        mixed = self._attend(
+                            q, k, v, spec.window).reshape(S, -1)
+            x = self._block_tail(spec, blk, x, mixed, taps)
         with jax.named_scope("lm.head_loss"):
             x = self._rms(x, params["final_norm"])
             return x @ params["out"]
@@ -552,16 +758,33 @@ class BlockLM:
             return -jnp.mean(
                 jnp.take_along_axis(logp, targets[:, None], axis=1))
 
+    def token_losses(self, params, tokens):
+        """``loss`` before its mean: the next-token cross-entropy of each
+        position 0..S-2, (S-1,). A whole forward pass; for checks that
+        must see where in the sequence a result is wrong (the mean hides
+        it), not for the step."""
+        import jax
+        import jax.numpy as jnp
+
+        logits = self.apply(params, tokens)[:-1]
+        with jax.named_scope("lm.head_loss"):
+            logp = jax.nn.log_softmax(logits, axis=-1)
+            return -jnp.take_along_axis(logp, tokens[1:, None], axis=1)[:, 0]
+
     # ------------------------------------------------------------------
     # Inference: autoregressive decode with per-layer KV caches.
     # ------------------------------------------------------------------
     def _decode_step(self, params, caches, pos, tok):
         """One incremental position: returns (new_caches, logits).
 
-        caches: per block {"k": (S, kv_heads, Dh), "v": same} — only
-        rows [0, pos] are valid; this step writes row ``pos`` and
-        attends q against the masked cache. O(S) per step with static
-        shapes (jit/scan friendly), single device — decode is a
+        caches: per attention block {"k": (S, kv_heads, Dh), "v": same}
+        — only rows [0, pos] are valid; this step writes row ``pos`` and
+        attends q against the masked cache. Per state-space block
+        {"conv": the convolution's last ``conv - 1`` inputs, "state":
+        (heads, head_dim, state) float32}: the recurrence itself, one
+        position a step (``ops.ssm.ssd_step``), not the chunked scan. A
+        block without a mixer has an empty cache. O(S) per step with
+        static shapes (jit/scan friendly), single device — decode is a
         latency path, not a sharded-compute path.
         """
         import jax
@@ -574,6 +797,23 @@ class BlockLM:
         ropes = self._rope_tables(pos)                       # (r/2,) each
         new_caches = []
         for spec, blk, cache in zip(self.blocks, params["blocks"], caches):
+            if spec.mixer != "attention":
+                mixed = None
+                if spec.mixer == "ssm":
+                    from fiber_tpu.ops import ssm as ops
+
+                    z, xbc, dt = self._ssm_parts(spec.ssm, blk, x)
+                    taps = jnp.concatenate([cache["conv"], xbc[None]])
+                    xbc = jax.nn.silu(blk["conv_b"] + jnp.sum(
+                        taps * blk["conv_w"].T, axis=0))
+                    state, y = self._ssm_scanned(
+                        spec.ssm, blk, xbc, dt,
+                        lambda *a: ops.ssd_step(cache["state"], *a))
+                    cache = {"conv": taps[1:], "state": state}
+                    mixed = self._ssm_out(spec.ssm, blk, y, z)
+                new_caches.append(cache)
+                x = self._block_tail(spec, blk, x, mixed)
+                continue
             h = self._rms(x, blk["norm1"])
             q, k, v = self._project_qkv(blk, h)
             q = q.reshape(KVH, spec.heads // KVH, Dh)
@@ -606,6 +846,30 @@ class BlockLM:
         x = self._rms(x, params["final_norm"])
         return new_caches, x @ params["out"]
 
+    def init_caches(self, dtype) -> list:
+        """Empty decode caches, one a block (``_decode_step`` says what
+        each kind holds). KV caches and the convolution's inputs follow
+        ``dtype`` (the params' — an f32 cache under bf16 params would
+        silently double the KV-cache footprint, the very memory GQA
+        exists to save); a state-space layer's state is float32."""
+        import jax.numpy as jnp
+
+        S, KVH, Dh = self.max_seq, self.kv_heads, self.head_dim
+        caches = []
+        for spec in self.blocks:
+            if spec.mixer == "attention":
+                caches.append({"k": jnp.zeros((S, KVH, Dh), dtype),
+                               "v": jnp.zeros((S, KVH, Dh), dtype)})
+            elif spec.mixer == "ssm":
+                m = spec.ssm
+                caches.append({
+                    "conv": jnp.zeros((m.conv - 1, m.conv_dim), dtype),
+                    "state": jnp.zeros((m.heads, m.head_dim, m.state),
+                                       jnp.float32)})
+            else:
+                caches.append({})
+        return caches
+
     def generate(self, params, prompt, steps: int, key=None,
                  temperature: float = 0.0):
         """Decode ``steps`` tokens after ``prompt`` (1-D int array).
@@ -629,16 +893,7 @@ class BlockLM:
             raise ValueError("sampling (temperature > 0) needs a key")
         key = key if key is not None else jax.random.PRNGKey(0)
 
-        S, KVH, Dh = self.max_seq, self.kv_heads, self.head_dim
-        # Caches follow the params dtype — an f32 cache under bf16
-        # params would silently double the KV-cache footprint, the very
-        # memory GQA exists to save.
-        cdtype = params["embed"].dtype
-        caches = [
-            {"k": jnp.zeros((S, KVH, Dh), cdtype),
-             "v": jnp.zeros((S, KVH, Dh), cdtype)}
-            for _ in params["blocks"]
-        ]
+        caches = self.init_caches(params["embed"].dtype)
 
         def prefill(carry, inp):
             caches = carry

@@ -12,9 +12,10 @@ the other chips' parts; nothing here stands in for them.
 Shapes are static and no token is dropped: every (token, held expert)
 pair is computed whatever the routing. The pairs are sorted by expert
 and walked in chunks of ``chunk_rows`` rows: gather the chunk's token
-rows, three grouped products (``jax.lax.ragged_dot``, which XLA's TPU
-backend lowers to a grouped Mosaic kernel of its own, ``ragged-dot-*``
-in a profile), scatter-add. The loops run over the chunks that *exist*
+rows, the expert's grouped products (``jax.lax.ragged_dot``, which XLA's
+TPU backend lowers to a grouped Mosaic kernel of its own,
+``ragged-dot-*`` in a profile: three for a gated expert, two for an
+ungated one), scatter-add. The loops run over the chunks that *exist*
 (``ceil(pairs / chunk_rows)``, known on the device), so memory follows
 the chunk and time follows the pairs; the worst case (every token on a
 held expert) only makes the loop longer. The backward pass is written
@@ -119,45 +120,64 @@ def _grouped(rows, matrices, sizes):
     return jax.lax.ragged_dot(rows, matrices, sizes)
 
 
-def _chunk_experts(x, weight, valid, sizes, wg, wu, wd):
+def _expert_hidden(kind: str, products):
+    """An expert's hidden activation from its first products:
+    ``"swiglu"`` ``silu(x Wg) * (x Wu)`` (gated), ``"relu2"`` ``relu(x
+    Wu)^2`` (ungated)."""
+    import jax
+    import jax.numpy as jnp
+
+    if kind == "swiglu":
+        gate, up = products
+        return jax.nn.silu(gate) * up
+    if kind == "relu2":
+        (up,) = products
+        return jnp.square(jax.nn.relu(up))
+    raise ValueError(f"unknown expert kind {kind!r}")
+
+
+def _chunk_experts(x, weight, valid, sizes, mats, kind: str = "swiglu"):
     """Rows ``x`` (R, d), sorted by expert with ``sizes`` rows each:
-    ``weight * (silu(x Wg_e) * (x Wu_e)) Wd_e`` a row; 0 in the rows
-    that are no pair on a held expert (``valid`` false), which no group
-    owns. Every grouped product's result is masked where it is made,
-    and ``x`` on the way in, so that its gradient is masked too."""
+    ``weight * expert_e(x)`` a row, ``mats`` the stacked matrices of the
+    expert ``kind`` (``(Wg, Wu, Wd)`` gated, ``(Wu, Wd)`` ungated); 0 in
+    the rows that are no pair on a held expert (``valid`` false), which
+    no group owns. Every grouped product's result is masked where it is
+    made, and ``x`` on the way in, so that its gradient is masked too."""
     import jax
     import jax.numpy as jnp
 
     keep = valid[:, None]
     with jax.named_scope("experts"):
         x = jnp.where(keep, x, 0.0)
-        gate = jnp.where(keep, _grouped(x, wg, sizes), 0.0)
-        up = jnp.where(keep, _grouped(x, wu, sizes), 0.0)
-        down = _grouped(jax.nn.silu(gate) * up, wd, sizes)
+        first = [jnp.where(keep, _grouped(x, m, sizes), 0.0)
+                 for m in mats[:-1]]
+        down = _grouped(_expert_hidden(kind, first), mats[-1], sizes)
         return jnp.where(keep, down * weight[:, None], 0.0)
 
 
-def routed_experts(h, ids, weights, wg, wu, wd, *, first: int,
-                   chunk_rows: int):
+def routed_experts(h, ids, weights, *mats, first: int, chunk_rows: int,
+                   kind: str = "swiglu"):
     """``sum over the taken experts e held here of weights_e *
     expert_e(h)`` for every row of ``h`` (S, d): ``ids`` / ``weights``
-    (S, top_k) as ``route`` gives them, ``wg`` / ``wu`` (E, d, w) and
-    ``wd`` (E, w, d) the held experts ``first .. first + E - 1``, each a
-    SwiGLU. Dropless at static shapes; see the module's head."""
-    return _routed(int(first), int(chunk_rows))(h, ids, weights, wg, wu, wd)
+    (S, top_k) as ``route`` gives them, ``mats`` the held experts
+    ``first .. first + E - 1`` stacked: ``wg`` / ``wu`` (E, d, w) and
+    ``wd`` (E, w, d) for ``kind="swiglu"``, ``wu`` and ``wd`` for
+    ``"relu2"``. Dropless at static shapes; see the module's head."""
+    return _routed(int(first), int(chunk_rows), kind)(h, ids, weights, *mats)
 
 
 @functools.lru_cache(maxsize=None)
-def _routed(first: int, chunk_rows: int):
-    """The differentiable walk for one (first held expert, chunk)."""
+def _routed(first: int, chunk_rows: int, kind: str = "swiglu"):
+    """The differentiable walk for one (first held expert, chunk, expert
+    kind)."""
     import jax
     import jax.numpy as jnp
 
-    def fwd(h, ids, weights, wg, wu, wd):
+    def fwd(h, ids, weights, *mats):
         top_k = ids.shape[1]
         rows = min(chunk_rows, ids.size)
         with jax.named_scope("dispatch"):
-            order, ends, pairs = _plan(ids, first, wg.shape[0], rows)
+            order, ends, pairs = _plan(ids, first, mats[0].shape[0], rows)
         flat_w = weights.reshape(-1)
 
         def body(c, out):
@@ -165,47 +185,45 @@ def _routed(first: int, chunk_rows: int):
                 pair, token, valid, sizes = _chunk_of(
                     c, order, ends, pairs, rows, top_k)
                 x = h[token]
-            y = _chunk_experts(x, flat_w[pair], valid, sizes, wg, wu, wd)
+            y = _chunk_experts(x, flat_w[pair], valid, sizes, mats, kind)
             with jax.named_scope("combine"):
                 return out.at[token].add(y)
 
         out = jax.lax.fori_loop(0, (pairs + rows - 1) // rows, body,
                                 jnp.zeros_like(h))
-        return out, (h, weights, wg, wu, wd, order, ends, pairs)
+        return out, (h, weights, mats, order, ends, pairs)
 
     def bwd(res, d_out):
-        h, weights, wg, wu, wd, order, ends, pairs = res
+        h, weights, mats, order, ends, pairs = res
         top_k = weights.shape[1]
         rows = min(chunk_rows, weights.size)
         flat_w = weights.reshape(-1)
 
         def body(c, carry):
-            d_h, d_w, d_wg, d_wu, d_wd = carry
+            d_h, d_w, d_mats = carry
             with jax.named_scope("dispatch"):
                 pair, token, valid, sizes = _chunk_of(
                     c, order, ends, pairs, rows, top_k)
                 x, d_y = h[token], d_out[token]
             _, vjp = jax.vjp(
-                lambda x, w, g, u, d: _chunk_experts(x, w, valid, sizes,
-                                                     g, u, d),
-                x, flat_w[pair], wg, wu, wd)
-            d_x, d_row, g_wg, g_wu, g_wd = vjp(d_y)
+                lambda x, w, *m: _chunk_experts(x, w, valid, sizes, m, kind),
+                x, flat_w[pair], *mats)
+            d_x, d_row, *g_mats = vjp(d_y)
             with jax.named_scope("combine"):
                 d_h = d_h.at[token].add(d_x)
                 d_w = jax.lax.dynamic_update_slice(d_w, d_row, (c * rows,))
-            return d_h, d_w, d_wg + g_wg, d_wu + g_wu, d_wd + g_wd
+            return d_h, d_w, tuple(d + g for d, g in zip(d_mats, g_mats))
 
-        d_h, d_w, d_wg, d_wu, d_wd = jax.lax.fori_loop(
+        d_h, d_w, d_mats = jax.lax.fori_loop(
             0, (pairs + rows - 1) // rows, body,
             (jnp.zeros_like(h), jnp.zeros(order.shape, weights.dtype),
-             jnp.zeros_like(wg), jnp.zeros_like(wu), jnp.zeros_like(wd)))
+             tuple(jnp.zeros_like(m) for m in mats)))
         with jax.named_scope("combine"):
             # back from sorted order to (token, slot); rows past the held
             # pairs were never written and stay 0 (the padding of
             # ``order`` points at pair 0 and adds those zeros)
             d_weights = jnp.zeros_like(flat_w).at[order].add(d_w)
-        return (d_h, None, d_weights.reshape(weights.shape),
-                d_wg, d_wu, d_wd)
+        return (d_h, None, d_weights.reshape(weights.shape)) + d_mats
 
     routed = jax.custom_vjp(lambda *a: fwd(*a)[0])
     routed.defvjp(fwd, bwd)
@@ -219,29 +237,40 @@ def swiglu(h, wg, wu, wd):
     return (jax.nn.silu(h @ wg) * (h @ wu)) @ wd
 
 
+def relu2(h, wu, wd):
+    """``relu(h Wu)^2 Wd``: the ungated squared-relu feed-forward."""
+    return _expert_hidden("relu2", [h @ wu]) @ wd
+
+
+#: the parameter names of an expert kind's matrices, in product order
+EXPERT_MATRICES = {"swiglu": ("wg", "wu", "wd"), "relu2": ("wu", "wd")}
+
+
 def moe_ffn(h, blk, *, total: int, top_k: int, scale: float,
-            first: int, chunk_rows: int, taps=None):
+            first: int, chunk_rows: int, kind: str = "swiglu", taps=None):
     """The expert layer on rows ``h`` (S, d): ``shared(h) + routed part
-    of the experts held here``. ``blk`` holds ``router`` (d, total),
-    ``shared_wg`` / ``shared_wu`` / ``shared_wd`` and the held experts'
-    stacked ``experts_wg`` / ``experts_wu`` / ``experts_wd``. ``taps``,
-    a list, is given (taken ids, load of each held expert)."""
+    of the experts held here``. ``blk`` holds ``router`` (d, total) and,
+    per matrix of the expert ``kind`` (``EXPERT_MATRICES``), the shared
+    expert's ``shared_<m>`` (of its own width) and the held experts'
+    stacked ``experts_<m>``. ``taps``, a list, is given (taken ids, load
+    of each held expert)."""
     import jax
 
     from fiber_tpu.telemetry import device as device_telemetry
 
-    count = blk["experts_wg"].shape[0]
+    names = EXPERT_MATRICES[kind]
+    count = blk["experts_wd"].shape[0]
     device_telemetry.moe_traced(count, total, top_k)
     with jax.named_scope("lm.moe"):
         with jax.named_scope("router"):
             ids, weights = route(h, blk["router"], top_k=top_k, scale=scale)
         if taps is not None:
             taps.append((ids, expert_load(ids, first, count)))
-        routed = routed_experts(h, ids, weights, blk["experts_wg"],
-                                blk["experts_wu"], blk["experts_wd"],
-                                first=first, chunk_rows=chunk_rows)
+        routed = routed_experts(
+            h, ids, weights, *(blk["experts_" + m] for m in names),
+            first=first, chunk_rows=chunk_rows, kind=kind)
         with jax.named_scope("shared"):
-            shared = swiglu(h, blk["shared_wg"], blk["shared_wu"],
-                            blk["shared_wd"])
+            dense = swiglu if kind == "swiglu" else relu2
+            shared = dense(h, *(blk["shared_" + m] for m in names))
         with jax.named_scope("combine"):
             return shared + routed

@@ -94,6 +94,10 @@ _m_moe_traces = telemetry.counter(
     "moe_layers_traced",
     "Sparse-expert layers traced, by experts held here, experts in "
     "all and experts a token takes")
+_m_ssm_traces = telemetry.counter(
+    "ssm_layers_traced",
+    "State-space layers traced, by heads, state size, groups, chunk "
+    "and whether the backward pass recomputes the mixer")
 _m_flash_grid_steps = telemetry.counter(
     "flash_grid_steps",
     "Inner grid steps a head makes in the flash-attention programs "
@@ -613,6 +617,17 @@ def moe_traced(held: int, total: int, top_k: int) -> None:
     traces, not calls, like ``rollout_traced``: an operator reads off it
     which share of the experts the compiled program holds."""
     _m_moe_traces.inc(held=str(held), total=str(total), top_k=str(top_k))
+
+
+def ssm_traced(heads: int, state: int, groups: int, chunk: int,
+               recompute: bool) -> None:
+    """One state-space layer was traced (``models/transformer.py``).
+    Counts traces, not calls, like ``moe_traced``; a recomputed mixer's
+    forward pass is traced once (``jax.checkpoint`` replays the traced
+    equations), so recomputation does not move it twice."""
+    _m_ssm_traces.inc(heads=str(heads), state=str(state),
+                      groups=str(groups), chunk=str(chunk),
+                      recompute=str(bool(recompute)).lower())
 
 
 def flash_grid_built(kernel: str, run: int, idle: int) -> None:

@@ -481,9 +481,10 @@ class BlockLM:
             from fiber_tpu.ops.pallas_attention import flash_attention
 
             if self._flash_multi:
-                from fiber_tpu.ops.ring_attention import ring_attention
+                from fiber_tpu.ops.ring_attention import (
+                    ring_attention_ordered)
 
-                return ring_attention(
+                return ring_attention_ordered(
                     q, k, v, mesh=self._mesh, causal=True,
                     local="flash", interpret=self.interpret)
             return flash_attention(q, k, v, causal=True,
@@ -494,9 +495,27 @@ class BlockLM:
 
             return ulysses_attention(q, k, v, mesh=self._mesh,
                                      causal=True)
-        from fiber_tpu.ops.ring_attention import ring_attention
+        from fiber_tpu.ops.ring_attention import ring_attention_ordered
 
-        return ring_attention(q, k, v, mesh=self._mesh, causal=True)
+        return ring_attention_ordered(q, k, v, mesh=self._mesh,
+                                      causal=True)
+
+    def _ring_order(self):
+        """The positions of the rows as ``_forward`` holds them: on the
+        ring (``attention="ring"``, or flash over several chips) the
+        ring's own order (``ops.ring_attention.ring_order``: zigzag for
+        a length whole in 2n half-blocks, so that no chip waits under
+        the causal mask), as a numpy array; None for the natural order
+        (one chip, Ulysses, the reference, a length that does not
+        halve). Everything but attention acts on one row at a time, so
+        ordering the token ids orders the whole step at no traffic."""
+        if self.attention != "ring" and not self._flash_multi:
+            return None
+        from fiber_tpu.ops.ring_attention import ring_order
+        from fiber_tpu.parallel.mesh import default_mesh
+
+        mesh = self._mesh or default_mesh()
+        return ring_order(self.max_seq, mesh.shape["pool"], causal=True)
 
     def _rms(self, x, g):
         import jax.numpy as jnp
@@ -677,21 +696,34 @@ class BlockLM:
         with ``in_proj``, ``conv``, ``scan``, ``gate_norm``, ``out``,
         ``lm.mlp``, ``lm.moe``, ``lm.head_loss``) are metadata: every op's
         ``op_name`` in a profile starts with its phase."""
-        return self._forward(params, tokens)
+        import numpy as np
 
-    def _forward(self, params, tokens, taps=None):
+        order = self._ring_order()
+        logits = self._forward(params, tokens, order=order)
+        # by position again: argsort inverts the order
+        return logits if order is None else logits[np.argsort(order)]
+
+    def _forward(self, params, tokens, taps=None, order=None):
+        """Logits of ``tokens``. With ``order`` (``_ring_order``) row
+        ``r`` of everything in here, the logits too, is position
+        ``order[r]``: the ids are taken in that order and ropes and the
+        position table get the rows' true positions."""
         import jax
         import jax.numpy as jnp
 
         S, Dh = self.max_seq, self.head_dim
         KVH = self.kv_heads
         with jax.named_scope("lm.embed"):
+            if order is not None:
+                tokens = tokens[order]
             x = params["embed"][tokens]                      # (S, dim)
             if self.pos == "learned":
-                x = x + params["pos"]
+                x = x + (params["pos"] if order is None
+                         else params["pos"][order])
+            positions = jnp.arange(S) if order is None else order
             ropes = {rope: (cos[:, None, :], sin[:, None, :])  # (S, 1, r/2)
                      for rope, (cos, sin)
-                     in self._rope_tables(jnp.arange(S)).items()}
+                     in self._rope_tables(positions).items()}
         for spec, blk in zip(self.blocks, params["blocks"]):
             mixed = None
             if spec.mixer == "ssm":
@@ -723,12 +755,17 @@ class BlockLM:
         held) int32, the tokens each held expert gets}``. A whole
         forward pass; for set-up and checks, not for the step."""
         import jax.numpy as jnp
+        import numpy as np
 
         taps = []
-        self._forward(params, tokens, taps)
+        order = self._ring_order()
+        self._forward(params, tokens, taps, order=order)
         if not taps:
             raise ValueError("the model has no expert layer")
-        return {"ids": jnp.stack([ids for ids, _ in taps]),
+        ids = jnp.stack([ids for ids, _ in taps])
+        if order is not None:
+            ids = ids[:, np.argsort(order)]
+        return {"ids": ids,
                 "load": jnp.stack([load for _, load in taps])}
 
     def probe_routing(self, params, tokens) -> dict:
@@ -750,7 +787,23 @@ class BlockLM:
         """Mean next-token cross-entropy over positions 0..S-2."""
         import jax
         import jax.numpy as jnp
+        import numpy as np
 
+        order = self._ring_order()
+        if order is not None:
+            # The rows stay where the ring has them: each takes its
+            # target by its true position, the row of position S-1 has
+            # none and is left out; a mean over the same S-1 terms, so
+            # no logits move between chips.
+            S = self.max_seq
+            logits = self._forward(params, tokens, order=order)
+            with jax.named_scope("lm.head_loss"):
+                targets = tokens[np.minimum(order + 1, S - 1)]
+                picked = jnp.take_along_axis(
+                    logits, targets[:, None], axis=1)[:, 0]
+                losses = jax.nn.logsumexp(logits, axis=-1) - picked
+                return jnp.sum(
+                    jnp.where(order < S - 1, losses, 0.0)) / (S - 1)
         logits = self.apply(params, tokens)[:-1]             # (S-1, V)
         with jax.named_scope("lm.head_loss"):
             targets = tokens[1:]

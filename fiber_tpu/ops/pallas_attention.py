@@ -374,13 +374,21 @@ def flash_attention(q, k, v, *, causal: bool = False,
     (shape, dtype, flags).
     """
     fn = _build(q.shape, str(q.dtype), causal, block_q, block_kv,
-                interpret, _kv_heads_of(q, k), window)
+                interpret, _kv_heads_of(q, k), window, _kv_len_of(q, k))
     return fn(q, k, v)
 
 
 def _kv_heads_of(q, k):
     """None for plain MHA (cache-key stability), kv head count for GQA."""
     return None if k.shape[1] == q.shape[1] else k.shape[1]
+
+
+def _kv_len_of(q, k):
+    """None where keys and queries are as many (cache-key stability),
+    else the number of keys: a non-causal call may be rectangular (the
+    zigzag ring attends all its rows to half a visiting block, and half
+    its rows to a whole one)."""
+    return None if k.shape[0] == q.shape[0] else k.shape[0]
 
 
 def flash_attention_lse(q, k, v, *, causal: bool = False,
@@ -401,13 +409,28 @@ def flash_attention_lse(q, k, v, *, causal: bool = False,
     window (the ring composition does not pass a window).
     """
     fn = _build_lse(q.shape, str(q.dtype), causal, block_q, block_kv,
-                    interpret, _kv_heads_of(q, k), window)
+                    interpret, _kv_heads_of(q, k), window,
+                    _kv_len_of(q, k))
     return fn(q, k, v)
+
+
+def flash_attention_lse_bwd(q, k, v, out, lse, dout, dlse, *,
+                            causal: bool = False, block_q: int = 512,
+                            block_kv: int = 512,
+                            interpret: bool = False):
+    """:func:`flash_attention_lse`'s backward pass from that call's own
+    ``(out, lse)`` and their cotangents: ``(dq, dk, dv)``. For a caller
+    with a VJP of its own that already keeps ``out`` and ``lse`` (the
+    ring keeps each rotation's partial once, for its merge and for
+    this); everyone else differentiates :func:`flash_attention_lse`."""
+    _, bwd = _cores(q.shape, str(q.dtype), causal, block_q, block_kv,
+                    interpret, _kv_heads_of(q, k), None, _kv_len_of(q, k))
+    return bwd(q, k, v, out, lse, dout, dlse)
 
 
 @functools.lru_cache(maxsize=64)
 def _build_calls(shape, dtype, causal, block_q, block_kv, interpret,
-                 kv_heads=None, window=None):
+                 kv_heads=None, window=None, kv_len=None):
     """The three pallas_call programs (fwd, dq, dkv) for one config —
     shared by the out-only and the (out, lse) entry points.
 
@@ -422,7 +445,11 @@ def _build_calls(shape, dtype, causal, block_q, block_kv, interpret,
     kv_heads heads and every group of ``heads // kv_heads`` query heads
     reads the same KV block (the index maps do the sharing — no
     repeated KV ever materializes); dk/dv accumulate across the group
-    inside the kernel."""
+    inside the kernel.
+
+    ``kv_len`` is the number of keys where it is not the number of
+    queries (non-causal only: the causal mask is in local coordinates
+    and takes row i for key i)."""
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
@@ -442,10 +469,15 @@ def _build_calls(shape, dtype, causal, block_q, block_kv, interpret,
         raise ValueError("window requires causal=True")
     if window is not None and window < 1:
         raise ValueError(f"window must be >= 1, got {window}")
+    s_kv = s if kv_len is None else kv_len
+    if causal and s_kv != s:
+        raise ValueError(
+            f"causal attention of {s} queries over {s_kv} keys: the "
+            "mask takes row i for key i and needs as many of each")
     bq = _pick_block(s, block_q)
-    bk = _pick_block(s, block_kv)
+    bk = _pick_block(s_kv, block_kv)
     n_q = s // bq
-    n_kv = s // bk
+    n_kv = s_kv // bk
     scale = 1.0 / (d ** 0.5)
 
     # Inner extents: the widest band any q-block (kv-block, for dkv)
@@ -526,8 +558,8 @@ def _build_calls(shape, dtype, causal, block_q, block_kv, interpret,
         in_specs=[dkv_q_spec, dkv_k_spec, dkv_k_spec, dkv_q_spec,
                   dkv_row_spec, dkv_row_spec],
         out_specs=[dkv_k_spec, dkv_k_spec],
-        out_shape=[jax.ShapeDtypeStruct((kvh, s, d), dtype),
-                   jax.ShapeDtypeStruct((kvh, s, d), dtype)],
+        out_shape=[jax.ShapeDtypeStruct((kvh, s_kv, d), dtype),
+                   jax.ShapeDtypeStruct((kvh, s_kv, d), dtype)],
         scratch_shapes=[pltpu.VMEM((bk, d), jnp.float32),
                         pltpu.VMEM((bk, d), jnp.float32)],
         interpret=interpret,
@@ -536,14 +568,17 @@ def _build_calls(shape, dtype, causal, block_q, block_kv, interpret,
     return fwd_call, dq_call, dkv_call
 
 
-def _make_attn(shape, dtype, causal, block_q, block_kv, interpret,
-               with_lse: bool, kv_heads=None, window=None):
-    import jax
+@functools.lru_cache(maxsize=64)
+def _cores(shape, dtype, causal, block_q, block_kv, interpret,
+           kv_heads=None, window=None, kv_len=None):
+    """(forward, backward) over the public (S, H, D) layout for one
+    config: ``forward(q, k, v) -> (out, lse)`` and ``backward(q, k, v,
+    out, lse, dout, dlse) -> (dq, dk, dv)``."""
     import jax.numpy as jnp
 
     fwd_call, dq_call, dkv_call = _build_calls(
         shape, dtype, causal, block_q, block_kv, interpret, kv_heads,
-        window)
+        window, kv_len)
 
     def _fwd_core(q, k, v):
         """(S,H,D) API -> (H,S,D) kernels and back; the kernel's
@@ -567,6 +602,17 @@ def _make_attn(shape, dtype, causal, block_q, block_kv, interpret,
         dq = dq_call(qt, kt, vt, dot, lse, delta)
         dk, dv = dkv_call(qt, kt, vt, dot, lse, delta)
         return tuple(jnp.swapaxes(g, 0, 1) for g in (dq, dk, dv))
+
+    return _fwd_core, _bwd_core
+
+
+def _make_attn(shape, dtype, causal, block_q, block_kv, interpret,
+               with_lse: bool, kv_heads=None, window=None, kv_len=None):
+    import jax
+
+    _fwd_core, _bwd_core = _cores(
+        shape, dtype, causal, block_q, block_kv, interpret, kv_heads,
+        window, kv_len)
 
     if not with_lse:
         @jax.custom_vjp
@@ -604,15 +650,15 @@ def _make_attn(shape, dtype, causal, block_q, block_kv, interpret,
 
 @functools.lru_cache(maxsize=64)
 def _build(shape, dtype, causal, block_q, block_kv, interpret,
-           kv_heads=None, window=None):
+           kv_heads=None, window=None, kv_len=None):
     return _make_attn(shape, dtype, causal, block_q, block_kv,
                       interpret, with_lse=False, kv_heads=kv_heads,
-                      window=window)
+                      window=window, kv_len=kv_len)
 
 
 @functools.lru_cache(maxsize=64)
 def _build_lse(shape, dtype, causal, block_q, block_kv, interpret,
-               kv_heads=None, window=None):
+               kv_heads=None, window=None, kv_len=None):
     return _make_attn(shape, dtype, causal, block_q, block_kv,
                       interpret, with_lse=True, kv_heads=kv_heads,
-                      window=window)
+                      window=window, kv_len=kv_len)

@@ -16,6 +16,25 @@ rotations the result equals exact softmax attention.
 
 Causal masking uses global positions derived from ``axis_index``, so the
 mask stays correct as blocks rotate.
+
+The order of the rows on the chips (:func:`ring_order`). Contiguous
+blocks leave a causal ring unbalanced: chip ``i`` attends ``i`` whole
+blocks and half of its own while the ring steps in lockstep, so every
+rotation costs what the last chip's costs (3.5 block-units on four
+chips for a mean of 2). A causal ring over ``n > 1`` chips whose length
+is whole in ``2n`` half-blocks therefore holds them in zigzag: chip
+``i`` has half-blocks ``i`` and ``2n - 1 - i``, early half first, and
+every chip attends two half-block pairs in every rotation
+(:func:`ring_schedule`). Nobody chooses the order: it follows from
+(length, chips, causal). :func:`ring_attention` takes and returns the
+natural order and reorders at its own door. The LM trainer
+(``models/transformer.py`` ``BlockLM``) orders the token ids instead,
+once a step, gives ropes and the position table the rows' true
+positions, calls :func:`ring_attention_ordered`, and restores the
+natural order only where it returns by position (``apply``,
+``token_losses``, ``routing``): its loss is a mean and needs none.
+:func:`ring_attention_local`, the body for a caller's own
+``shard_map``, the non-causal ring and Ulysses keep contiguous blocks.
 """
 
 from __future__ import annotations
@@ -58,15 +77,71 @@ _compiled_cache: dict = {}
 _KV_CHUNK = 1024
 
 
-def _accumulate_block(q_blk, q_pos, k_cur, v_cur, kv_pos0, m, l, o,
+def ring_order(seq: int, n_dev: int, causal: bool):
+    """The positions of a sequence's rows as the ring holds them:
+    ``order[r]`` is the position of row ``r`` and chip ``i`` has rows
+    ``[i * seq / n_dev, (i + 1) * seq / n_dev)``. A causal ring over
+    more than one chip whose length is whole in ``2 n_dev`` half-blocks
+    holds them in zigzag (chip ``i``: half-blocks ``i`` and ``2 n_dev -
+    1 - i``, early half first), as a numpy array; any other ring keeps
+    the natural order, which is ``None``. ``numpy.argsort(order)`` puts
+    rows back by position."""
+    if not causal or n_dev < 2 or seq % (2 * n_dev):
+        return None
+    import numpy as np
+
+    halves = np.arange(seq).reshape(2 * n_dev, seq // (2 * n_dev))
+    return np.stack([halves[:n_dev], halves[:n_dev - 1:-1]],
+                    axis=1).reshape(seq)
+
+
+def ring_schedule(seq: int, n_dev: int, causal: bool):
+    """(layout, pairs): the order :func:`ring_order` gives a ring of
+    this length (``"zigzag"`` or ``"contiguous"``) and, from the mask
+    alone, the (query, key) pairs that chip ``i`` attends in rotation
+    ``t`` (``pairs[i][t]``; rotation ``t`` brings the keys of chip ``(i
+    - t) % n_dev``; 0 is a rotation the flash engine skips). What the
+    ``ring_rotations_traced`` counter reads; no device needed."""
+    blk = seq // n_dev
+    diagonal = blk * (blk + 1) // 2
+    zigzag = ring_order(seq, n_dev, causal) is not None
+
+    def attended(chip, src):
+        if not causal:
+            return blk * blk
+        if src == chip:
+            return diagonal
+        if zigzag:      # all rows on an early half, or late rows on all
+            return blk * (blk // 2)
+        return blk * blk if src < chip else 0
+
+    return ("zigzag" if zigzag else "contiguous",
+            [[attended(i, (i - t) % n_dev) for t in range(n_dev)]
+             for i in range(n_dev)])
+
+
+def _local_positions(my, sq: int, n_dev: int, zigzag: bool):
+    """Global positions of the ``sq`` rows chip ``my`` (traced or not)
+    holds under the ring's order."""
+    import jax.numpy as jnp
+
+    if not zigzag:
+        return my * sq + jnp.arange(sq)
+    half = sq // 2
+    return jnp.concatenate([
+        my * half + jnp.arange(half),
+        (2 * n_dev - 1 - my) * half + jnp.arange(half)])
+
+
+def _accumulate_block(q_blk, q_pos, k_cur, v_cur, kv_pos, m, l, o,
                       causal: bool):
     """Online-softmax update of (m, l, o) with one KV block, internally
     chunked so the materialized score slab is bounded at
     (h, sq, _KV_CHUNK) — shared by the ring body (per rotation) and
     :func:`blockwise_attention` (single block = whole sequence).
 
-    q_blk: (sq, h, d); k_cur/v_cur: (skv, h, d); q_pos: (sq,) global
-    query positions; kv_pos0: scalar global position of k_cur[0].
+    q_blk: (sq, h, d); k_cur/v_cur: (skv, h, d); q_pos: (sq,) and
+    kv_pos: (skv,) global positions of the query and key rows.
     m, l: (h, sq); o: (sq, h, d) — all in ``_acc_dtype`` (>= f32),
     allocated by :func:`_acc_init`: with bf16 inputs the denominator l
     sums tens of thousands of terms, which 8 mantissa bits cannot carry
@@ -108,7 +183,6 @@ def _accumulate_block(q_blk, q_pos, k_cur, v_cur, kv_pos0, m, l, o,
 
     skv = k_cur.shape[0]
     if skv <= _KV_CHUNK:
-        kv_pos = kv_pos0 + jnp.arange(skv)
         return one_chunk(k_cur, v_cur, kv_pos, m, l, o)
     # Divisible prefix via scan; any remainder as one short tail chunk —
     # the O(sq x _KV_CHUNK) score bound must hold for ARBITRARY skv,
@@ -118,18 +192,18 @@ def _accumulate_block(q_blk, q_pos, k_cur, v_cur, kv_pos0, m, l, o,
     main = n_chunks * _KV_CHUNK
     k_ch = k_cur[:main].reshape(n_chunks, _KV_CHUNK, *k_cur.shape[1:])
     v_ch = v_cur[:main].reshape(n_chunks, _KV_CHUNK, *v_cur.shape[1:])
+    pos_ch = kv_pos[:main].reshape(n_chunks, _KV_CHUNK)
 
     def chunk_body(carry, inp):
         m, l, o = carry
-        kc, vc, idx = inp
-        kv_pos = kv_pos0 + idx * _KV_CHUNK + jnp.arange(_KV_CHUNK)
-        return one_chunk(kc, vc, kv_pos, m, l, o), None
+        kc, vc, pos = inp
+        return one_chunk(kc, vc, pos, m, l, o), None
 
     (m, l, o), _ = jax.lax.scan(
-        chunk_body, (m, l, o), (k_ch, v_ch, jnp.arange(n_chunks)))
+        chunk_body, (m, l, o), (k_ch, v_ch, pos_ch))
     if skv > main:
-        kv_pos = kv_pos0 + main + jnp.arange(skv - main)
-        m, l, o = one_chunk(k_cur[main:], v_cur[main:], kv_pos, m, l, o)
+        m, l, o = one_chunk(k_cur[main:], v_cur[main:], kv_pos[main:],
+                            m, l, o)
     return m, l, o
 
 
@@ -166,7 +240,8 @@ def blockwise_attention(q, k, v, causal: bool = False):
     sq = q.shape[0]
     q_pos = jnp.arange(sq)
     m0, l0, o0 = _acc_init(q)
-    m, l, o = _accumulate_block(q, q_pos, k, v, 0, m0, l0, o0, causal)
+    m, l, o = _accumulate_block(q, q_pos, k, v, jnp.arange(k.shape[0]),
+                                m0, l0, o0, causal)
     return _acc_finalize(o, l, q.dtype)
 
 
@@ -216,63 +291,124 @@ def _kv_rotate(k_cur, v_cur, *, axis: str, n_dev: int,
 
 def _ring_flash_local(q_blk, k_blk, v_blk, *, axis: str, n_dev: int,
                       causal: bool, interpret: bool,
-                      use_dma_ring: bool = False):
+                      use_dma_ring: bool = False, zigzag: bool = False):
     """Ring attention with the Pallas flash kernel as the per-device
     block: each rotation runs flash over (local Q, visiting KV) and the
     (out, lse) partials merge exactly (:func:`_merge_partials`).
 
-    Causality with rotating KV blocks is a THREE-WAY split on global
-    block position — the kernel's own causal flag only knows local
-    coordinates: the diagonal (src == my) runs the causal kernel,
-    fully-past blocks (src < my) run the unmasked kernel (every KV
-    position precedes every Q position), fully-future blocks are
-    skipped (lse = -1e30 zeroes them in the merge). ``lax.cond`` on the
-    traced src index picks the branch at runtime; differentiable end to
-    end (flash_attention_lse carries a custom VJP in both outputs).
+    Causality with rotating KV blocks is a THREE-WAY split on the
+    visiting block's chip ``src`` — the kernel's own causal flag only
+    knows local coordinates. The own block (``src == my``) runs the
+    causal kernel in either order: in zigzag's local coordinates
+    early-on-early is causal, late-on-early full, late-on-late causal
+    and early-on-late masked, which is the causal mask of the chip's
+    rows as they lie. A visiting block is past or future, picked at
+    runtime by ``lax.cond`` on the traced index:
+
+    - contiguous blocks: a fully-past block (``src < my``) runs the
+      unmasked kernel, a fully-future block is skipped (lse = -1e30
+      zeroes it in the merge), so the last chip computes in every
+      rotation and the first in one;
+    - zigzag (``zigzag=True``: the rows lie in :func:`ring_order`): for
+      ``src < my`` both local halves come after ``src``'s early half
+      and before its late half, so all rows attend ``k[:half]``
+      unmasked; for ``src > my`` the early half sees nothing of ``src``
+      and the late half comes after both of its halves, so rows
+      ``[half:]`` attend all of ``k`` unmasked and the early rows carry
+      lse = -1e30. Two half-block pairs on every chip in every
+      rotation; nothing is skipped.
+
+    Differentiable end to end: the own block through
+    flash_attention_lse's custom VJP, a visiting block through one of
+    its own (``visiting`` below).
     """
     import jax
     import jax.numpy as jnp
 
-    from fiber_tpu.ops.pallas_attention import flash_attention_lse
+    from fiber_tpu.ops.pallas_attention import (
+        flash_attention_lse, flash_attention_lse_bwd)
 
     sq, h, _ = q_blk.shape
+    half = sq // 2
     my = jax.lax.axis_index(axis)
 
-    def full_block(k_cur, v_cur):
-        o, lse = flash_attention_lse(q_blk, k_cur, v_cur, causal=False,
+    def attend(q, k, v, causal):
+        o, lse = flash_attention_lse(q, k, v, causal=causal,
                                      interpret=interpret)
         return o.astype(jnp.float32), lse
 
-    def diag_block(k_cur, v_cur):
-        o, lse = flash_attention_lse(q_blk, k_cur, v_cur, causal=True,
-                                     interpret=interpret)
-        return o.astype(jnp.float32), lse
+    def rows_padded(x, before=0, after=0):
+        return jnp.pad(x, [(before, after)] + [(0, 0)] * (x.ndim - 1))
 
-    def skip_block(k_cur, v_cur):
-        return (jnp.zeros(q_blk.shape, jnp.float32),
-                jnp.full((h, sq), -1e30, jnp.float32))
+    def backward(q, k, v, o, lse, do, dlse):
+        return flash_attention_lse_bwd(
+            q, k, v, o.astype(q.dtype), lse, do.astype(q.dtype), dlse,
+            interpret=interpret)
 
-    @jax.named_scope("ring.block")
-    def one_rotation(k_cur, v_cur, src):
-        if not causal:
-            return full_block(k_cur, v_cur)
-        return jax.lax.cond(
-            src == my,
-            diag_block,
-            lambda kc, vc: jax.lax.cond(
-                src < my, full_block, skip_block, kc, vc),
-            k_cur, v_cur,
-        )
+    # What a visiting block from chip ``src != my`` adds, as the
+    # partial (o, lse) over all local rows, and its backward pass from
+    # that partial. A past block (``src < my``) offers its early half's
+    # keys in zigzag and all of them in contiguous blocks.
+    past_keys = half if zigzag else sq
 
-    o, lse = one_rotation(k_blk, v_blk, my)  # local block first
+    def past_fwd(q, k, v):
+        return attend(q, k[:past_keys], v[:past_keys], False)
+
+    def past_bwd(q, k, v, o, lse, do, dlse):
+        dq, dk, dv = backward(q, k[:past_keys], v[:past_keys], o, lse,
+                              do, dlse)
+        return (dq, rows_padded(dk, after=sq - past_keys),
+                rows_padded(dv, after=sq - past_keys))
+
+    def future_fwd(q, k, v):
+        if not zigzag:          # no row sees any key: lse = -1e30
+            return (jnp.zeros(q.shape, jnp.float32),    # zeroes it in
+                    jnp.full((h, sq), -1e30, jnp.float32))  # the merge
+        o, lse = attend(q[half:], k, v, False)
+        return (rows_padded(o, before=half),
+                jnp.pad(lse, [(0, 0), (half, 0)], constant_values=-1e30))
+
+    def future_bwd(q, k, v, o, lse, do, dlse):
+        if not zigzag:
+            return jnp.zeros_like(q), jnp.zeros_like(k), jnp.zeros_like(v)
+        dq, dk, dv = backward(q[half:], k, v, o[half:], lse[:, half:],
+                              do[half:], dlse[:, half:])
+        return rows_padded(dq, before=half), dk, dv
+
+    # A VJP of the rotation's own: differentiated through ``lax.cond``
+    # each branch would keep its kernel's q, out and lse as residuals
+    # of its own beside the partial the merge keeps (1.5 more arrays of
+    # the rows' size a rotation and layer); here the partial is the one
+    # residual, and each branch's backward pass reads its part of it.
+    @jax.custom_vjp
+    def visiting(q, k_cur, v_cur, past):
+        return jax.lax.cond(past, past_fwd, future_fwd, q, k_cur, v_cur)
+
+    def visiting_fwd(q, k_cur, v_cur, past):
+        o, lse = visiting(q, k_cur, v_cur, past)
+        return (o, lse), (q, k_cur, v_cur, past, o, lse)
+
+    def visiting_bwd(res, cotangents):
+        *res, past, o, lse = res
+        return (*jax.lax.cond(past, past_bwd, future_bwd, *res, o, lse,
+                              *cotangents), None)
+
+    visiting.defvjp(visiting_fwd, visiting_bwd)
+
+    with jax.named_scope("ring.block"):     # local block first
+        o, lse = attend(q_blk, k_blk, v_blk, causal)
 
     def body(carry, _):
         k_cur, v_cur, src, o, lse = carry
         k_cur, v_cur = _kv_rotate(k_cur, v_cur, axis=axis, n_dev=n_dev,
                                   use_dma_ring=use_dma_ring,
                                   interpret=interpret)
-        src = (src - 1) % n_dev
-        o2, lse2 = one_rotation(k_cur, v_cur, src)
+        src = (src - 1) % n_dev         # never ``my`` in here
+        with jax.named_scope("ring.block"):
+            if causal:
+                o2, lse2 = visiting(q_blk, k_cur, v_cur, src < my)
+            else:
+                o2, lse2 = attend(q_blk, k_cur, v_cur, False)
         with jax.named_scope("ring.merge"):
             o, lse = _merge_partials(o, lse, o2, lse2)
         return (k_cur, v_cur, src, o, lse), None
@@ -283,50 +419,19 @@ def _ring_flash_local(q_blk, k_blk, v_blk, *, axis: str, n_dev: int,
     return o.astype(q_blk.dtype)
 
 
-def ring_attention_local(q_blk, k_blk, v_blk, *, axis: str,
-                         n_devices: int | None = None,
-                         causal: bool = False,
-                         local: str = "xla",
-                         interpret: bool = False,
-                         use_dma_ring: bool = False):
-    """The raw per-device ring-attention body, for COMPOSITION inside a
-    caller's own ``shard_map``.
-
-    ``local`` picks the per-device block engine: ``"xla"`` (chunked
-    online-softmax in plain jnp — differentiable everywhere) or
-    ``"flash"`` (the Pallas flash kernels — the flagship long-context
-    configuration: scores stream through VMEM on every rotation;
-    ``interpret=True`` runs them in the Pallas interpreter for
-    CPU-mesh tests).
-
-    ``q_blk/k_blk/v_blk`` are this device's (seq/n_devices, heads,
-    head_dim) shards along a mesh axis named ``axis``; the KV blocks
-    rotate around that axis with ``ppermute`` + online softmax. Because
-    collectives bind by AXIS NAME, this composes freely with other mesh
-    axes — e.g. 2-D data x sequence parallelism: an outer shard_map
-    over ("data", "seq") vmaps this body (axis="seq") over the local
-    batch shard, and every sequence still spans the full seq axis. It
-    also composes with ``vmap`` and jax AD (gradient parity with full
-    attention is pinned in tests). ``n_devices`` defaults to the bound
-    axis's true size (``jax.lax.axis_size``) — pass it only to
-    override, and beware a mismatch silently drops KV blocks.
-
-    ``use_dma_ring=True`` rotates KV via the Pallas async remote-DMA
-    exchange (ops/dma_ring) instead of ``ppermute`` — both blocks'
-    transfers overlap each other and the per-rotation compute.
-    Forward-only (the DMA primitive has no VJP); numerics are pinned
-    against the ppermute path in tests.
-    """
+def _ring_local(q_blk, k_blk, v_blk, *, axis: str, n_dev: int,
+                causal: bool, local: str, interpret: bool,
+                use_dma_ring: bool, zigzag: bool):
+    """The per-device ring body on blocks of rows that lie contiguous
+    or, with ``zigzag``, in :func:`ring_order`."""
     import jax
     import jax.numpy as jnp
 
-    n_dev = (jax.lax.axis_size(axis) if n_devices is None
-             else n_devices)
     if local == "flash":
         return _ring_flash_local(q_blk, k_blk, v_blk, axis=axis,
                                  n_dev=n_dev, causal=causal,
                                  interpret=interpret,
-                                 use_dma_ring=use_dma_ring)
+                                 use_dma_ring=use_dma_ring, zigzag=zigzag)
     # "blockwise" is ulysses_attention's name for the same chunked
     # online-softmax engine — accepted here so the two sequence-parallel
     # planes share an engine vocabulary.
@@ -334,7 +439,8 @@ def ring_attention_local(q_blk, k_blk, v_blk, *, axis: str,
         raise ValueError(f"unknown local attention engine {local!r}")
     sq = q_blk.shape[0]
     my = jax.lax.axis_index(axis)
-    q_pos = my * sq + jnp.arange(sq)            # global query positions
+    # global positions, of the query rows and of a visiting block's
+    q_pos = _local_positions(my, sq, n_dev, zigzag)
 
     # Per rotation, the KV block is accumulated via the shared
     # intra-block-chunked recurrence (_accumulate_block): one device's
@@ -346,9 +452,10 @@ def ring_attention_local(q_blk, k_blk, v_blk, *, axis: str,
     # the ring itself uses.
     @jax.named_scope("ring.block")
     def accumulate(k_cur, v_cur, src_dev, m, l, o):
-        return _accumulate_block(q_blk, q_pos, k_cur, v_cur,
-                                 src_dev * k_cur.shape[0], m, l, o,
-                                 causal)
+        return _accumulate_block(
+            q_blk, q_pos, k_cur, v_cur,
+            _local_positions(src_dev, k_cur.shape[0], n_dev, zigzag),
+            m, l, o, causal)
 
     m0, l0, o0 = _acc_init(q_blk)
 
@@ -373,29 +480,106 @@ def ring_attention_local(q_blk, k_blk, v_blk, *, axis: str,
     return _acc_finalize(o, l, q_blk.dtype)
 
 
+def ring_attention_local(q_blk, k_blk, v_blk, *, axis: str,
+                         n_devices: int | None = None,
+                         causal: bool = False,
+                         local: str = "xla",
+                         interpret: bool = False,
+                         use_dma_ring: bool = False):
+    """The raw per-device ring-attention body, for COMPOSITION inside a
+    caller's own ``shard_map``.
+
+    ``local`` picks the per-device block engine: ``"xla"`` (chunked
+    online-softmax in plain jnp — differentiable everywhere) or
+    ``"flash"`` (the Pallas flash kernels — the flagship long-context
+    configuration: scores stream through VMEM on every rotation;
+    ``interpret=True`` runs them in the Pallas interpreter for
+    CPU-mesh tests).
+
+    ``q_blk/k_blk/v_blk`` are this device's (seq/n_devices, heads,
+    head_dim) shards along a mesh axis named ``axis``, contiguous
+    blocks of the sequence in their natural order (the balanced order
+    of a causal ring, :func:`ring_order`, is :func:`ring_attention`'s
+    and the trainer's); the KV blocks rotate around that axis with
+    ``ppermute`` + online softmax. Because
+    collectives bind by AXIS NAME, this composes freely with other mesh
+    axes — e.g. 2-D data x sequence parallelism: an outer shard_map
+    over ("data", "seq") vmaps this body (axis="seq") over the local
+    batch shard, and every sequence still spans the full seq axis. It
+    also composes with ``vmap`` and jax AD (gradient parity with full
+    attention is pinned in tests). ``n_devices`` defaults to the bound
+    axis's true size (``jax.lax.axis_size``) — pass it only to
+    override, and beware a mismatch silently drops KV blocks.
+
+    ``use_dma_ring=True`` rotates KV via the Pallas async remote-DMA
+    exchange (ops/dma_ring) instead of ``ppermute`` — both blocks'
+    transfers overlap each other and the per-rotation compute.
+    Forward-only (the DMA primitive has no VJP); numerics are pinned
+    against the ppermute path in tests.
+    """
+    import jax
+
+    n_dev = (jax.lax.axis_size(axis) if n_devices is None
+             else n_devices)
+    return _ring_local(q_blk, k_blk, v_blk, axis=axis, n_dev=n_dev,
+                       causal=causal, local=local, interpret=interpret,
+                       use_dma_ring=use_dma_ring, zigzag=False)
+
+
 def _build_ring_attention(mesh, axis: str, causal: bool,
                           local: str = "xla", interpret: bool = False,
-                          use_dma_ring: bool = False):
+                          use_dma_ring: bool = False,
+                          ordered: bool = False):
     import functools
 
     import jax
+    import numpy as np
+    from fiber_tpu.telemetry import device as device_telemetry
     from fiber_tpu.utils.jaxcompat import shard_map
-    from jax.sharding import PartitionSpec as P
+    from jax.sharding import NamedSharding, PartitionSpec as P
 
-    body = functools.partial(
-        ring_attention_local, axis=axis, n_devices=mesh.shape[axis],
-        causal=causal, local=local, interpret=interpret,
-        use_dma_ring=use_dma_ring,
-    )
-
+    n_dev = mesh.shape[axis]
     spec = P(axis)
-    return jax.jit(shard_map(
-        body,
-        mesh=mesh,
-        in_specs=(spec, spec, spec),
-        out_specs=spec,
-        check_vma=False,
-    ))
+
+    def run(q, k, v):
+        # Traced once per length: the order follows from it.
+        order = ring_order(q.shape[0], n_dev, causal)
+        device_telemetry.ring_built(
+            *ring_schedule(q.shape[0], n_dev, causal))
+        ring = shard_map(
+            functools.partial(
+                _ring_local, axis=axis, n_dev=n_dev, causal=causal,
+                local=local, interpret=interpret,
+                use_dma_ring=use_dma_ring, zigzag=order is not None),
+            mesh=mesh,
+            in_specs=(spec, spec, spec),
+            out_specs=spec,
+            check_vma=False,
+        )
+        if ordered or order is None:
+            return ring(q, k, v)
+        # back by position, and sharded as the ring's own result is
+        return jax.lax.with_sharding_constraint(
+            ring(q[order], k[order], v[order])[np.argsort(order)],
+            NamedSharding(mesh, spec))
+
+    return jax.jit(run)
+
+
+def _ring_program(mesh, axis, causal, local, interpret, use_dma_ring,
+                  ordered):
+    from fiber_tpu.parallel.mesh import default_mesh
+
+    mesh = mesh or default_mesh()
+    # Mesh hashes by value (devices + axis names): no id-aliasing after GC,
+    # and equal meshes share the compiled program.
+    key = (mesh, axis, causal, local, interpret, use_dma_ring, ordered)
+    fn = _compiled_cache.get(key)
+    if fn is None:
+        fn = _build_ring_attention(mesh, axis, causal, local, interpret,
+                                   use_dma_ring, ordered)
+        _compiled_cache[key] = fn
+    return fn
 
 
 def ring_attention(
@@ -412,7 +596,10 @@ def ring_attention(
     """Exact attention with sequence sharded over the mesh.
 
     q, k, v: (seq, heads, head_dim) — ``seq`` must divide evenly over the
-    axis. Returns (seq, heads, head_dim) with the same sharding.
+    axis. Returns (seq, heads, head_dim) with the same sharding, rows
+    in their natural order in and out: where the ring holds another
+    (:func:`ring_order`) the rows are put in it and back inside the
+    program.
     ``local="flash"`` runs the Pallas flash kernels as the per-device
     block (``interpret=True`` for CPU-mesh testing).
     ``use_dma_ring=True`` rotates KV with the Pallas async remote-DMA
@@ -421,18 +608,20 @@ def ring_attention(
     (mesh, axis, causal, local, interpret, use_dma_ring); shapes re-use
     jit's own cache.
     """
-    from fiber_tpu.parallel.mesh import default_mesh
+    return _ring_program(mesh, axis, causal, local, interpret,
+                         use_dma_ring, False)(q, k, v)
 
-    mesh = mesh or default_mesh()
-    # Mesh hashes by value (devices + axis names): no id-aliasing after GC,
-    # and equal meshes share the compiled program.
-    key = (mesh, axis, causal, local, interpret, use_dma_ring)
-    fn = _compiled_cache.get(key)
-    if fn is None:
-        fn = _build_ring_attention(mesh, axis, causal, local, interpret,
-                                   use_dma_ring)
-        _compiled_cache[key] = fn
-    return fn(q, k, v)
+
+def ring_attention_ordered(q, k, v, mesh=None, axis: str = "pool",
+                           causal: bool = False, local: str = "xla",
+                           interpret: bool = False):
+    """:func:`ring_attention` on rows that already lie in
+    ``ring_order(seq, chips, causal)``, returned in that order: no row
+    moves between chips. For a caller that can order its sequence at
+    no cost and acts on single rows elsewhere (the LM trainer orders
+    the token ids)."""
+    return _ring_program(mesh, axis, causal, local, interpret, False,
+                         True)(q, k, v)
 
 
 def reference_attention(q, k, v, causal: bool = False):

@@ -103,6 +103,12 @@ _m_flash_grid_steps = telemetry.counter(
     "Inner grid steps a head makes in the flash-attention programs "
     "built (for dkv a kv-head), by kernel and state: 'run' computes, "
     "'idle' only exists")
+_m_ring_rotations = telemetry.counter(
+    "ring_rotations_traced",
+    "Rotations of the ring-attention programs built, summed over the "
+    "ring's chips, by the order the rows lie in ('zigzag' or "
+    "'contiguous') and state: 'run' attends, 'skip' is a rotation the "
+    "causal mask leaves a chip nothing of")
 _g_moe_load_max = telemetry.gauge(
     "moe_expert_load_max",
     "Tokens of the last probed batch on the most loaded held expert, "
@@ -639,6 +645,21 @@ def flash_grid_built(kernel: str, run: int, idle: int) -> None:
     to a wide band."""
     _m_flash_grid_steps.inc(run, kernel=kernel, state="run")
     _m_flash_grid_steps.inc(idle, kernel=kernel, state="idle")
+
+
+def ring_built(layout: str, pairs) -> None:
+    """One ring-attention program was built (``ops/ring_attention.py``)
+    under the static schedule ``ring_schedule`` gives: ``pairs[chip]
+    [rotation]`` (query, key) pairs, 0 where the mask leaves the chip
+    nothing. Moves ``run`` and ``skip`` by the rotations of all the
+    ring's chips together, so a balanced ring of n chips reads n x n
+    and 0. Counts programs built, not calls: an operator who reads
+    ``contiguous`` with ``skip`` above 0 on a causal run has a length
+    that is not whole in 2n half-blocks, and chips that wait."""
+    run = sum(1 for chip in pairs for n in chip if n)
+    skip = sum(len(chip) for chip in pairs) - run
+    _m_ring_rotations.inc(run, layout=layout, state="run")
+    _m_ring_rotations.inc(skip, layout=layout, state="skip")
 
 
 def moe_load(loads) -> None:

@@ -149,6 +149,223 @@ def test_ring_flash_gradients_match_reference():
         assert rel < 1e-4, rel
 
 
+def _pool_mesh(n):
+    from jax.sharding import Mesh
+
+    return Mesh(np.asarray(jax.devices()[:n]), ("pool",))
+
+
+@pytest.mark.parametrize("n_dev", [4, 2])
+@pytest.mark.parametrize("local", ["flash", "xla"])
+def test_zigzag_ring_matches_reference(local, n_dev):
+    """A causal ring over n chips holds the rows in zigzag (chip i:
+    half-blocks i and 2n-1-i), so every chip attends two half-block
+    pairs in every rotation; ring_attention still takes and returns the
+    natural order. Output and the gradients of q, k, v against the
+    full-matrix reference, for both engines; a length that is not whole
+    in 2n half-blocks keeps contiguous blocks and still matches."""
+    from fiber_tpu.ops.ring_attention import ring_attention, ring_order
+
+    mesh = _pool_mesh(n_dev)
+    for seq, zigzag in ((256, True), (33 * n_dev, False)):
+        assert (ring_order(seq, n_dev, True) is not None) == zigzag
+        q, k, v = _rand_qkv(seq, 4, 16)
+
+        def loss_ring(q, k, v):
+            o = ring_attention(q, k, v, mesh=mesh, causal=True,
+                               local=local, interpret=True)
+            return jnp.sum(o ** 2), o
+
+        def loss_ref(q, k, v):
+            o = reference_attention(q, k, v, causal=True)
+            return jnp.sum(o ** 2), o
+
+        (_, got), gf = jax.value_and_grad(
+            loss_ring, argnums=(0, 1, 2), has_aux=True)(q, k, v)
+        (_, want), gr = jax.value_and_grad(
+            loss_ref, argnums=(0, 1, 2), has_aux=True)(q, k, v)
+        assert np.abs(np.asarray(got) - np.asarray(want)).max() < 2e-5
+        # by position again, and sharded over the ring as before
+        assert {s.data.shape for s in got.addressable_shards} == {
+            (seq // n_dev, 4, 16)}
+        for a, b in zip(gf, gr):
+            a, b = np.asarray(a), np.asarray(b)
+            rel = np.abs(a - b).max() / (np.abs(b).max() + 1e-9)
+            assert rel < 1e-4, (seq, rel)
+
+
+@pytest.mark.parametrize("n_dev", [2, 4, 8])
+def test_ring_order_and_its_inverse_round_trip(n_dev):
+    """ring_order is a permutation of the positions whose argsort puts
+    rows back; chip i's block is half-blocks i and 2n-1-i, early half
+    first; the non-causal ring, one chip and a length that does not
+    halve keep the natural order (None)."""
+    from fiber_tpu.ops.ring_attention import ring_order
+
+    seq = 16 * n_dev
+    order = ring_order(seq, n_dev, True)
+    assert sorted(order) == list(range(seq))
+    x = np.arange(seq) * 3 + 1
+    assert np.array_equal(x[order][np.argsort(order)], x)
+    half = seq // (2 * n_dev)
+    for chip, rows in enumerate(order.reshape(n_dev, 2, half)):
+        assert list(rows[0]) == list(range(chip * half, (chip + 1) * half))
+        late = 2 * n_dev - 1 - chip
+        assert list(rows[1]) == list(range(late * half, (late + 1) * half))
+    assert ring_order(seq, n_dev, False) is None
+    assert ring_order(seq, 1, True) is None
+    assert ring_order(seq + n_dev, n_dev, True) is None
+
+
+@pytest.mark.parametrize("n_dev", [4, 8])
+def test_ring_schedule_balances_the_causal_ring(n_dev):
+    """The static schedule the ring_rotations_traced counter reads, no
+    device needed: in zigzag every chip runs every rotation and all
+    chips attend the same number of (query, key) pairs in a rotation;
+    the busiest chip's total is S^2/2n + S/2n where contiguous blocks
+    give S^2 (2n-1)/2n^2 + S/2n: 2.0 block-units on the critical path
+    of four chips for 3.5."""
+    from fiber_tpu.ops.ring_attention import ring_schedule
+
+    seq = 4096 * n_dev
+    layout, pairs = ring_schedule(seq, n_dev, True)
+    assert layout == "zigzag"
+    assert all(n > 0 for chip in pairs for n in chip)
+    for rotation in zip(*pairs):
+        assert len(set(rotation)) == 1
+    assert {sum(chip) for chip in pairs} == {
+        seq * seq // (2 * n_dev) + seq // (2 * n_dev)}
+
+    # one row short of whole half-blocks: today's contiguous blocks
+    odd = seq + n_dev
+    layout, pairs = ring_schedule(odd, n_dev, True)
+    assert layout == "contiguous"
+    assert [sum(1 for n in chip if n) for chip in pairs] == list(
+        range(1, n_dev + 1))
+    # S^2 (2n-1)/2n^2 + S/2n, doubled to stay in whole numbers
+    assert 2 * n_dev * n_dev * max(sum(chip) for chip in pairs) == (
+        odd * odd * (2 * n_dev - 1) + odd * n_dev)
+    block = (seq // n_dev) ** 2
+    zigzag_units = max(sum(c) for c in ring_schedule(seq, n_dev, True)[1])
+    assert round(zigzag_units / block, 2) == n_dev / 2
+    assert round(max(sum(chip) for chip in pairs) / block, 1) == n_dev - 0.5
+
+    layout, pairs = ring_schedule(seq, n_dev, False)
+    assert layout == "contiguous"
+    assert {n for chip in pairs for n in chip} == {block}
+
+
+def test_ring_rotations_traced_counter():
+    """Building a ring program moves ring_rotations_traced{layout,
+    state} by the rotations of all its chips: a causal ring of four
+    chips reads zigzag 16 run and 0 skip; a length that does not halve
+    reads contiguous 10 and 6, which is what an operator looks for."""
+    import fiber_tpu
+    from fiber_tpu import telemetry
+    from fiber_tpu.ops.ring_attention import ring_attention
+
+    fiber_tpu.init()
+    counter = telemetry.counter("ring_rotations_traced")
+
+    def read():
+        return {(lay, st): counter.value(layout=lay, state=st)
+                for lay in ("zigzag", "contiguous")
+                for st in ("run", "skip")}
+
+    def built(seq, causal):
+        before = read()
+        q, k, v = _rand_qkv(seq, 2, 8)
+        ring_attention(q, k, v, mesh=_pool_mesh(4), causal=causal)
+        after = read()
+        return {key: int(after[key] - before[key])
+                for key in after if after[key] != before[key]}
+
+    assert built(72, True) == {("zigzag", "run"): 16}
+    assert built(68, True) == {("contiguous", "run"): 10,
+                               ("contiguous", "skip"): 6}
+    assert built(76, False) == {("contiguous", "run"): 16}
+
+
+def test_flash_rectangular_matches_reference():
+    """Fewer or more keys than queries (non-causal): what the zigzag
+    ring asks of the kernels, all rows on half a visiting block and half
+    the rows on a whole one. Values, lse and gradients; a causal call
+    needs as many keys as queries and says so."""
+    from fiber_tpu.ops.pallas_attention import flash_attention_lse
+
+    q, k, v = _rand_qkv(256, 4, 16)
+    for rows, keys in ((256, 128), (128, 256)):
+        qs, ks, vs = q[:rows], k[:keys, :2], v[:keys, :2]
+
+        def ref(q, k, v):
+            kr, vr = jnp.repeat(k, 2, axis=1), jnp.repeat(v, 2, axis=1)
+            s = jnp.einsum("qhd,khd->hqk", q, kr) / 4.0
+            return (jnp.einsum("hqk,khd->qhd", jax.nn.softmax(s, -1), vr),
+                    jax.nn.logsumexp(s, axis=-1))
+
+        def loss(fn):
+            def f(q, k, v):
+                o, lse = fn(q, k, v)
+                return jnp.sum(o ** 2) + jnp.sum(jnp.sin(lse))
+            return jax.grad(f, argnums=(0, 1, 2))
+
+        def flash(q, k, v):
+            return flash_attention_lse(q, k, v, block_q=128, block_kv=128,
+                                       interpret=True)
+
+        for got, want in zip(flash(qs, ks, vs), ref(qs, ks, vs)):
+            assert np.abs(np.asarray(got) - np.asarray(want)).max() < 2e-5
+        for a, b in zip(loss(flash)(qs, ks, vs), loss(ref)(qs, ks, vs)):
+            a, b = np.asarray(a), np.asarray(b)
+            assert np.abs(a - b).max() / np.abs(b).max() < 1e-4
+    with pytest.raises(ValueError, match="as many"):
+        flash_attention_lse(q, k[:128], v[:128], causal=True,
+                            interpret=True)
+
+
+@pytest.mark.parametrize("pos", ["rope", "learned"])
+@pytest.mark.parametrize("attention", ["flash", "ring"])
+def test_tiny_lm_on_the_ring_matches_one_device(attention, pos):
+    """On a 4-device mesh the trainer takes the token ids in the ring's
+    zigzag order and gives ropes / the position table the rows' true
+    positions: loss and gradients equal the one-device model's; apply
+    returns logits and token_losses losses BY POSITION (one altered
+    token moves the rows from its position on and none before it)."""
+    from fiber_tpu.models import TinyLM
+
+    kwargs = dict(vocab=64, dim=32, heads=4, kv_heads=2, layers=2,
+                  max_seq=128, pos=pos)
+    lm = TinyLM(attention=attention, mesh=_pool_mesh(4), interpret=True,
+                **kwargs)
+    lm_ref = TinyLM(attention="reference", **kwargs)
+    order = lm._ring_order()
+    assert order is not None and list(order[:32]) == (
+        list(range(16)) + list(range(112, 128)))
+    assert lm_ref._ring_order() is None
+    params = lm_ref.init(jax.random.PRNGKey(0))
+    tokens = jax.random.randint(jax.random.PRNGKey(1), (128,), 0, 64)
+
+    lf, gf = jax.value_and_grad(lm.loss)(params, tokens)
+    lr, gr = jax.value_and_grad(lm_ref.loss)(params, tokens)
+    assert abs(float(lf) - float(lr)) < 1e-4
+    for a, b in zip(jax.tree_util.tree_leaves(gf),
+                    jax.tree_util.tree_leaves(gr)):
+        assert np.abs(np.asarray(a) - np.asarray(b)).max() < 5e-4
+
+    logits = np.asarray(lm.apply(params, tokens))
+    assert np.abs(logits - np.asarray(lm_ref.apply(params, tokens))
+                  ).max() < 1e-4
+    losses = np.asarray(lm.token_losses(params, tokens))
+    assert np.abs(losses - np.asarray(
+        lm_ref.token_losses(params, tokens))).max() < 1e-4
+    altered = tokens.at[70].set((tokens[70] + 1) % 64)
+    moved = np.abs(np.asarray(lm.apply(params, altered)) - logits).max(1)
+    assert moved[:70].max() == 0.0 and (moved[70:] > 0.0).all()
+    moved = np.abs(np.asarray(lm.token_losses(params, altered)) - losses)
+    # position 69's loss is its target's: token 70 itself
+    assert moved[:69].max() == 0.0 and (moved[69:] > 0.0).all()
+
+
 def test_flash_attention_lse_values():
     """flash_attention_lse's second output IS the softmax logsumexp
     (scaled scores), the mergeable residual."""

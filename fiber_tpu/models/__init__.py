@@ -9,6 +9,7 @@ from fiber_tpu.models.policies import (  # noqa: F401
 from fiber_tpu.models.transformer import (  # noqa: F401
     Block,
     BlockLM,
+    ExitGate,
     Experts,
     Rope,
     StateSpace,

@@ -20,8 +20,11 @@ A model is a description of its layers (:class:`Block`): each layer has
 a mixer (attention with its own query-head count, window and rope, or a
 state-space mixer, :mod:`fiber_tpu.ops.ssm`), a feed-forward (ungated
 MLP, gated MLP, or sparse experts of which this program holds a share,
-:mod:`fiber_tpu.ops.moe`), or one of the two alone. :class:`BlockLM`
-runs any such description; :class:`TinyLM` is the uniform one.
+:mod:`fiber_tpu.ops.moe`), or one of the two alone, each part behind a
+norm and, with ``post_norm``, before a second one. :class:`BlockLM`
+runs any such description, once or ``passes`` times over the same
+weights (with an :class:`ExitGate`, under the expected-exit loss);
+:class:`TinyLM` is the uniform one.
 """
 
 from __future__ import annotations
@@ -161,7 +164,9 @@ class Block:
     with no position scheme at all); ``"ssm"``: the state-space mixer
     ``ssm=``; None: no mixer. The feed-forward: ``ffn="mlp"`` (ungated
     tanh-GELU of ``width`` with biases), ``"gated"`` (SwiGLU of
-    ``width``, no biases), ``"experts"`` (``experts=``) or None."""
+    ``width``, no biases), ``"experts"`` (``experts=``) or None.
+    ``post_norm``: a second RMSNorm, with a gain of its own, behind each
+    part (a sandwich): ``x + norm(part(norm(x)))``."""
 
     heads: int = 0
     window: Optional[int] = None
@@ -171,6 +176,7 @@ class Block:
     experts: Optional[Experts] = None
     mixer: Optional[str] = "attention"
     ssm: Optional[StateSpace] = None
+    post_norm: bool = False
 
     @property
     def attn_kind(self) -> str:
@@ -180,6 +186,19 @@ class Block:
     def kind(self) -> str:
         mixer = {"attention": self.attn_kind, "ssm": "ssm"}.get(self.mixer)
         return "/".join(part for part in (mixer, self.ffn) if part)
+
+
+@dataclasses.dataclass(frozen=True)
+class ExitGate:
+    """The exit gate of a model whose layers run ``passes`` times
+    (arXiv:2510.25741): a linear map with bias from each pass's normed
+    rows to one number, ``lambda_t = sigmoid(x_t w + b)``; a position
+    exits after pass ``t`` with probability ``p_t = lambda_t prod_(j<t)
+    (1 - lambda_j)``, after the last with what is left. ``loss`` is then
+    the expected-exit loss, per position ``sum_t p_t CE_t - beta H(p)``
+    (``H`` the entropy of ``p``: a uniform prior over the exits)."""
+
+    beta: float = 0.05
 
 
 class BlockLM:
@@ -195,6 +214,26 @@ class BlockLM:
     the state-space layers carry position). A state-space layer runs on
     one device and over whole chunks of positions.
 
+    ``passes``: how often the stack of layers runs over the same weights
+    (1 = once, and then nothing else changes). Pass ``t`` starts from
+    pass ``t - 1``'s rows after the final norm, which closes every pass;
+    the passes are one ``lax.scan`` whose body holds the layers once, so
+    a shared weight's gradient is the sum over the passes'. ``apply``
+    and ``generate`` give the last pass's logits (decode keeps a cache
+    per pass and layer: a pass attends its own keys and values). With an
+    ``exit_gate`` (:class:`ExitGate`; needs ``passes > 1``) ``loss`` is
+    the expected-exit loss over every pass's logits, else the last
+    pass's cross-entropy; ``pass_losses`` gives the parts. Attention
+    layers with dense feed-forwards only, on one device.
+
+    What a step recomputes (memory, never what is computed):
+    ``recompute="layer"`` puts each application of a layer under
+    ``jax.checkpoint``, so that the backward pass keeps a layer's input
+    and not its intermediates; ``head_block`` computes head and
+    cross-entropy over blocks of that many rows (all passes' rows
+    alike), each block recomputed in the backward pass, so that no
+    (rows, vocab) array outlives its block (None: the logits whole).
+
     ``apply`` / ``loss`` / ``generate`` as :class:`TinyLM`;
     ``routing(params, tokens)`` gives, for the expert layers, the taken
     expert ids of every token and the load of each held expert."""
@@ -202,8 +241,27 @@ class BlockLM:
     def __init__(self, blocks: Sequence[Block], *, vocab: int, dim: int,
                  head_dim: int, kv_heads: int, max_seq: int,
                  attention: str = "flash", pos: str = "rope", mesh=None,
-                 interpret: bool = False, norm_eps: float = 1e-6) -> None:
+                 interpret: bool = False, norm_eps: float = 1e-6,
+                 passes: int = 1, exit_gate: Optional[ExitGate] = None,
+                 recompute: Optional[str] = None,
+                 head_block: Optional[int] = None) -> None:
         blocks = tuple(blocks)
+        if passes < 1:
+            raise ValueError(f"passes must be >= 1, got {passes}")
+        if exit_gate is not None and passes == 1:
+            raise ValueError(
+                "an exit gate chooses among passes: with passes=1 there "
+                "is one exit; drop the gate or give the model passes")
+        if recompute not in (None, "layer"):
+            raise ValueError(f"unknown recomputation {recompute!r}")
+        if head_block is not None and head_block < 1:
+            raise ValueError(f"head_block must be >= 1, got {head_block}")
+        if passes > 1 and any(b.ffn == "experts" or b.mixer == "ssm"
+                              for b in blocks):
+            raise ValueError(
+                "passes > 1 runs attention layers with dense "
+                "feed-forwards: an expert layer's routing and a "
+                "state-space layer's carried state have no pass")
         if attention not in ("ring", "ulysses", "flash", "reference"):
             raise ValueError(f"unknown attention {attention!r}")
         if pos not in ("learned", "rope", "none"):
@@ -256,6 +314,11 @@ class BlockLM:
                     "a state-space layer runs on one device: the "
                     "sequence-parallel plane hands keys and values on, "
                     "not state; drop the mesh")
+            if multi and (passes > 1 or head_block is not None):
+                raise ValueError(
+                    "passes > 1 and head_block run on one device: the "
+                    "sequence-parallel plane orders the rows and "
+                    "carries no pass; drop the mesh")
             if multi and "pool" not in mesh.shape:
                 # Loud, at construction: the sequence-parallel planes
                 # shard over the mesh's "pool" axis — without this
@@ -309,6 +372,13 @@ class BlockLM:
         self.norm_eps = norm_eps
         #: flash plane only: run the kernels in the Pallas interpreter
         self.interpret = interpret
+        #: how often the stack runs over the same weights
+        self.passes = passes
+        self.exit_gate = exit_gate
+        #: None, or "layer": every layer application under jax.checkpoint
+        self.recompute = recompute
+        #: rows of a block of the head and its cross-entropy (None: whole)
+        self.head_block = head_block
         self._mesh = mesh
         self._probe = None
 
@@ -337,11 +407,28 @@ class BlockLM:
                     "window is a kernel feature)")
 
     @property
+    def _loss_by_pass(self) -> bool:
+        """Whether ``loss`` is formed from ``pass_losses`` (several
+        passes, or a blocked head); else it lowers as it always did."""
+        return self.passes > 1 or self.head_block is not None
+
+    @property
+    def _recompute_label(self) -> str:
+        return (self.recompute or "none") + (
+            "+head" if self.head_block is not None else "")
+
+    @property
     def span_fields(self) -> dict:
         """What the ``lm.train_step`` span says of the model: the layer
-        kinds in order and, with expert layers, how many experts are
-        held here, exist in all and are taken a token."""
+        kinds in order; with expert layers, how many experts are held
+        here, exist in all and are taken a token; where the stack runs
+        more than once or a step recomputes, ``passes`` and
+        ``recompute`` (``none``, ``layer``, with ``+head`` for a
+        blocked head)."""
         fields = {"layers": ",".join(b.kind for b in self.blocks)}
+        if self.passes > 1 or self._recompute_label != "none":
+            fields.update(passes=self.passes,
+                          recompute=self._recompute_label)
         experts = [b.experts for b in self.blocks if b.experts is not None]
         if experts:
             from fiber_tpu.ops.moe import held_experts
@@ -365,7 +452,10 @@ class BlockLM:
         log-uniformly in [dt_min, dt_max], floored at dt_floor),
         out_proj; ``A_log = log(1..heads)``, ``D = 1``. A part the layer
         does not have draws nothing and has no leaf (``norm1`` is the
-        mixer's gain, ``norm2`` the feed-forward's)."""
+        mixer's gain, ``norm2`` the feed-forward's; with ``post_norm``
+        ``post_norm1`` and ``post_norm2`` are the gains behind them,
+        and a gain draws nothing). An exit gate's ``gate_w`` is drawn
+        from the rest the last layer leaves; ``gate_b`` is 0."""
         import jax
         import jax.numpy as jnp
 
@@ -405,6 +495,10 @@ class BlockLM:
                            **self._init_ssm(spec.ssm, keys[0], normal))
             if spec.ffn is not None:
                 blk["norm2"] = jnp.ones((d,))
+            if spec.post_norm:
+                for part, n in ((spec.mixer, "1"), (spec.ffn, "2")):
+                    if part is not None:
+                        blk["post_norm" + n] = jnp.ones((d,))
             if spec.ffn == "mlp":
                 h = spec.width
                 blk.update(w1=normal(keys[2], d, h), b1=jnp.zeros((h,)),
@@ -433,6 +527,9 @@ class BlockLM:
                         blk["experts_" + m] = matrix(sub[4 + i], m,
                                                      e.width, held)
             params["blocks"].append(blk)
+        if self.exit_gate is not None:
+            params["gate_w"] = normal(key, self.dim)
+            params["gate_b"] = jnp.zeros(())
         return params
 
     def _init_ssm(self, ssm, key, normal) -> dict:
@@ -659,25 +756,33 @@ class BlockLM:
         (shared like _project_qkv)."""
         import jax
 
+        def behind(y, gain):
+            """The norm behind a part, where the layer has one."""
+            return self._rms(y, blk[gain]) if spec.post_norm else y
+
         if spec.mixer == "attention":
             with jax.named_scope("lm.attn"), \
                     jax.named_scope(spec.attn_kind), jax.named_scope("out"):
-                x = x + mixed @ blk["wo"]
+                x = x + behind(mixed @ blk["wo"], "post_norm1")
         elif spec.mixer == "ssm":
-            x = x + mixed
+            x = x + behind(mixed, "post_norm1")
         if spec.ffn is None:
             return x
         if spec.ffn == "mlp":
             with jax.named_scope("lm.mlp"):
                 h = self._rms(x, blk["norm2"])
-                return x + jax.nn.gelu(h @ blk["w1"] + blk["b1"]) \
-                    @ blk["w2"] + blk["b2"]
+                y = jax.nn.gelu(h @ blk["w1"] + blk["b1"]) @ blk["w2"]
+                if spec.post_norm:
+                    return x + behind(y + blk["b2"], "post_norm2")
+                return x + y + blk["b2"]
         from fiber_tpu.ops import moe
 
         if spec.ffn == "gated":
             with jax.named_scope("lm.mlp"):
                 h = self._rms(x, blk["norm2"])
-                return x + moe.swiglu(h, blk["wg"], blk["wu"], blk["wd"])
+                return x + behind(
+                    moe.swiglu(h, blk["wg"], blk["wu"], blk["wd"]),
+                    "post_norm2")
         with jax.named_scope("lm.moe"), jax.named_scope("norm"):
             h = self._rms(x, blk["norm2"])
         e = spec.experts
@@ -686,7 +791,7 @@ class BlockLM:
             rows, blk, total=e.total, top_k=e.top_k, scale=e.scale,
             first=moe.held_experts(e.total, e.share)[0],
             chunk_rows=e.chunk_rows, kind=e.kind, taps=taps)
-        return x + y.reshape(x.shape)
+        return x + behind(y.reshape(x.shape), "post_norm2")
 
     def apply(self, params, tokens):
         """tokens (max_seq,) int -> logits (max_seq, vocab).
@@ -704,15 +809,25 @@ class BlockLM:
         return logits if order is None else logits[np.argsort(order)]
 
     def _forward(self, params, tokens, taps=None, order=None):
-        """Logits of ``tokens``. With ``order`` (``_ring_order``) row
-        ``r`` of everything in here, the logits too, is position
-        ``order[r]``: the ids are taken in that order and ropes and the
-        position table get the rows' true positions."""
+        """Logits of ``tokens`` (the last pass's). With ``order``
+        (``_ring_order``) row ``r`` of everything in here, the logits
+        too, is position ``order[r]``: the ids are taken in that order
+        and ropes and the position table get the rows' true positions."""
+        import jax
+
+        x = self._normed_rows(params, tokens, taps, order)
+        with jax.named_scope("lm.head_loss"):
+            return (x if self.passes == 1 else x[-1]) @ params["out"]
+
+    def _normed_rows(self, params, tokens, taps=None, order=None):
+        """The rows the head reads: the stream after the last layer and
+        the final norm, (S, dim); with ``passes > 1`` every pass's,
+        (passes, S, dim): the passes are one ``lax.scan`` over the
+        layers' walk, each closed by the final norm."""
         import jax
         import jax.numpy as jnp
 
-        S, Dh = self.max_seq, self.head_dim
-        KVH = self.kv_heads
+        S = self.max_seq
         with jax.named_scope("lm.embed"):
             if order is not None:
                 tokens = tokens[order]
@@ -724,29 +839,64 @@ class BlockLM:
             ropes = {rope: (cos[:, None, :], sin[:, None, :])  # (S, 1, r/2)
                      for rope, (cos, sin)
                      in self._rope_tables(positions).items()}
-        for spec, blk in zip(self.blocks, params["blocks"]):
-            mixed = None
-            if spec.mixer == "ssm":
-                mixed = self._ssm_mix(spec.ssm, blk, x)
-            elif spec.mixer == "attention":
-                with jax.named_scope("lm.attn"), \
-                        jax.named_scope(spec.attn_kind):
-                    with jax.named_scope("qkv"):
-                        h = self._rms(x, blk["norm1"])
-                        q, k, v = self._project_qkv(blk, h)
-                        q = q.reshape(S, spec.heads, Dh)
-                        k = k.reshape(S, KVH, Dh)
-                        v = v.reshape(S, KVH, Dh)
-                        if spec.rope is not None:
-                            q = self._rope_rotate(q, *ropes[spec.rope])
-                            k = self._rope_rotate(k, *ropes[spec.rope])
-                    with jax.named_scope("kernel"):
-                        mixed = self._attend(
-                            q, k, v, spec.window).reshape(S, -1)
-            x = self._block_tail(spec, blk, x, mixed, taps)
-        with jax.named_scope("lm.head_loss"):
-            x = self._rms(x, params["final_norm"])
-            return x @ params["out"]
+        if self.passes > 1 or self._recompute_label != "none":
+            from fiber_tpu.telemetry import device as device_telemetry
+
+            device_telemetry.passes_traced(self.passes, self.layers,
+                                           self._recompute_label)
+        if self.passes == 1:
+            x = self._walk(params["blocks"], x, ropes, taps)
+            with jax.named_scope("lm.head_loss"):
+                return self._rms(x, params["final_norm"])
+
+        def one_pass(x, _):
+            with jax.named_scope("lm.pass"):
+                x = self._rms(self._walk(params["blocks"], x, ropes, taps),
+                              params["final_norm"])
+            return x, x
+
+        return jax.lax.scan(one_pass, x, None, length=self.passes)[1]
+
+    def _walk(self, blocks, x, ropes, taps=None):
+        """The stream ``x`` (S, dim) through the layers once, in order;
+        with ``recompute="layer"`` each application under
+        ``jax.checkpoint``."""
+        import jax
+
+        for spec, blk in zip(self.blocks, blocks):
+            def layer(blk, x, spec=spec):
+                return self._layer(spec, blk, x, ropes, taps)
+
+            if self.recompute == "layer" and taps is None:
+                # (``routing`` taps the expert layers: forward only)
+                layer = jax.checkpoint(layer)
+            x = layer(blk, x)
+        return x
+
+    def _layer(self, spec, blk, x, ropes, taps=None):
+        """One layer on the stream ``x`` (S, dim)."""
+        import jax
+
+        S, Dh, KVH = self.max_seq, self.head_dim, self.kv_heads
+        mixed = None
+        if spec.mixer == "ssm":
+            mixed = self._ssm_mix(spec.ssm, blk, x)
+        elif spec.mixer == "attention":
+            with jax.named_scope("lm.attn"), \
+                    jax.named_scope(spec.attn_kind):
+                with jax.named_scope("qkv"):
+                    h = self._rms(x, blk["norm1"])
+                    q, k, v = self._project_qkv(blk, h)
+                    q = q.reshape(S, spec.heads, Dh)
+                    k = k.reshape(S, KVH, Dh)
+                    v = v.reshape(S, KVH, Dh)
+                    if spec.rope is not None:
+                        q = self._rope_rotate(q, *ropes[spec.rope])
+                        k = self._rope_rotate(k, *ropes[spec.rope])
+                with jax.named_scope("kernel"):
+                    mixed = self._attend(
+                        q, k, v, spec.window).reshape(S, -1)
+        return self._block_tail(spec, blk, x, mixed, taps)
 
     def routing(self, params, tokens):
         """For one sequence of tokens, what the expert layers' routers
@@ -784,11 +934,25 @@ class BlockLM:
         return found
 
     def loss(self, params, tokens):
-        """Mean next-token cross-entropy over positions 0..S-2."""
+        """Mean next-token cross-entropy over positions 0..S-2; with an
+        exit gate the expected-exit loss, per position ``sum_t p_t CE_t
+        - beta H(p)`` over the passes ``t``."""
         import jax
         import jax.numpy as jnp
         import numpy as np
 
+        if self._loss_by_pass:
+            ce, p = self.pass_losses(params, tokens)
+            if p is None:
+                return jnp.mean(ce[-1])
+            with jax.named_scope("lm.exit_gate"):
+                p = p[:, :-1]
+                # p log p -> 0 with p: a pass no position leaves by
+                entropy = -jnp.sum(
+                    p * jnp.log(jnp.maximum(p, jnp.finfo(p.dtype).tiny)),
+                    axis=0)
+                return jnp.mean(jnp.sum(p * ce, axis=0)
+                                - self.exit_gate.beta * entropy)
         order = self._ring_order()
         if order is not None:
             # The rows stay where the ring has them: each takes its
@@ -819,10 +983,75 @@ class BlockLM:
         import jax
         import jax.numpy as jnp
 
+        if self._loss_by_pass:
+            return self.pass_losses(params, tokens)[0][-1]
         logits = self.apply(params, tokens)[:-1]
         with jax.named_scope("lm.head_loss"):
             logp = jax.nn.log_softmax(logits, axis=-1)
             return -jnp.take_along_axis(logp, tokens[1:, None], axis=1)[:, 0]
+
+    def pass_losses(self, params, tokens):
+        """What ``loss`` is formed from, pass by pass: (the next-token
+        cross-entropy of each position 0..S-2 under each pass's logits,
+        (passes, S-1) float32; the exit distribution of each position
+        0..S-1, (passes, S) float32, which sums to 1 over the passes, or
+        None without an exit gate). One pass: (1, S-1) and None."""
+        import jax
+        import jax.numpy as jnp
+
+        S = self.max_seq
+        x = self._normed_rows(params, tokens)
+        if self.passes == 1:
+            x = x[None]
+        p = None
+        if self.exit_gate is not None:
+            with jax.named_scope("lm.exit_gate"):
+                # float32 on the vector unit, not a rounded matrix
+                # product: sum_t p_t = 1 rests on it
+                g = jnp.sum(x.astype(jnp.float32) * params["gate_w"],
+                            axis=-1) + params["gate_b"]      # (passes, S)
+                # log p_t = log lambda_t + sum_(j<t) log(1 - lambda_j);
+                # the last pass takes what is left
+                stay = -jax.nn.softplus(g[:-1])
+                before = jnp.concatenate(
+                    [jnp.zeros((1, S)), jnp.cumsum(stay, axis=0)])
+                leave = jnp.concatenate(
+                    [-jax.nn.softplus(-g[:-1]), jnp.zeros((1, S))])
+                p = jnp.exp(before + leave)
+        with jax.named_scope("lm.head_loss"):
+            # every pass's S rows, so that the blocks are whole; the row
+            # of position S-1 has no target and is cut
+            targets = jnp.tile(jnp.roll(tokens, -1), self.passes)
+            ce = self._head_losses(x.reshape(-1, x.shape[-1]), targets,
+                                   params["out"])
+            return ce.reshape(self.passes, S)[:, :-1], p
+
+    def _head_losses(self, rows, targets, out):
+        """Cross-entropy of each of ``rows`` (N, dim) under the head
+        ``out`` (dim, vocab) against ``targets`` (N,), float32 (N,). With
+        ``head_block`` over blocks of that many rows (the last one
+        padded), each under ``jax.checkpoint``: a block's logits live
+        while it is computed, forward and backward, and no longer."""
+        import jax
+        import jax.numpy as jnp
+
+        def losses(rows, targets):
+            logits = (rows @ out).astype(jnp.float32)
+            picked = jnp.take_along_axis(
+                logits, targets[:, None], axis=1)[:, 0]
+            return jax.nn.logsumexp(logits, axis=-1) - picked
+
+        n, block = rows.shape[0], self.head_block
+        if block is None or block >= n:
+            return losses(rows, targets)
+        pad = -n % block
+        rows = jnp.pad(rows, ((0, pad), (0, 0)))
+        targets = jnp.pad(targets, (0, pad))
+        ce = jax.lax.map(
+            jax.checkpoint(lambda blk: losses(*blk)),
+            (rows.reshape(-1, block, rows.shape[-1]),
+             targets.reshape(-1, block)))
+        return ce.reshape(-1)[:n]
 
     # ------------------------------------------------------------------
     # Inference: autoregressive decode with per-layer KV caches.
@@ -836,18 +1065,33 @@ class BlockLM:
         {"conv": the convolution's last ``conv - 1`` inputs, "state":
         (heads, head_dim, state) float32}: the recurrence itself, one
         position a step (``ops.ssm.ssd_step``), not the chunked scan. A
-        block without a mixer has an empty cache. O(S) per step with
-        static shapes (jit/scan friendly), single device — decode is a
-        latency path, not a sharded-compute path.
+        block without a mixer has an empty cache. With ``passes > 1``
+        a list of such lists, one a pass: the vector goes through the
+        stack ``passes`` times, each closed by the final norm, and pass
+        ``t`` attends the keys and values pass ``t`` wrote. O(S) per
+        step with static shapes (jit/scan friendly), single device —
+        decode is a latency path, not a sharded-compute path.
         """
-        import jax
-        import jax.numpy as jnp
-
-        KVH, Dh = self.kv_heads, self.head_dim
         x = params["embed"][tok]                             # (dim,)
         if self.pos == "learned":
             x = x + params["pos"][pos]
         ropes = self._rope_tables(pos)                       # (r/2,) each
+        new_caches = []
+        for cache in (caches if self.passes > 1 else [caches]):
+            # a pass's own keys and values
+            cache, x = self._decode_walk(params, cache, pos, x, ropes)
+            x = self._rms(x, params["final_norm"])
+            new_caches.append(cache)
+        return (new_caches if self.passes > 1 else new_caches[0],
+                x @ params["out"])
+
+    def _decode_walk(self, params, caches, pos, x, ropes):
+        """One position's vector ``x`` (dim,) through the layers once:
+        (the layers' new caches, x)."""
+        import jax
+        import jax.numpy as jnp
+
+        KVH, Dh = self.kv_heads, self.head_dim
         new_caches = []
         for spec, blk, cache in zip(self.blocks, params["blocks"], caches):
             if spec.mixer != "attention":
@@ -896,12 +1140,12 @@ class BlockLM:
                               v_cache, preferred_element_type=jnp.float32)
             x = self._block_tail(spec, blk, x,
                                  attn.astype(x.dtype).reshape(-1))
-        x = self._rms(x, params["final_norm"])
-        return new_caches, x @ params["out"]
+        return new_caches, x
 
     def init_caches(self, dtype) -> list:
-        """Empty decode caches, one a block (``_decode_step`` says what
-        each kind holds). KV caches and the convolution's inputs follow
+        """Empty decode caches, one a block and, with ``passes > 1``,
+        such a list a pass (``_decode_step`` says what each kind
+        holds). KV caches and the convolution's inputs follow
         ``dtype`` (the params' — an f32 cache under bf16 params would
         silently double the KV-cache footprint, the very memory GQA
         exists to save); a state-space layer's state is float32."""
@@ -921,7 +1165,10 @@ class BlockLM:
                                        jnp.float32)})
             else:
                 caches.append({})
-        return caches
+        if self.passes == 1:
+            return caches
+        # (arrays are immutable: the passes' lists may share the zeros)
+        return [list(caches) for _ in range(self.passes)]
 
     def generate(self, params, prompt, steps: int, key=None,
                  temperature: float = 0.0):

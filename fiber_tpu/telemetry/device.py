@@ -98,6 +98,11 @@ _m_ssm_traces = telemetry.counter(
     "ssm_layers_traced",
     "State-space layers traced, by heads, state size, groups, chunk "
     "and whether the backward pass recomputes the mixer")
+_m_passes_traces = telemetry.counter(
+    "lm_passes_traced",
+    "Forward passes traced of a model whose stack of layers runs more "
+    "than once or whose step recomputes, by passes over the stack, "
+    "layers in it and what the backward pass recomputes")
 _m_flash_grid_steps = telemetry.counter(
     "flash_grid_steps",
     "Inner grid steps a head makes in the flash-attention programs "
@@ -634,6 +639,18 @@ def ssm_traced(heads: int, state: int, groups: int, chunk: int,
     _m_ssm_traces.inc(heads=str(heads), state=str(state),
                       groups=str(groups), chunk=str(chunk),
                       recompute=str(bool(recompute)).lower())
+
+
+def passes_traced(passes: int, layers: int, recompute: str) -> None:
+    """One forward of a model was traced whose ``layers`` layers run
+    ``passes`` times over the same weights, or whose step recomputes
+    (``models/transformer.py``; ``recompute`` as the ``lm.train_step``
+    span has it). Counts traces, not calls, like ``ssm_traced``: the
+    passes are one ``lax.scan`` and a checkpointed layer replays its
+    traced equations, so one traced forward moves it once whatever
+    ``passes`` and ``recompute`` are."""
+    _m_passes_traces.inc(passes=str(passes), layers=str(layers),
+                         recompute=recompute)
 
 
 def flash_grid_built(kernel: str, run: int, idle: int) -> None:

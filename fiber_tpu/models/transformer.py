@@ -229,10 +229,16 @@ class BlockLM:
     What a step recomputes (memory, never what is computed):
     ``recompute="layer"`` puts each application of a layer under
     ``jax.checkpoint``, so that the backward pass keeps a layer's input
-    and not its intermediates; ``head_block`` computes head and
-    cross-entropy over blocks of that many rows (all passes' rows
-    alike), each block recomputed in the backward pass, so that no
-    (rows, vocab) array outlives its block (None: the logits whole).
+    and, where the layer calls the flash kernels, their forward output
+    (S, heads, head_dim) and row statistics (heads, S), which
+    ``ops/pallas_attention.py`` names for the checkpoint's policy.
+    Everything else of the layer (norms, projections, ropes, the
+    feed-forward) is recomputed from the input; the forward kernel is
+    not run again, the kept output being what it would write.
+    ``head_block`` computes head and cross-entropy over blocks of that
+    many rows (all passes' rows alike), each block recomputed in the
+    backward pass, so that no (rows, vocab) array outlives its block
+    (None: the logits whole).
 
     ``apply`` / ``loss`` / ``generate`` as :class:`TinyLM`;
     ``routing(params, tokens)`` gives, for the expert layers, the taken
@@ -375,7 +381,8 @@ class BlockLM:
         #: how often the stack runs over the same weights
         self.passes = passes
         self.exit_gate = exit_gate
-        #: None, or "layer": every layer application under jax.checkpoint
+        #: None, or "layer": every layer application under jax.checkpoint,
+        #: which keeps the flash kernel's output and row statistics
         self.recompute = recompute
         #: rows of a block of the head and its cross-entropy (None: whole)
         self.head_block = head_block
@@ -418,17 +425,31 @@ class BlockLM:
             "+head" if self.head_block is not None else "")
 
     @property
+    def _kept_label(self) -> str:
+        """What the backward pass keeps of a layer application: ``all``
+        that jax's AD keeps (no ``recompute``), else the layer's
+        ``input`` and, where the layer calls the flash kernels itself
+        (one device), their output and row statistics besides."""
+        if self.recompute != "layer":
+            return "all"
+        kernels = (self.attention == "flash" and not self._flash_multi
+                   and any(b.mixer == "attention" for b in self.blocks))
+        return "input+attn_out+lse" if kernels else "input"
+
+    @property
     def span_fields(self) -> dict:
         """What the ``lm.train_step`` span says of the model: the layer
         kinds in order; with expert layers, how many experts are held
         here, exist in all and are taken a token; where the stack runs
-        more than once or a step recomputes, ``passes`` and
-        ``recompute`` (``none``, ``layer``, with ``+head`` for a
-        blocked head)."""
+        more than once or a step recomputes, ``passes``, ``recompute``
+        (``none``, ``layer``, with ``+head`` for a blocked head) and
+        ``kept`` (what the backward pass keeps of a layer application:
+        ``all``, ``input``, ``input+attn_out+lse``)."""
         fields = {"layers": ",".join(b.kind for b in self.blocks)}
         if self.passes > 1 or self._recompute_label != "none":
             fields.update(passes=self.passes,
-                          recompute=self._recompute_label)
+                          recompute=self._recompute_label,
+                          kept=self._kept_label)
         experts = [b.experts for b in self.blocks if b.experts is not None]
         if experts:
             from fiber_tpu.ops.moe import held_experts
@@ -843,7 +864,8 @@ class BlockLM:
             from fiber_tpu.telemetry import device as device_telemetry
 
             device_telemetry.passes_traced(self.passes, self.layers,
-                                           self._recompute_label)
+                                           self._recompute_label,
+                                           self._kept_label)
         if self.passes == 1:
             x = self._walk(params["blocks"], x, ropes, taps)
             with jax.named_scope("lm.head_loss"):
@@ -859,17 +881,21 @@ class BlockLM:
 
     def _walk(self, blocks, x, ropes, taps=None):
         """The stream ``x`` (S, dim) through the layers once, in order;
-        with ``recompute="layer"`` each application under
-        ``jax.checkpoint``."""
+        with ``recompute="layer"`` each application under a
+        ``jax.checkpoint`` that keeps the flash kernel's two named
+        results and nothing else."""
         import jax
 
+        from fiber_tpu.ops.pallas_attention import KEPT_NAMES
+
+        keep = jax.checkpoint_policies.save_only_these_names(*KEPT_NAMES)
         for spec, blk in zip(self.blocks, blocks):
             def layer(blk, x, spec=spec):
                 return self._layer(spec, blk, x, ropes, taps)
 
             if self.recompute == "layer" and taps is None:
                 # (``routing`` taps the expert layers: forward only)
-                layer = jax.checkpoint(layer)
+                layer = jax.checkpoint(layer, policy=keep)
             x = layer(blk, x)
         return x
 

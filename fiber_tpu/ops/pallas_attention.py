@@ -17,6 +17,15 @@ and gradients match the full-matrix reference to numerical tolerance,
 pinned by tests in interpret mode on CPU and, compiled through Mosaic,
 by ``chip_smoke.py`` on the chip.
 
+Under a checkpoint: the forward rules give the two residuals the kernel
+produced, ``out`` (S, H, D) and the compact ``lse`` (H, S), the names
+``KEPT_NAMES`` (``jax.ad_checkpoint.checkpoint_name``); q, k and v get
+none. A ``jax.checkpoint`` whose policy saves those two names keeps
+them and recomputes q, k, v and all else, and then its backward pass
+has no use for the forward kernel and holds no call of it
+(``BlockLM(recompute="layer")`` is that caller). Anywhere else a name
+lowers to nothing.
+
 The reference framework has no kernels and no attention (SURVEY.md §5);
 this is the repo's own TPU-native bar, not a parity item.
 """
@@ -26,6 +35,11 @@ from __future__ import annotations
 import functools
 
 _NEG_INF = -1e30  # large-negative instead of -inf: avoids inf-inf NaNs
+
+#: What the forward rules name of their residuals: the forward kernel's
+#: output and its row statistics (not the (H, S, 1) column the kernels
+#: read, which pads to 128 lanes).
+KEPT_NAMES = ("flash_attn_out", "flash_attn_lse")
 
 
 # ---------------------------------------------------------------------------
@@ -609,10 +623,18 @@ def _cores(shape, dtype, causal, block_q, block_kv, interpret,
 def _make_attn(shape, dtype, causal, block_q, block_kv, interpret,
                with_lse: bool, kv_heads=None, window=None, kv_len=None):
     import jax
+    from jax.ad_checkpoint import checkpoint_name
 
     _fwd_core, _bwd_core = _cores(
         shape, dtype, causal, block_q, block_kv, interpret, kv_heads,
         window, kv_len)
+
+    def _named_fwd(q, k, v):
+        """The forward rules' kernel call: the two residuals the kernel
+        produced carry KEPT_NAMES (q, k, v none)."""
+        out, lse = _fwd_core(q, k, v)
+        return (checkpoint_name(out, KEPT_NAMES[0]),
+                checkpoint_name(lse, KEPT_NAMES[1]))
 
     if not with_lse:
         @jax.custom_vjp
@@ -621,7 +643,7 @@ def _make_attn(shape, dtype, causal, block_q, block_kv, interpret,
             return out
 
         def attn_fwd(q, k, v):
-            out, lse = _fwd_core(q, k, v)
+            out, lse = _named_fwd(q, k, v)
             return out, (q, k, v, out, lse)
 
         def attn_bwd(res, dout):
@@ -636,7 +658,7 @@ def _make_attn(shape, dtype, causal, block_q, block_kv, interpret,
         return _fwd_core(q, k, v)
 
     def attn_lse_fwd(q, k, v):
-        out, lse = _fwd_core(q, k, v)
+        out, lse = _named_fwd(q, k, v)
         return (out, lse), (q, k, v, out, lse)
 
     def attn_lse_bwd(res, cots):

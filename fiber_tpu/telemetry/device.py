@@ -102,7 +102,8 @@ _m_passes_traces = telemetry.counter(
     "lm_passes_traced",
     "Forward passes traced of a model whose stack of layers runs more "
     "than once or whose step recomputes, by passes over the stack, "
-    "layers in it and what the backward pass recomputes")
+    "layers in it, what the backward pass recomputes and what it keeps "
+    "of a layer application")
 _m_flash_grid_steps = telemetry.counter(
     "flash_grid_steps",
     "Inner grid steps a head makes in the flash-attention programs "
@@ -641,16 +642,20 @@ def ssm_traced(heads: int, state: int, groups: int, chunk: int,
                       recompute=str(bool(recompute)).lower())
 
 
-def passes_traced(passes: int, layers: int, recompute: str) -> None:
+def passes_traced(passes: int, layers: int, recompute: str,
+                  kept: str) -> None:
     """One forward of a model was traced whose ``layers`` layers run
     ``passes`` times over the same weights, or whose step recomputes
-    (``models/transformer.py``; ``recompute`` as the ``lm.train_step``
-    span has it). Counts traces, not calls, like ``ssm_traced``: the
-    passes are one ``lax.scan`` and a checkpointed layer replays its
-    traced equations, so one traced forward moves it once whatever
-    ``passes`` and ``recompute`` are."""
+    (``models/transformer.py``; ``recompute`` and ``kept`` as the
+    ``lm.train_step`` span has them: ``kept="input+attn_out+lse"`` says
+    the checkpoint around a layer keeps the flash kernel's output and
+    row statistics, so the backward pass holds no forward kernel;
+    ``"input"`` that it recomputes the whole layer). Counts traces, not
+    calls, like ``ssm_traced``: the passes are one ``lax.scan`` and a
+    checkpointed layer replays its traced equations, so one traced
+    forward moves it once whatever ``passes`` and ``recompute`` are."""
     _m_passes_traces.inc(passes=str(passes), layers=str(layers),
-                         recompute=recompute)
+                         recompute=recompute, kept=kept)
 
 
 def flash_grid_built(kernel: str, run: int, idle: int) -> None:

@@ -11,6 +11,7 @@ interpreter.
 
 import importlib.util
 import os
+import re
 
 import numpy as np
 import pytest
@@ -447,12 +448,14 @@ def test_span_fields_and_the_passes_counter():
 
     fiber_tpu.init()
     counter = telemetry.counter("lm_passes_traced")
-    labels = dict(passes="3", layers="2", recompute="layer+head")
+    labels = dict(passes="3", layers="2", recompute="layer+head",
+                  kept="input")
     before = counter.value(**labels)
     model, step, params, state = _step_and_state(recompute="layer",
                                                  head_block=16)
     assert model.span_fields == {"layers": "full/gated,full/gated",
-                                 "passes": 3, "recompute": "layer+head"}
+                                 "passes": 3, "recompute": "layer+head",
+                                 "kept": "input"}
     tracing.SPANS.clear()
     step(params, state, tokens_of(3))
     step(params, state, tokens_of(4))
@@ -460,12 +463,13 @@ def test_span_fields_and_the_passes_counter():
              if s["name"] == "lm.train_step"]
     assert len(spans) == 2
     assert (spans[0]["layers"], spans[0]["passes"], spans[0]["recompute"],
-            spans[0]["tokens"]) == ("full/gated,full/gated", 3, "layer+head",
-                                    S)
+            spans[0]["kept"], spans[0]["tokens"]) == (
+                "full/gated,full/gated", 3, "layer+head", "input", S)
     # one trace of the step moves it once: the passes are one scan, a
     # checkpointed layer replays its equations, a second call traces nothing
     assert counter.value(**labels) == before + 1
-    assert model_of(gate=False).span_fields["recompute"] == "none"
+    fields = model_of(gate=False).span_fields
+    assert (fields["recompute"], fields["kept"]) == ("none", "all")
 
 
 def test_the_new_scopes_reach_the_lowered_program():
@@ -477,3 +481,164 @@ def test_the_new_scopes_reach_the_lowered_program():
                   "lm.pass/checkpoint/lm.mlp", "rematted_computation/lm.mlp",
                   "lm.exit_gate", "lm.head_loss", "lm.optimizer"):
         assert scope in text.replace(")/", "/"), scope
+
+
+# -- what a recomputed layer keeps -------------------------------------------------
+def kernel_calls(jaxpr, counts=None):
+    """How often each Pallas kernel is called in a jaxpr, by its name,
+    every nested jaxpr (jit, scan, checkpoint, custom VJP) included."""
+    counts = {} if counts is None else counts
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            counts[eqn.params["name"]] = counts.get(eqn.params["name"], 0) + 1
+            continue
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (tuple, list)) else [value]:
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    kernel_calls(sub, counts)
+    return counts
+
+
+def with_bare_checkpoint(model):
+    """The same model, each layer application under a ``jax.checkpoint``
+    with no policy: the wrapping as it was, which keeps a layer's input
+    alone and runs the forward kernel again in the backward pass."""
+    def walk(blocks, x, ropes, taps=None):
+        for spec, blk in zip(model.blocks, blocks):
+            x = jax.checkpoint(
+                lambda blk, x, spec=spec: model._layer(spec, blk, x, ropes)
+            )(blk, x)
+        return x
+
+    bare = model_of(plain_spec(kv_heads=model.kv_heads),
+                    attention=model.attention, recompute="layer",
+                    passes=model.passes)
+    bare._walk = walk
+    return bare
+
+
+@pytest.mark.parametrize("wrapping,forward", [("kept", 1), ("bare", 2)])
+def test_a_kept_layer_runs_the_forward_kernel_once(wrapping, forward):
+    """Two passes over two layers: four layer applications, two in each
+    scan's body. The differentiated loss holds one forward kernel call a
+    layer of the body (with the bare checkpoint two: the backward scan's
+    replay), and dq and dkv once each either way."""
+    model = model_of(attention="flash", recompute="layer", passes=2)
+    if wrapping == "bare":
+        model = with_bare_checkpoint(model)
+    params = model.init(jax.random.PRNGKey(1))
+    jaxpr = jax.make_jaxpr(jax.grad(model.loss))(params, tokens_of(1))
+    assert kernel_calls(jaxpr.jaxpr) == {
+        "flash_attn_fwd": forward * LAYERS, "flash_attn_dq": LAYERS,
+        "flash_attn_dkv": LAYERS}
+
+
+@pytest.mark.parametrize("kv_heads", [4, 2])
+def test_a_kept_layer_computes_what_the_recomputed_one_did(kv_heads):
+    """Loss and every gradient leaf bit for bit those of the bare
+    checkpoint (the kept output is what the second run would have
+    written), and those of no recomputation to rounding."""
+    spec = plain_spec(kv_heads=kv_heads, passes=2)
+    model = model_of(spec, attention="flash", recompute="layer")
+    params = model.init(jax.random.PRNGKey(4))
+    tokens = tokens_of(2)
+    loss, grads = jax.jit(jax.value_and_grad(model.loss))(params, tokens)
+    bare_loss, bare_grads = jax.jit(jax.value_and_grad(
+        with_bare_checkpoint(model).loss))(params, tokens)
+    assert float(loss) == float(bare_loss)
+    for (path, g), h in zip(jax.tree_util.tree_flatten_with_path(grads)[0],
+                            jax.tree.leaves(bare_grads)):
+        assert np.array_equal(np.asarray(g), np.asarray(h)), \
+            jax.tree_util.keystr(path)
+    whole = model_of(spec, attention="flash")
+    whole_loss, whole_grads = jax.value_and_grad(whole.loss)(params, tokens)
+    assert float(loss) == pytest.approx(float(whole_loss), rel=2e-6)
+    assert_grads_close(grads, whole_grads)
+
+
+@pytest.mark.parametrize("with_lse", [False, True])
+def test_the_kernels_under_the_keeping_policy(with_lse):
+    """The two entry points on their own under a checkpoint whose policy
+    saves ``KEPT_NAMES``: gradients (the lse's cotangent included) those
+    of the bare checkpoint bit for bit, and no second forward kernel."""
+    from fiber_tpu.ops.pallas_attention import (
+        KEPT_NAMES, flash_attention, flash_attention_lse)
+
+    keys = jax.random.split(jax.random.PRNGKey(8), 4)
+    q, k, v = (jax.random.normal(key, (S, 4, DH)) for key in keys[:3])
+    w = jax.random.normal(keys[3], (4, S))
+
+    def f(q, k, v):
+        if not with_lse:
+            return jnp.sum(jnp.sin(flash_attention(
+                2.0 * q, k, v, causal=True, interpret=True)))
+        out, lse = flash_attention_lse(2.0 * q, k, v, causal=True,
+                                       interpret=True)
+        return jnp.sum(jnp.sin(out)) + jnp.sum(w * lse)
+
+    policy = jax.checkpoint_policies.save_only_these_names(*KEPT_NAMES)
+    kept = jax.grad(jax.checkpoint(f, policy=policy), argnums=(0, 1, 2))
+    bare = jax.grad(jax.checkpoint(f), argnums=(0, 1, 2))
+    for g, h in zip(kept(q, k, v), bare(q, k, v)):
+        assert np.array_equal(np.asarray(g), np.asarray(h))
+    assert kernel_calls(jax.make_jaxpr(kept)(q, k, v).jaxpr)[
+        "flash_attn_fwd"] == 1
+    assert kernel_calls(jax.make_jaxpr(bare)(q, k, v).jaxpr)[
+        "flash_attn_fwd"] == 2
+
+
+@pytest.mark.parametrize("program", ["model", "lse"])
+def test_outside_a_checkpoint_a_name_is_no_op(program, monkeypatch):
+    """A model without ``recompute`` (and the lse entry point the ring
+    calls) lowers to the same StableHLO with the names and without."""
+    from fiber_tpu.ops import pallas_attention
+
+    model = model_of(attention="flash", passes=1, gate=False)
+    params = model.init(jax.random.PRNGKey(6))
+    q = jax.random.normal(jax.random.PRNGKey(9), (S, 4, DH))
+
+    def lowered():
+        # (a fresh build of the kernels' functions, and of what calls them)
+        pallas_attention._build.cache_clear()
+        pallas_attention._build_lse.cache_clear()
+        if program == "model":
+            f, args = (lambda p, t: model.loss(p, t)), (params, tokens_of(0))
+        else:
+            f, args = (lambda q: jnp.sum(jnp.prod(jnp.stack(
+                [jnp.sum(part) for part in
+                 pallas_attention.flash_attention_lse(
+                     q, q, q, causal=True, interpret=True)])))), (q,)
+        # (the counter jax ends a repeated private function's symbol with
+        # is no part of the program: it moves by one with the names)
+        return re.sub(r"(@[A-Za-z_][\w.]*?)_\d+\b", r"\1",
+                      jax.jit(jax.grad(f)).lower(*args).as_text())
+
+    named = lowered()
+    seen = []
+    monkeypatch.setattr(jax.ad_checkpoint, "checkpoint_name",
+                        lambda x, name: seen.append(name) or x)
+    unnamed = lowered()
+    pallas_attention._build.cache_clear()
+    pallas_attention._build_lse.cache_clear()
+    assert sorted(set(seen)) == sorted(pallas_attention.KEPT_NAMES)
+    assert named == unnamed
+
+
+@pytest.mark.parametrize("attention,recompute,kept", [
+    ("flash", "layer", "input+attn_out+lse"), ("reference", "layer", "input"),
+    ("flash", None, "all")])
+def test_the_passes_counter_says_what_is_kept(attention, recompute, kept):
+    import fiber_tpu
+    from fiber_tpu import telemetry
+
+    fiber_tpu.init()
+    counter = telemetry.counter("lm_passes_traced")
+    labels = dict(passes="2", layers="2", recompute=recompute or "none",
+                  kept=kept)
+    before = counter.value(**labels)
+    model = model_of(attention=attention, recompute=recompute, passes=2)
+    assert model.span_fields["kept"] == kept
+    jax.make_jaxpr(jax.grad(model.loss))(
+        model.init(jax.random.PRNGKey(2)), tokens_of(5))
+    assert counter.value(**labels) == before + 1

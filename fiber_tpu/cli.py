@@ -780,6 +780,7 @@ def _render_top_rows(pulls) -> list:
         rows.append(
             f"{key:<22} "
             f"{last.get('tasks_per_s', 0.0):>8.1f} "
+            f"{last.get('steps_per_s', 0.0):>8.2f} "
             f"{int(last.get('inflight', 0)):>9d} "
             f"{int(last.get('queue_depth', 0)):>7d} "
             f"{_human_bytes(last.get('bytes_tx_per_s', 0.0)):>10}/s "
@@ -801,7 +802,8 @@ def _human_bytes(n: float) -> str:
     return f"{n:.1f}GB"  # pragma: no cover - unreachable
 
 
-_TOP_HEADER = (f"{'HOST':<22} {'EVALS/S':>8} {'INFLIGHT':>9} "
+_TOP_HEADER = (f"{'HOST':<22} {'EVALS/S':>8} {'STEPS/S':>8} "
+               f"{'INFLIGHT':>9} "
                f"{'QUEUE':>7} {'TX':>12} {'RX':>12} {'HB-AGE':>8} "
                f"{'HBM':>15} {'DSTORE':>8} {'MFU':>6} ANOMALIES")
 

@@ -26,6 +26,11 @@ This module is the missing instrument panel:
 * **Step calls** — :func:`step` is the span and the two counters
   (``device_steps``, ``device_step_units``) around one call of a
   device-plane step function (``es.run_fused``, ``lm.train_step``).
+  The span carries the host's account of the call (:class:`StepCalls`):
+  what the calling thread did inside it and since its last one (CPU
+  time, collector time), the
+  ``step_stall`` rule over the periods from call to call, and the call
+  in flight for the sampler's ``monitor.tick`` to name.
 * **Device gauges** — per-process HBM ``memory_stats()``
   (bytes_in_use / limit; honestly ``None`` on CPU),
   live-array count/bytes, pushed into the registry each monitor tick
@@ -52,11 +57,14 @@ from __future__ import annotations
 
 import collections
 import contextlib
+import functools
+import gc
 import os
+import statistics
 import sys
 import threading
 import time
-from typing import Any, Dict, Iterator, Optional
+from typing import Any, Dict, Iterator, List, Optional
 
 from fiber_tpu import telemetry
 from fiber_tpu.telemetry import tracing
@@ -83,6 +91,10 @@ _m_step_units = telemetry.counter(
     "device_step_units",
     "Work those calls asked for, by fn (ES: generations x population; "
     "LM: tokens)")
+_m_step_stalls = telemetry.counter(
+    "device_step_stalls",
+    "Periods from one call of a device-plane step function to the next "
+    "that the step_stall rule found stalled, by fn")
 _m_rollout_traces = telemetry.counter(
     "policy_rollout_traces",
     "Env rollouts traced, by policy class and params: 'prepared' "
@@ -519,6 +531,7 @@ class DeviceTelemetry:
             self._hbm = {"bytes_in_use": None, "bytes_limit": None}
             self._live = {"count": None, "bytes": None}
             self._xla_trace = None
+        CALLS.clear()
 
 
 # ---------------------------------------------------------------------------
@@ -602,14 +615,265 @@ def transfer(site: str, nbytes: int = 0):
     return DEVICE.transfer(site, nbytes)
 
 
+# ---------------------------------------------------------------------------
+# The host's account of a step call
+# ---------------------------------------------------------------------------
+
+#: ``step_stall`` (docs/observability.md "Anomaly rules"): the periods
+#: (start of a call to the start of the next) kept for each fn, how many
+#: must be held before one is judged, and what makes one a stall: over
+#: this many medians AND over the median by this much. Constants: a
+#: threshold nobody has had to tune is no knob.
+STALL_HELD = 32
+STALL_MIN_HELD = 8
+STALL_RATIO = 1.5
+STALL_EXCESS_NS = 50_000_000
+
+#: what a call span says of the call itself; the same names under
+#: ``since_`` say it of the time since the thread's last call of that fn
+ACCOUNT_FIELDS = ("cpu_ns", "gc_ns", "gc_runs")
+_SINCE_FIELDS = tuple("since_" + name for name in ACCOUNT_FIELDS)
+
+
+def _between(sp: Dict, keys: tuple, a: tuple, b: tuple) -> None:
+    """Write into the span what moved from snapshot ``a`` to ``b``
+    (``StepCalls._snapshot``), under ``keys``."""
+    sp[keys[0]] = b[1] - a[1]
+    sp[keys[1]] = b[2] - a[2]
+    sp[keys[2]] = b[3] - a[3]
+
+
+class _Held:
+    """What the ``step_stall`` rule holds for one fn."""
+
+    __slots__ = ("periods", "start_ns", "span")
+
+    def __init__(self) -> None:
+        self.periods: "collections.deque" = collections.deque(
+            maxlen=STALL_HELD)
+        self.start_ns: Optional[int] = None  # the last call's start
+        self.span: Optional[Dict] = None     # and its span
+
+
+def _seconds(ns: Optional[int]) -> str:
+    return "-" if ns is None else f"{ns / 1e9:.3f}"
+
+
+class StepCalls:
+    """The host side of the device-plane calls that go through
+    :func:`step`: the account each call span carries, the ``step_stall``
+    rule, and the call in flight. Touched only where spans are recorded
+    at all (``telemetry.tracing_active()``)."""
+
+    def __init__(self) -> None:
+        self._lock = threading.Lock()
+        # by thread: {fn: the snapshot its last call span ended with}
+        self._tls = threading.local()
+        # the process's collector, summed from start to stop by one
+        # gc.callbacks hook that the first call installs
+        self._gc_hooked = False
+        self._gc_t0 = 0
+        self.gc_ns = 0
+        self.gc_runs = 0
+        self._held: Dict[str, _Held] = {}
+        self._stalled: set = set()
+        #: the last call begun: (thread id, fn, span id, start, end or
+        #: None while it is open), on ``time.perf_counter_ns``'s clock;
+        #: one tuple, replaced whole, so the sampler reads it unlocked
+        self.in_flight: Optional[tuple] = None
+        # (when, where) the sampler's last ticks found the caller
+        self._ticks: "collections.deque" = collections.deque(maxlen=64)
+
+    # -- sources --------------------------------------------------------
+    def _on_gc(self, phase: str, info: Dict) -> None:
+        if phase == "start":
+            self._gc_t0 = time.perf_counter_ns()
+        elif self._gc_t0:
+            self.gc_ns += time.perf_counter_ns() - self._gc_t0
+            self.gc_runs += 1
+            self._gc_t0 = 0
+
+    def _ended(self) -> Dict[str, tuple]:
+        ended = getattr(self._tls, "ended", None)
+        if ended is None:
+            with self._lock:
+                if not self._gc_hooked:
+                    gc.callbacks.append(self._on_gc)
+                    self._gc_hooked = True
+            ended = self._tls.ended = {}
+        return ended
+
+    def _snapshot(self) -> tuple:
+        """The calling thread now: (monotonic ns, thread CPU ns,
+        collector ns, collector runs). The CPU time is as fine as the
+        kernel keeps it: by the 10 ms tick on the chip's machine, so a
+        reader sums it over many calls."""
+        return (time.perf_counter_ns(), time.thread_time_ns(),
+                self.gc_ns, self.gc_runs)
+
+    # -- the two ends of a call span ------------------------------------
+    def begin(self, fn: str, sp: Dict) -> tuple:
+        """The span ``sp`` of ``fn`` has just started on this thread:
+        record what the thread did since its last one ended, publish
+        the call, and judge the period that this start closes. Gives
+        the start's snapshot and what :func:`step` is to report once
+        the span has closed (a callable, or None)."""
+        ended = self._ended()
+        start = self._snapshot()
+        last = ended.get(fn)
+        if last is not None:
+            sp["since_ns"] = start[0] - last[0]
+            _between(sp, _SINCE_FIELDS, last, start)
+        self.in_flight = (threading.get_ident(), fn, sp["span"],
+                          start[0], None)
+        return start, self._judge_period(fn, start[0], sp)
+
+    def end(self, sp: Dict, start: tuple) -> None:
+        """The call is over: record what the thread did inside it. This
+        snapshot is also where the next ``since_*`` starts, so a call
+        costs two snapshots, not four."""
+        end = self._snapshot()
+        _between(sp, ACCOUNT_FIELDS, start, end)
+        self._ended()[sp["name"]] = end
+        flight = self.in_flight
+        if flight is not None and flight[2] == sp["span"]:
+            self.in_flight = flight[:4] + (end[0],)
+
+    # -- step_stall -----------------------------------------------------
+    def _judge_period(self, fn: str, start_ns: int, sp: Dict):
+        """The start of ``sp`` closes a period of ``fn``: hold it, or
+        find it stalled. Gives what is to be reported: the stall, or
+        the first sound period after one, which clears the rule."""
+        with self._lock:
+            held = self._held.get(fn)
+            if held is None:
+                held = self._held[fn] = _Held()
+            last_ns, last_span = held.start_ns, held.span
+            held.start_ns, held.span = start_ns, sp
+            if last_ns is None:
+                return None
+            period = start_ns - last_ns
+            if len(held.periods) >= STALL_MIN_HELD:
+                median = statistics.median(held.periods)
+                if (period > STALL_RATIO * median
+                        and period - median >= STALL_EXCESS_NS):
+                    # not added to the held periods: one stall must
+                    # not raise the median it is judged against
+                    self._stalled.add(fn)
+                    return functools.partial(
+                        self._raise_stall, fn, last_ns, start_ns, median,
+                        last_span, sp)
+            held.periods.append(period)
+            if fn in self._stalled:
+                self._stalled.discard(fn)
+                if not self._stalled:
+                    return self._clear_stall
+        return None
+
+    def _raise_stall(self, fn: str, last_ns: int, start_ns: int,
+                     median: float, last_span: Dict, sp: Dict) -> None:
+        """One stalled period of ``fn``, from ``last_span``'s start to
+        ``sp``'s: the counter, and the rule's event with both halves of
+        the account and where the ticks inside found the caller."""
+        from fiber_tpu.telemetry.monitor import WATCHDOG
+
+        period = start_ns - last_ns
+        call = {k: last_span[k] for k in ACCOUNT_FIELDS if k in last_span}
+        call["ns"] = int(last_span.get("dur", 0.0) * 1e9)
+        since = {k: sp["since_" + k] for k in ("ns",) + ACCOUNT_FIELDS
+                 if "since_" + k in sp}
+        at: List[str] = []
+        for when, where in list(self._ticks):
+            if last_ns <= when <= start_ns and where not in at:
+                at.append(where)
+        _m_step_stalls.inc(fn=fn)
+        WATCHDOG.external_breach(
+            "step_stall", _stall_line(fn, period, median, call, since, at),
+            fn=fn, period_s=round(period / 1e9, 6),
+            median_s=round(median / 1e9, 6), call=call, since=since, at=at)
+
+    @staticmethod
+    def _clear_stall() -> None:
+        from fiber_tpu.telemetry.monitor import WATCHDOG
+
+        WATCHDOG.external_clear("step_stall")
+
+    # -- the sampler's side ---------------------------------------------
+    def caller_now(self) -> Dict[str, Any]:
+        """What a ``monitor.tick`` span says of the device plane's
+        caller: ``open`` (the fn of the call span open now, or None)
+        with ``open_ns`` (its age), else ``idle_ns`` (since the last one
+        ended); and ``at``, the innermost Python frame of the thread
+        that made the last call, ``file:function:line``."""
+        flight = self.in_flight
+        out: Dict[str, Any] = {"open": None}
+        if flight is None:
+            return out
+        ident, fn, _, start_ns, end_ns = flight
+        now = time.perf_counter_ns()
+        if end_ns is None:
+            out["open"], out["open_ns"] = fn, now - start_ns
+        else:
+            out["idle_ns"] = now - end_ns
+        frame = sys._current_frames().get(ident)
+        if frame is not None:
+            code = frame.f_code
+            out["at"] = (f"{os.path.basename(code.co_filename)}:"
+                         f"{code.co_name}:{frame.f_lineno}")
+            self._ticks.append((now, out["at"]))
+        return out
+
+    def clear(self) -> None:
+        with self._lock:
+            self._held.clear()
+            self._stalled.clear()
+            self._ticks.clear()
+            self.in_flight = None
+            self._tls = threading.local()
+
+
+def _stall_line(fn: str, period: int, median: float, call: Dict,
+                since: Dict, at: List[str]) -> str:
+    """The one line of a ``step_stall``: the period against the median,
+    then where the period went: inside the call that began it, and
+    between that call's end and the next one's start."""
+    return (f"{fn} period {period / 1e9:.2f} s (median "
+            f"{median / 1e9:.2f}): in call {_seconds(call.get('ns'))} s "
+            f"(cpu {_seconds(call.get('cpu_ns'))}), since "
+            f"{_seconds(since.get('ns'))} s "
+            f"(cpu {_seconds(since.get('cpu_ns'))}, "
+            f"gc {_seconds(since.get('gc_ns'))} / "
+            f"{since.get('gc_runs', 0)} runs); sampler saw "
+            + (", ".join(at) if at else "no tick inside"))
+
+
+#: Process-wide account of the device-plane step calls.
+CALLS = StepCalls()
+
+
 @contextlib.contextmanager
 def step(fn: str, units: int, **attrs) -> Iterator[Optional[Dict]]:
     """One call of the device-plane step function ``fn``: the span that
     device idle time is charged to and that compile spans hang from,
-    and the operator's counters of calls made and ``units`` of work
-    asked for. Host-side only; touches no device array."""
+    with the host's account of the call on it (:class:`StepCalls`), and
+    the operator's counters of calls made and ``units`` of work asked
+    for. Host-side only; touches no device array. With spans off it
+    yields None and reads nothing."""
+    report = None
     with tracing.span(fn, **attrs) as sp:
-        yield sp
+        if sp is None:
+            yield None
+        else:
+            start, report = CALLS.begin(fn, sp)
+            try:
+                yield sp
+            finally:
+                CALLS.end(sp, start)
+    if report is not None:
+        # a stall found, or cleared: said once the span has closed, so
+        # that no call's length or ``cpu_ns`` holds the saying of it
+        # (the next call's ``since_cpu_ns`` does: one log line a stall)
+        report()
     _m_steps.inc(fn=fn)
     _m_step_units.inc(units, fn=fn)
 

@@ -49,6 +49,11 @@ TRACKED_COUNTERS = {
     "tasks_submitted": "pool_tasks_submitted",
     "bytes_tx": "transport_bytes_tx",
     "bytes_rx": "transport_bytes_rx",
+    # the device plane's calls (telemetry/device.py ``step``), all fns
+    # together: what a training job's throughput reads on either side
+    # of a stall
+    "device_steps": "device_steps",
+    "device_step_units": "device_step_units",
 }
 TRACKED_GAUGES = {
     "queue_depth": "pool_queue_depth",
@@ -67,6 +72,8 @@ RATE_SERIES = {
     "tasks_completed": "tasks_per_s",
     "bytes_tx": "bytes_tx_per_s",
     "bytes_rx": "bytes_rx_per_s",
+    "device_steps": "steps_per_s",
+    "device_step_units": "step_units_per_s",
 }
 
 
@@ -281,12 +288,23 @@ class MonitorSampler:
             if not self.enabled or wake is not self._wake:
                 return
             try:
-                # One span per pass: when a tick ran and how long it
-                # took, next to the step calls it may have intruded on.
-                with tracing.span("monitor.tick"):
-                    self.sample_once()
+                self.tick()
             except Exception:  # noqa: BLE001 - keep sampling
                 logger.exception("monitor: sample failed")
+
+    def tick(self) -> Dict[str, Any]:
+        """One pass of the sampler thread under its ``monitor.tick``
+        span: when a tick ran and how long it took, next to the step
+        calls it may have intruded on, and where the device plane's
+        caller was as it began (``open`` / ``open_ns`` or ``idle_ns``,
+        ``at``: telemetry/device.py ``StepCalls.caller_now``), so that a
+        stalled period is crossed by ticks that name where it sat."""
+        with tracing.span("monitor.tick") as sp:
+            if sp is not None:
+                from fiber_tpu.telemetry.device import CALLS
+
+                sp.update(CALLS.caller_now())
+            return self.sample_once()
 
     # -- read side -----------------------------------------------------
     def last_sample(self) -> Dict[str, Any]:

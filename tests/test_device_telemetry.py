@@ -622,6 +622,270 @@ def test_new_shape_compiles_under_that_calls_span():
 
 
 # ---------------------------------------------------------------------------
+# the host's account on the call span, and the step_stall rule
+# ---------------------------------------------------------------------------
+
+
+def _step(fn="lm.train_step", body=None, **attrs):
+    from fiber_tpu.telemetry import device
+
+    with device.step(fn, 8, **attrs) as sp:
+        if body is not None:
+            body()
+    return sp
+
+
+def _spin(seconds):
+    end = time.thread_time() + seconds
+    while time.thread_time() < end:
+        pass
+
+
+def test_a_call_that_computes_reads_its_cpu_time():
+    fiber_tpu.init()
+    sp = _step(body=lambda: _spin(0.02))
+    assert sp["cpu_ns"] >= 15_000_000
+    assert sp["gc_ns"] >= 0 and sp["gc_runs"] >= 0
+
+
+def test_a_call_that_sleeps_reads_as_off_the_cpu():
+    fiber_tpu.init()
+    sp = _step(body=lambda: time.sleep(0.02))
+    assert sp["end_ns"] - sp["start_ns"] - sp["cpu_ns"] >= 15_000_000
+
+
+def test_es_run_fused_spans_carry_the_account_through_the_one_site():
+    fiber_tpu.init()
+    _step("es.run_fused", generations=1)
+    sp = _step("es.run_fused", generations=1)
+    assert {"cpu_ns", "gc_runs", "since_ns", "since_cpu_ns"} <= set(sp)
+    assert sp["generations"] == 1
+    assert sp in tracing.SPANS.snapshot()
+
+
+def test_since_fields_start_with_a_threads_second_call():
+    fiber_tpu.init()
+    first = _step()
+    time.sleep(0.02)
+    second = _step()
+    assert not [k for k in first if k.startswith("since_")]
+    assert second["since_ns"] >= 15_000_000
+    assert second["since_cpu_ns"] < second["since_ns"]
+    assert {"since_gc_ns", "since_gc_runs"} <= set(second)
+    # another fn on the same thread starts its own account
+    other = _step("es.run_fused")
+    assert "since_ns" not in other
+
+
+def test_since_fields_are_kept_apart_for_two_threads():
+    """Two threads call in turn: each one's ``since`` runs from its own
+    last call, across the other's call in between."""
+    fiber_tpu.init()
+    spans = {"a": [], "b": []}
+    turn = threading.Semaphore(1), threading.Semaphore(0)
+
+    def caller(name, mine, theirs):
+        for _ in range(2):
+            assert mine.acquire(timeout=10)
+            spans[name].append(_step(body=lambda: time.sleep(0.02)))
+            theirs.release()
+
+    threads = [threading.Thread(target=caller, args=("a", *turn)),
+               threading.Thread(target=caller, args=("b", *turn[::-1]))]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(timeout=20)
+        assert not t.is_alive()
+    for name in ("a", "b"):
+        first, second = spans[name]
+        assert "since_ns" not in first
+        # the other thread's 20 ms call lies inside this one's since
+        assert second["since_ns"] >= 15_000_000
+        assert second["since_cpu_ns"] < 10_000_000
+
+
+def test_a_collection_between_two_calls_shows_in_the_seconds_since():
+    import gc
+
+    fiber_tpu.init()
+    _step()
+    gc.collect()
+    sp = _step()
+    assert sp["since_gc_runs"] >= 1 and sp["since_gc_ns"] > 0
+    assert sp["gc_runs"] == 0 and sp["gc_ns"] == 0
+
+
+def test_with_telemetry_off_no_source_is_read(monkeypatch):
+    from fiber_tpu.telemetry import device
+
+    fiber_tpu.init(telemetry_enabled=False)
+
+    def fail(*a, **kw):
+        raise AssertionError("read with telemetry off")
+
+    monkeypatch.setattr(device.StepCalls, "_snapshot", fail)
+    monkeypatch.setattr(device.StepCalls, "_ended", fail)
+    monkeypatch.setattr(device.time, "thread_time_ns", fail)
+    before = len(tracing.SPANS)
+    assert _step() is None
+    assert len(tracing.SPANS) == before
+    assert device.CALLS.in_flight is None
+
+
+class _Clock:
+    """The clock the periods are judged on, moved by hand: a real sleep
+    of 10 ms overshoots under load, and 50 ms over is a stall."""
+
+    def __init__(self, monkeypatch):
+        from fiber_tpu.telemetry import device
+
+        real = device.CALLS._snapshot
+        self.ns = time.perf_counter_ns()
+        monkeypatch.setattr(device.CALLS, "_snapshot",
+                            lambda: (self.ns,) + real()[1:])
+
+    def paced(self, periods_s, fn="lm.train_step"):
+        """Calls of ``fn`` whose starts lie ``periods_s`` apart."""
+        _step(fn)
+        for period in periods_s:
+            self.ns += int(period * 1e9)
+            _step(fn)
+
+
+def test_step_stall_is_one_event_with_both_halves_of_the_account(
+        monkeypatch):
+    """Ten periods of 10 ms and one of 200: exactly one flight event on
+    the monitor plane, one move of each counter, the rule active; the
+    next 10 ms period clears it."""
+    from fiber_tpu.telemetry import device
+
+    fiber_tpu.init()
+    clock = _Clock(monkeypatch)
+    stalls = telemetry.counter("device_step_stalls")
+    anomalies = telemetry.counter("monitor_anomalies")
+    before = (stalls.value(fn="lm.train_step"),
+              anomalies.value(rule="step_stall"))
+    clock.paced([0.01] * 10)
+    assert "step_stall" not in WATCHDOG.snapshot()["active"]
+    clock.paced([0.2])
+    events = [e for e in FLIGHT.snapshot()
+              if (e["plane"], e["kind"]) == ("monitor", "step_stall")]
+    assert len(events) == 1
+    ev = events[0]
+    assert ev["fn"] == "lm.train_step"
+    assert ev["period_s"] == pytest.approx(0.2)
+    assert ev["median_s"] == pytest.approx(0.01)
+    # the call that began the period, and the time since it ended
+    assert ev["call"]["ns"] < 50_000_000 and "cpu_ns" in ev["call"]
+    assert ev["since"]["ns"] == 200_000_000
+    assert ev["since"]["cpu_ns"] < 100_000_000
+    for half in ("call", "since"):
+        assert {"cpu_ns", "gc_ns", "gc_runs"} <= set(ev[half])
+    assert ev["detail"].startswith(
+        "lm.train_step period 0.20 s (median 0.01): in call 0.0")
+    assert "since 0.200 s (cpu 0.0" in ev["detail"]
+    assert "gc 0.000 / 0 runs" in ev["detail"]
+    assert (stalls.value(fn="lm.train_step"),
+            anomalies.value(rule="step_stall")) == (before[0] + 1,
+                                                    before[1] + 1)
+    active = WATCHDOG.snapshot()["active"]
+    assert active["step_stall"]["fn"] == "lm.train_step"
+    # the stalled period is not held: the median it is judged by stands
+    assert max(device.CALLS._held["lm.train_step"].periods) == 10_000_000
+    clock.ns += 10_000_000
+    _step()
+    assert "step_stall" not in WATCHDOG.snapshot()["active"]
+    kinds = [(e["kind"], e.get("rule")) for e in FLIGHT.snapshot()
+             if e["plane"] == "monitor"]
+    assert kinds.count(("clear", "step_stall")) == 1
+    assert len([k for k in kinds if k[0] == "step_stall"]) == 1
+
+
+def test_step_stall_is_said_once_the_span_that_found_it_has_closed(
+        monkeypatch):
+    """The log line and the flight event are written after the call
+    span that ends the stalled period is over, so that neither its
+    length nor its ``cpu_ns`` holds them."""
+    from fiber_tpu.telemetry import device
+
+    fiber_tpu.init()
+    clock = _Clock(monkeypatch)
+    real, closed = device.CALLS._raise_stall, []
+
+    def raise_stall(*args):
+        closed.append("end_ns" in args[-1] and "cpu_ns" in args[-1])
+        real(*args)
+
+    monkeypatch.setattr(device.CALLS, "_raise_stall", raise_stall)
+    clock.paced([0.01] * 9 + [0.5])
+    assert closed == [True]
+    assert "step_stall" in WATCHDOG.snapshot()["active"]
+
+
+def test_step_stall_needs_eight_periods_and_fifty_milliseconds(
+        monkeypatch):
+    fiber_tpu.init()
+    clock = _Clock(monkeypatch)
+    # seven periods held: a long eighth is not judged
+    clock.paced([0.005] * 7 + [0.15], fn="es.run_fused")
+    assert "step_stall" not in WATCHDOG.snapshot()["active"]
+    # held now (the long one among them): eight medians, 49 ms over
+    clock.paced([0.005] * 3 + [0.054], fn="es.run_fused")
+    assert "step_stall" not in WATCHDOG.snapshot()["active"]
+    assert not [e for e in FLIGHT.snapshot() if e["kind"] == "step_stall"]
+    # 50 ms over, and under 1.5 medians of steps of a second
+    clock.paced([1.0] * 32 + [1.45], fn="lm.train_step")
+    assert "step_stall" not in WATCHDOG.snapshot()["active"]
+    clock.paced([0.005] * 8 + [0.056], fn="lm.other_step")
+    assert WATCHDOG.snapshot()["active"]["step_stall"]["fn"] == (
+        "lm.other_step")
+
+
+def test_step_stall_names_where_the_sampler_saw_the_caller():
+    """Ticks taken inside the stalled period give the event its ``at``:
+    the innermost frame of the calling thread."""
+    from fiber_tpu.telemetry import device
+    from fiber_tpu.telemetry.timeseries import TIMESERIES
+
+    fiber_tpu.init(monitor_enabled=False)
+    done = threading.Event()
+
+    def pause_between_steps():  # time.sleep has no Python frame
+        deadline = time.monotonic() + 5.0
+        while not done.is_set() and time.monotonic() < deadline:
+            time.sleep(0.005)
+
+    def job():
+        _step()
+        for _ in range(9):
+            time.sleep(0.01)
+            _step()
+        pause_between_steps()
+        _step()
+
+    t = threading.Thread(target=job)
+    t.start()
+    deadline = time.monotonic() + 10
+    seen = {}
+    while time.monotonic() < deadline:
+        time.sleep(0.02)
+        seen = device.CALLS.caller_now()
+        if "pause_between_steps" in seen.get("at", ""):
+            break
+    TIMESERIES.tick()
+    time.sleep(0.5)  # the pause outlasts the rule's 50 ms, under load too
+    done.set()
+    t.join(timeout=10)
+    assert not t.is_alive()
+    assert seen["open"] is None and seen["idle_ns"] > 0
+    ev = [e for e in FLIGHT.snapshot() if e["kind"] == "step_stall"][-1]
+    assert any("pause_between_steps" in at for at in ev["at"])
+    assert "sampler saw" in ev["detail"]
+    assert "pause_between_steps" in ev["detail"]
+
+
+# ---------------------------------------------------------------------------
 # collection plane: agent op, backends, CLI
 # ---------------------------------------------------------------------------
 

@@ -213,6 +213,80 @@ def test_sampler_derives_rates_from_counters():
     assert value >= 200
 
 
+def test_sampler_tracks_the_device_planes_steps():
+    """``device_steps`` / ``device_step_units`` ride the rings with
+    their rates, so ``top`` shows a training job's throughput."""
+    from fiber_tpu.cli import _TOP_HEADER, _render_top_rows
+    from fiber_tpu.telemetry import device
+
+    fiber_tpu.init(monitor_enabled=False)
+    for _ in range(4):
+        for _ in range(3):
+            with device.step("lm.train_step", 8192):
+                pass
+        TIMESERIES.sample_once()
+        time.sleep(0.02)
+    last = TIMESERIES.last_sample()
+    assert last["steps_per_s"] > 0
+    assert last["step_units_per_s"] == pytest.approx(
+        8192 * last["steps_per_s"], rel=1e-3)
+    series = TIMESERIES.snapshot()["series"]
+    assert len(series["device_steps"]) >= 4
+    assert series["device_step_units"][-1][2] >= 12 * 8192
+    pulls = {"h1:7060": {"timeseries": {"last": last},
+                         "anomalies": {"active": {"step_stall": {}}}}}
+    row = _render_top_rows(pulls)[0]
+    assert f"{last['steps_per_s']:.2f}" in row and "step_stall" in row
+    assert "STEPS/S" in _TOP_HEADER
+
+
+def test_tick_names_the_call_in_flight_and_where_its_thread_is():
+    """A tick taken while another thread sleeps inside ``device.step``
+    carries ``open``, ``open_ns`` and an ``at`` that names the sleeping
+    function; once the call is over, ``idle_ns``."""
+    from fiber_tpu.telemetry import device, tracing
+
+    fiber_tpu.init(monitor_enabled=False)
+    tracing.SPANS.clear()
+    TIMESERIES.tick()
+    assert tracing.SPANS.snapshot()[-1]["open"] is None  # no call yet
+    inside, leave = threading.Event(), threading.Event()
+
+    def sleeping_inside_the_step():
+        inside.set()
+        while not leave.is_set():
+            time.sleep(0.005)
+
+    def job():
+        with device.step("lm.train_step", 8):
+            sleeping_inside_the_step()
+
+    t = threading.Thread(target=job)
+    t.start()
+    assert inside.wait(10)
+    time.sleep(0.02)
+    TIMESERIES.tick()
+    leave.set()
+    t.join(timeout=10)
+    assert not t.is_alive()
+    tick = tracing.SPANS.snapshot()[-2]
+    assert tick["name"] == "monitor.tick"
+    assert tick["open"] == "lm.train_step"
+    assert tick["open_ns"] >= 15_000_000 and "idle_ns" not in tick
+    path, function, line = tick["at"].split(":")
+    assert path == "test_monitor.py" and int(line) > 0
+    assert function == "sleeping_inside_the_step"
+    time.sleep(0.01)
+    TIMESERIES.tick()
+    after = tracing.SPANS.snapshot()[-1]
+    assert after["open"] is None and after["idle_ns"] >= 10_000_000
+    assert "at" not in after  # its thread has ended
+    # with spans off the tick reads nothing of the caller
+    fiber_tpu.init(telemetry_enabled=False, monitor_enabled=False)
+    TIMESERIES.tick()
+    assert tracing.SPANS.snapshot()[-1] is after
+
+
 # ---------------------------------------------------------------------------
 # watchdog rules (synthetic samples — exact edge semantics)
 # ---------------------------------------------------------------------------

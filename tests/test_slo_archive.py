@@ -118,6 +118,9 @@ def _unique_job(tag: str) -> str:
 
 
 def test_archive_kinds_labels_and_ranges(tmp_path):
+    # no sampler thread: a tick of its own between enable and query
+    # archives a second ``tasks_per_s`` point (seen once in a whole run)
+    fiber_tpu.init(monitor_enabled=False)
     ARCHIVE.enable(str(tmp_path / "arch"))
     now = time.time()
     ARCHIVE.append("slo_obs", {"tenant": "alice", "state": "done",

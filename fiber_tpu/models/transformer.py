@@ -749,22 +749,39 @@ class BlockLM:
         adds to it (S, dim): norm, in-projection, causal convolution,
         chunked scan, gated group norm, out-projection."""
         import jax
+        import jax.numpy as jnp
 
         from fiber_tpu.ops import ssm as ops
         from fiber_tpu.telemetry import device as device_telemetry
 
+        path = ops.scan_path(x.shape[0], ssm.heads, ssm.head_dim,
+                             ssm.groups, ssm.state, ssm.chunk,
+                             self.interpret)
         device_telemetry.ssm_traced(ssm.heads, ssm.state, ssm.groups,
-                                    ssm.chunk, ssm.recompute)
+                                    ssm.chunk, ssm.recompute, path)
+        # the interpreter is asked for only where a kernel runs in it:
+        # every other call of the scan is the plain form's, argument for
+        # argument (a test may stand in for ``ops.ssd_scan`` under it)
+        how = {"interpret": True} if (
+            self.interpret and path == "kernel") else {}
 
         def mix(blk, x):
             z, xbc, dt = self._ssm_parts(ssm, blk, x)
             with jax.named_scope("conv"):
-                xbc = jax.nn.silu(
-                    ops.causal_conv(xbc, blk["conv_w"], blk["conv_b"]))
+                # x, B and C each convolved on its own (the convolution
+                # is depthwise): the scan's kernels then read three
+                # arrays, not slices of one that XLA has to copy out
+                cuts = [ssm.inner, ssm.inner + ssm.groups * ssm.state]
+                xbc = jnp.concatenate([
+                    jax.nn.silu(ops.causal_conv(v, w, b))
+                    for v, w, b in zip(
+                        jnp.split(xbc, cuts, axis=-1),
+                        jnp.split(blk["conv_w"], cuts, axis=0),
+                        jnp.split(blk["conv_b"], cuts))], axis=-1)
             with jax.named_scope("scan"):
                 y = self._ssm_scanned(
                     ssm, blk, xbc, dt,
-                    lambda *a: ops.ssd_scan(*a, chunk=ssm.chunk))
+                    lambda *a: ops.ssd_scan(*a, chunk=ssm.chunk, **how))
             return self._ssm_out(ssm, blk, y, z)
 
         with jax.named_scope("lm.ssm"):

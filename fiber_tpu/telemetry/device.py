@@ -108,8 +108,9 @@ _m_moe_traces = telemetry.counter(
     "all and experts a token takes")
 _m_ssm_traces = telemetry.counter(
     "ssm_layers_traced",
-    "State-space layers traced, by heads, state size, groups, chunk "
-    "and whether the backward pass recomputes the mixer")
+    "State-space layers traced, by heads, state size, groups, chunk, "
+    "whether the backward pass recomputes the mixer and the form the "
+    "scan runs in (kernel or plain)")
 _m_passes_traces = telemetry.counter(
     "lm_passes_traced",
     "Forward passes traced of a model whose stack of layers runs more "
@@ -896,14 +897,19 @@ def moe_traced(held: int, total: int, top_k: int) -> None:
 
 
 def ssm_traced(heads: int, state: int, groups: int, chunk: int,
-               recompute: bool) -> None:
+               recompute: bool, scan: str) -> None:
     """One state-space layer was traced (``models/transformer.py``).
     Counts traces, not calls, like ``moe_traced``; a recomputed mixer's
     forward pass is traced once (``jax.checkpoint`` replays the traced
-    equations), so recomputation does not move it twice."""
+    equations), so recomputation does not move it twice. ``scan`` is the
+    form its scan runs in (``ops/ssm.py`` ``scan_path``): ``kernel``, the
+    two Pallas kernels, or ``plain``; an operator who reads ``plain`` on
+    a TPU has a shape the kernels were not written for (a block that is
+    not 128, a state or a group's heads not whole in 128 lanes), and the
+    scan's seventy fusions a layer back."""
     _m_ssm_traces.inc(heads=str(heads), state=str(state),
                       groups=str(groups), chunk=str(chunk),
-                      recompute=str(bool(recompute)).lower())
+                      recompute=str(bool(recompute)).lower(), scan=scan)
 
 
 def passes_traced(passes: int, layers: int, recompute: str,

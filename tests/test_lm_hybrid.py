@@ -432,8 +432,10 @@ def test_span_fields_and_the_state_space_counter():
 
     fiber_tpu.init()
     counter = telemetry.counter("ssm_layers_traced")
+    # blocks of 16 positions and a state of 16 are no shape of the scan's
+    # kernels (tests/test_ssm_kernels.py has a model that takes them)
     labels = dict(heads="4", state="16", groups="2", chunk="16",
-                  recompute="true")
+                  recompute="true", scan="plain")
     before = counter.value(**labels)
     model, step, params, state = _step_and_state()
     tracing.SPANS.clear()

@@ -11,6 +11,8 @@ from fiber_tpu.models.transformer import (  # noqa: F401
     BlockLM,
     ExitGate,
     Experts,
+    Latent,
+    MTP,
     Rope,
     StateSpace,
     TinyLM,

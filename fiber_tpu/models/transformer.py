@@ -17,13 +17,15 @@ embedding -> [RMSNorm -> attention -> residual -> RMSNorm -> MLP ->
 residual] x L -> norm -> logits.
 
 A model is a description of its layers (:class:`Block`): each layer has
-a mixer (attention with its own query-head count, window and rope, or a
+a mixer (attention with its own query-head count, window and rope, latent
+attention over low-rank queries, keys and values (:class:`Latent`), or a
 state-space mixer, :mod:`fiber_tpu.ops.ssm`), a feed-forward (ungated
 MLP, gated MLP, or sparse experts of which this program holds a share,
 :mod:`fiber_tpu.ops.moe`), or one of the two alone, each part behind a
 norm and, with ``post_norm``, before a second one. :class:`BlockLM`
 runs any such description, once or ``passes`` times over the same
-weights (with an :class:`ExitGate`, under the expected-exit loss);
+weights (with an :class:`ExitGate`, under the expected-exit loss), with
+a multi-token-prediction module (:class:`MTP`) or without;
 :class:`TinyLM` is the uniform one.
 """
 
@@ -58,11 +60,16 @@ class Yarn:
 class Rope:
     """A layer's rotary embedding: ``base``, how many leading features
     of each head rotate (``rotary``; None = the whole head; the rest
-    pass through), and an optional YaRN scaling."""
+    pass through), and an optional YaRN scaling. Feature ``j`` of the
+    first half turns with feature ``j + r/2`` (``rotate_half``), or,
+    ``interleaved``, feature ``2j`` with ``2j + 1`` (adjacent pairs, the
+    ``rope_interleave`` of DeepSeek-V3's configuration); pair ``j``
+    turns by ``position * base^(-2j/r)`` either way."""
 
     base: float = 10000.0
     rotary: Optional[int] = None
     yarn: Optional[Yarn] = None
+    interleaved: bool = False
 
     def table(self, head_dim: int):
         """(features that rotate, inverse frequencies or None for the
@@ -155,16 +162,49 @@ class StateSpace:
 
 
 @dataclasses.dataclass(frozen=True)
+class Latent:
+    """Latent attention (MLA, DeepSeek-V3 report arXiv:2412.19437
+    §2.1.1): queries from a low-rank ``c_q = RMSNorm(x W_qa)`` of
+    ``q_rank``, ``q = c_q W_qb`` as heads of ``nope + rope_dim``; keys
+    and values from one ``[c_kv ; k_pe] = x W_kva`` of ``kv_rank +
+    rope_dim``, ``[k_nope ; v] = RMSNorm(c_kv) W_kvb`` as heads of
+    ``nope + v_dim``; the rope turns each head's last ``rope_dim``
+    features of q and the one ``k_pe`` that all heads share; a head
+    attends over ``qk_dim = nope + rope_dim`` features at scale
+    ``qk_dim^-0.5`` and returns ``v_dim``, which the out-projection
+    reads, ``heads x v_dim`` wide."""
+
+    q_rank: int
+    kv_rank: int
+    nope: int
+    rope_dim: int
+    v_dim: int
+
+    @property
+    def qk_dim(self) -> int:
+        return self.nope + self.rope_dim
+
+    @property
+    def label(self) -> str:
+        """What the ``lm.train_step`` span says of it."""
+        return (f"q{self.q_rank}/kv{self.kv_rank}/qk{self.nope}+"
+                f"{self.rope_dim}/v{self.v_dim}")
+
+
+@dataclasses.dataclass(frozen=True)
 class Block:
     """One layer: a mixer, a feed-forward, or one of the two alone, each
     part with its own RMSNorm and residual. ``mixer="attention"``:
     ``heads`` query heads (of the model's ``head_dim``, grouped over its
     ``kv_heads``), causal attention over the last ``window`` positions
     (None = all), its ``rope`` (None with a learned position table or
-    with no position scheme at all); ``"ssm"``: the state-space mixer
-    ``ssm=``; None: no mixer. The feed-forward: ``ffn="mlp"`` (ungated
-    tanh-GELU of ``width`` with biases), ``"gated"`` (SwiGLU of
-    ``width``, no biases), ``"experts"`` (``experts=``) or None.
+    with no position scheme at all); ``"latent"``: latent attention
+    ``latent=`` (:class:`Latent`) with ``heads`` heads of its own widths
+    and its ``rope``, causal over all positions; ``"ssm"``: the
+    state-space mixer ``ssm=``; None: no mixer. The feed-forward:
+    ``ffn="mlp"`` (ungated tanh-GELU of ``width`` with biases),
+    ``"gated"`` (SwiGLU of ``width``, no biases), ``"experts"``
+    (``experts=``) or None.
     ``post_norm``: a second RMSNorm, with a gain of its own, behind each
     part (a sandwich): ``x + norm(part(norm(x)))``."""
 
@@ -177,14 +217,23 @@ class Block:
     mixer: Optional[str] = "attention"
     ssm: Optional[StateSpace] = None
     post_norm: bool = False
+    latent: Optional[Latent] = None
 
     @property
     def attn_kind(self) -> str:
+        if self.mixer == "latent":
+            return "latent"
         return "window" if self.window is not None else "full"
 
     @property
+    def attends(self) -> bool:
+        """Whether the mixer is attention of either kind."""
+        return self.mixer in ("attention", "latent")
+
+    @property
     def kind(self) -> str:
-        mixer = {"attention": self.attn_kind, "ssm": "ssm"}.get(self.mixer)
+        mixer = (self.attn_kind if self.attends
+                 else {"ssm": "ssm"}.get(self.mixer))
         return "/".join(part for part in (mixer, self.ffn) if part)
 
 
@@ -199,6 +248,26 @@ class ExitGate:
     (``H`` the entropy of ``p``: a uniform prior over the exits)."""
 
     beta: float = 0.05
+
+
+@dataclasses.dataclass(frozen=True)
+class MTP:
+    """Multi-token prediction (DeepSeek-V3 report §2.2): a module that
+    predicts the token after next. Row ``i`` of the main stack, before
+    the final norm, and the embedding of token ``i + 1`` (zero for the
+    last row) each pass an RMSNorm of their own, are joined
+    (embedding first) and projected back to the model's width by
+    ``eh_proj``; one whole layer (``block``) runs over the result,
+    causal, with the main model's ropes; a norm of its own and the
+    main model's head give the logits of token ``i + 2``. The loss
+    adds ``weight`` times the mean of their cross-entropy over rows
+    ``0 .. S-3``. ``depth``: modules chained (1 is what is
+    implemented). Training only: ``apply`` and ``generate`` are the
+    main model's."""
+
+    block: Block
+    depth: int = 1
+    weight: float = 0.3
 
 
 class BlockLM:
@@ -240,9 +309,16 @@ class BlockLM:
     backward pass, so that no (rows, vocab) array outlives its block
     (None: the logits whole).
 
+    ``mtp`` (:class:`MTP`): a multi-token-prediction module whose term
+    ``loss`` adds (through the blocked head too); with it, or with a
+    latent layer, the model trains on one device, once over its stack,
+    with the flash kernels or the reference attention, and does not
+    decode.
+
     ``apply`` / ``loss`` / ``generate`` as :class:`TinyLM`;
-    ``routing(params, tokens)`` gives, for the expert layers, the taken
-    expert ids of every token and the load of each held expert."""
+    ``routing(params, tokens)`` gives, for the expert layers (the MTP
+    module's last), the taken expert ids of every token and the load of
+    each held expert."""
 
     def __init__(self, blocks: Sequence[Block], *, vocab: int, dim: int,
                  head_dim: int, kv_heads: int, max_seq: int,
@@ -250,8 +326,10 @@ class BlockLM:
                  interpret: bool = False, norm_eps: float = 1e-6,
                  passes: int = 1, exit_gate: Optional[ExitGate] = None,
                  recompute: Optional[str] = None,
-                 head_block: Optional[int] = None) -> None:
+                 head_block: Optional[int] = None,
+                 mtp: Optional[MTP] = None) -> None:
         blocks = tuple(blocks)
+        every = blocks + ((mtp.block,) if mtp is not None else ())
         if passes < 1:
             raise ValueError(f"passes must be >= 1, got {passes}")
         if exit_gate is not None and passes == 1:
@@ -270,13 +348,35 @@ class BlockLM:
                 "state-space layer's carried state have no pass")
         if attention not in ("ring", "ulysses", "flash", "reference"):
             raise ValueError(f"unknown attention {attention!r}")
+        if mtp is not None and mtp.depth != 1:
+            raise ValueError(
+                f"an MTP of depth {mtp.depth}: one module (DeepSeek-V3's "
+                "D = 1) is what is implemented")
+        # latent layers and an MTP module train on one device, once over
+        # the stack, and do not decode yet
+        latent_or_mtp = (mtp is not None
+                         or any(b.mixer == "latent" for b in every))
+        if latent_or_mtp:
+            what = "an MTP module" if mtp is not None else "a latent layer"
+            if passes > 1:
+                raise ValueError(
+                    f"{what} runs once over the stack: passes > 1 has no "
+                    "second stream for it")
+            if attention not in ("flash", "reference"):
+                raise ValueError(
+                    f"{what} attends through the flash kernels or the "
+                    f"reference, not {attention!r}: the sequence-parallel "
+                    "planes have no value width of its own")
         if pos not in ("learned", "rope", "none"):
             raise ValueError(f"unknown positional scheme {pos!r}")
         if kv_heads < 1:
             raise ValueError(f"kv_heads must be >= 1, got {kv_heads}")
-        for b in blocks:
-            if b.mixer not in ("attention", "ssm", None):
+        for b in every:
+            if b.mixer not in ("attention", "latent", "ssm", None):
                 raise ValueError(f"unknown mixer {b.mixer!r}")
+            if (b.mixer == "latent") != (b.latent is not None):
+                raise ValueError(
+                    "mixer='latent' comes with latent=, and no other does")
             if b.mixer is None and b.ffn is None:
                 raise ValueError(
                     "a layer with no part: give the block a mixer or a "
@@ -293,7 +393,7 @@ class BlockLM:
                     raise ValueError(
                         f"a sequence of {max_seq} positions is not whole "
                         f"chunks of {b.ssm.chunk}")
-            if b.mixer == "attention":
+            if b.attends:
                 self._check_attention(b, kv_heads, head_dim, pos, attention)
             if b.ffn not in ("mlp", "gated", "experts", None):
                 raise ValueError(f"unknown feed-forward {b.ffn!r}")
@@ -310,7 +410,7 @@ class BlockLM:
             elif b.ffn is not None and b.width < 1:
                 raise ValueError(f"feed-forward width {b.width}")
         self._flash_multi = False
-        attends = any(b.mixer == "attention" for b in blocks)
+        attends = any(b.attends for b in every)
         if mesh is not None:
             import numpy as np
 
@@ -325,6 +425,11 @@ class BlockLM:
                     "passes > 1 and head_block run on one device: the "
                     "sequence-parallel plane orders the rows and "
                     "carries no pass; drop the mesh")
+            if multi and latent_or_mtp:
+                raise ValueError(
+                    "latent layers and an MTP module run on one device: "
+                    "the ring has no value width of its own and no "
+                    "shifted stream; drop the mesh")
             if multi and "pool" not in mesh.shape:
                 # Loud, at construction: the sequence-parallel planes
                 # shard over the mesh's "pool" axis — without this
@@ -386,13 +491,28 @@ class BlockLM:
         self.recompute = recompute
         #: rows of a block of the head and its cross-entropy (None: whole)
         self.head_block = head_block
+        #: the multi-token-prediction module, or None
+        self.mtp = mtp
+        #: the layers of the stack and, last, the MTP module's
+        self._every = every
+        self._latent_or_mtp = latent_or_mtp
         self._mesh = mesh
         self._probe = None
 
     @staticmethod
     def _check_attention(b, kv_heads, head_dim, pos, attention):
         """Refuse an attention block the model cannot run."""
-        if b.heads < 1 or b.heads % kv_heads:
+        if b.mixer == "latent":
+            if b.heads < 1:
+                raise ValueError(f"a latent layer of {b.heads} heads")
+            if b.window is not None:
+                raise ValueError(
+                    "a latent layer attends over all positions: the "
+                    "window is not implemented for it")
+            if b.rope is None:
+                raise ValueError(
+                    "a latent layer's shared key is its rope: give it one")
+        elif b.heads < 1 or b.heads % kv_heads:
             raise ValueError(
                 f"heads {b.heads} not divisible by kv_heads {kv_heads}")
         if (pos == "rope") != (b.rope is not None):
@@ -400,7 +520,7 @@ class BlockLM:
                 "pos='rope' gives every block a rope and "
                 "pos='learned' or 'none' none")
         if b.rope is not None:
-            b.rope.table(head_dim)
+            b.rope.table(BlockLM._rope_width(b, head_dim))
         if b.window is not None:
             if b.window < 1:
                 raise ValueError(
@@ -433,7 +553,7 @@ class BlockLM:
         if self.recompute != "layer":
             return "all"
         kernels = (self.attention == "flash" and not self._flash_multi
-                   and any(b.mixer == "attention" for b in self.blocks))
+                   and any(b.attends for b in self._every))
         return "input+attn_out+lse" if kernels else "input"
 
     @property
@@ -444,13 +564,20 @@ class BlockLM:
         more than once or a step recomputes, ``passes``, ``recompute``
         (``none``, ``layer``, with ``+head`` for a blocked head) and
         ``kept`` (what the backward pass keeps of a layer application:
-        ``all``, ``input``, ``input+attn_out+lse``)."""
+        ``all``, ``input``, ``input+attn_out+lse``); with latent layers
+        ``latent``, their widths (``q1536/kv512/qk128+64/v128``); with an
+        MTP module ``mtp``, its depth and weight (``1/0.3``)."""
         fields = {"layers": ",".join(b.kind for b in self.blocks)}
         if self.passes > 1 or self._recompute_label != "none":
             fields.update(passes=self.passes,
                           recompute=self._recompute_label,
                           kept=self._kept_label)
-        experts = [b.experts for b in self.blocks if b.experts is not None]
+        latent = [b.latent for b in self._every if b.latent is not None]
+        if latent:
+            fields["latent"] = latent[0].label
+        if self.mtp is not None:
+            fields["mtp"] = f"{self.mtp.depth}/{self.mtp.weight:g}"
+        experts = [b.experts for b in self._every if b.experts is not None]
         if experts:
             from fiber_tpu.ops.moe import held_experts
 
@@ -471,12 +598,19 @@ class BlockLM:
         five: in_proj, conv_w, conv_b (uniform in +-conv^-0.5), the step
         sizes (``dt_bias`` is the inverse softplus of ``dt`` drawn
         log-uniformly in [dt_min, dt_max], floored at dt_floor),
-        out_proj; ``A_log = log(1..heads)``, ``D = 1``. A part the layer
+        out_proj; ``A_log = log(1..heads)``, ``D = 1``. A latent mixer
+        splits 0 in two (``wq_a``, ``wq_b``) and 4 in two (``wkv_a``,
+        ``wkv_b``); 1 is its ``wo``; its ``q_norm`` and ``kv_norm`` are
+        gains. A part the layer
         does not have draws nothing and has no leaf (``norm1`` is the
         mixer's gain, ``norm2`` the feed-forward's; with ``post_norm``
         ``post_norm1`` and ``post_norm2`` are the gains behind them,
         and a gain draws nothing). An exit gate's ``gate_w`` is drawn
-        from the rest the last layer leaves; ``gate_b`` is 0."""
+        from the rest the last layer leaves; ``gate_b`` is 0. An MTP
+        module (``params["mtp"]``) splits that rest in two: ``eh_proj``
+        (2 dim, dim) from the first, its layer's seven keys from the
+        second as any layer's; ``enorm``, ``hnorm`` and ``norm`` are
+        gains."""
         import jax
         import jax.numpy as jnp
 
@@ -500,58 +634,87 @@ class BlockLM:
         for spec in self.blocks:
             keys = jax.random.split(key, 7)
             key = keys[6]
-            d, q_dim = self.dim, spec.heads * self.head_dim
-            blk = {}
-            if spec.mixer == "attention":
-                blk.update(norm1=jnp.ones((d,)),
-                           wo=normal(keys[1], q_dim, d))
-                if self.kv_heads == spec.heads:
-                    blk["wqkv"] = normal(keys[0], d, 3 * q_dim)
-                else:
-                    kv_dim = self.kv_heads * self.head_dim
-                    blk["wq"] = normal(keys[0], d, q_dim)
-                    blk["wkv"] = normal(keys[4], d, 2 * kv_dim)
-            elif spec.mixer == "ssm":
-                blk.update(norm1=jnp.ones((d,)),
-                           **self._init_ssm(spec.ssm, keys[0], normal))
-            if spec.ffn is not None:
-                blk["norm2"] = jnp.ones((d,))
-            if spec.post_norm:
-                for part, n in ((spec.mixer, "1"), (spec.ffn, "2")):
-                    if part is not None:
-                        blk["post_norm" + n] = jnp.ones((d,))
-            if spec.ffn == "mlp":
-                h = spec.width
-                blk.update(w1=normal(keys[2], d, h), b1=jnp.zeros((h,)),
-                           w2=normal(keys[3], h, d), b2=jnp.zeros((d,)))
-            elif spec.ffn == "gated":
-                h = spec.width
-                blk.update(wg=normal(keys[2], d, h),
-                           wd=normal(keys[3], h, d),
-                           wu=normal(keys[5], d, h))
-            elif spec.ffn == "experts":
-                from fiber_tpu.ops.moe import EXPERT_MATRICES, held_experts
-
-                e = spec.experts
-                held = held_experts(e.total, e.share)[1]
-                sub = jax.random.split(keys[5], 7)
-
-                def matrix(k, m, width, *lead):
-                    return normal(k, *lead, *((width, d) if m == "wd"
-                                              else (d, width)))
-
-                blk["router"] = normal(sub[0], d, e.total)
-                for i, m in enumerate(("wg", "wu", "wd")):
-                    if m in EXPERT_MATRICES[e.kind]:
-                        blk["shared_" + m] = matrix(sub[1 + i], m,
-                                                    e.shared_width)
-                        blk["experts_" + m] = matrix(sub[4 + i], m,
-                                                     e.width, held)
-            params["blocks"].append(blk)
+            params["blocks"].append(self._init_block(spec, keys, normal))
         if self.exit_gate is not None:
             params["gate_w"] = normal(key, self.dim)
             params["gate_b"] = jnp.zeros(())
+        if self.mtp is not None:
+            k_eh, k_blk = jax.random.split(key)
+            d = self.dim
+            params["mtp"] = {
+                "enorm": jnp.ones((d,)), "hnorm": jnp.ones((d,)),
+                "eh_proj": normal(k_eh, 2 * d, d), "norm": jnp.ones((d,)),
+                "block": self._init_block(
+                    self.mtp.block, jax.random.split(k_blk, 7), normal)}
         return params
+
+    def _init_block(self, spec, keys, normal) -> dict:
+        """One layer's leaves from its seven keys (``init`` says the
+        stream)."""
+        import jax
+        import jax.numpy as jnp
+
+        d, q_dim = self.dim, spec.heads * self.head_dim
+        blk = {}
+        if spec.mixer == "latent":
+            a, h = spec.latent, spec.heads
+            k_qa, k_qb = jax.random.split(keys[0])
+            k_kva, k_kvb = jax.random.split(keys[4])
+            blk.update(
+                norm1=jnp.ones((d,)),
+                wq_a=normal(k_qa, d, a.q_rank),
+                q_norm=jnp.ones((a.q_rank,)),
+                wq_b=normal(k_qb, a.q_rank, h * a.qk_dim),
+                wkv_a=normal(k_kva, d, a.kv_rank + a.rope_dim),
+                kv_norm=jnp.ones((a.kv_rank,)),
+                wkv_b=normal(k_kvb, a.kv_rank, h * (a.nope + a.v_dim)),
+                wo=normal(keys[1], h * a.v_dim, d))
+        elif spec.mixer == "attention":
+            blk.update(norm1=jnp.ones((d,)),
+                       wo=normal(keys[1], q_dim, d))
+            if self.kv_heads == spec.heads:
+                blk["wqkv"] = normal(keys[0], d, 3 * q_dim)
+            else:
+                kv_dim = self.kv_heads * self.head_dim
+                blk["wq"] = normal(keys[0], d, q_dim)
+                blk["wkv"] = normal(keys[4], d, 2 * kv_dim)
+        elif spec.mixer == "ssm":
+            blk.update(norm1=jnp.ones((d,)),
+                       **self._init_ssm(spec.ssm, keys[0], normal))
+        if spec.ffn is not None:
+            blk["norm2"] = jnp.ones((d,))
+        if spec.post_norm:
+            for part, n in ((spec.mixer, "1"), (spec.ffn, "2")):
+                if part is not None:
+                    blk["post_norm" + n] = jnp.ones((d,))
+        if spec.ffn == "mlp":
+            h = spec.width
+            blk.update(w1=normal(keys[2], d, h), b1=jnp.zeros((h,)),
+                       w2=normal(keys[3], h, d), b2=jnp.zeros((d,)))
+        elif spec.ffn == "gated":
+            h = spec.width
+            blk.update(wg=normal(keys[2], d, h),
+                       wd=normal(keys[3], h, d),
+                       wu=normal(keys[5], d, h))
+        elif spec.ffn == "experts":
+            from fiber_tpu.ops.moe import EXPERT_MATRICES, held_experts
+
+            e = spec.experts
+            held = held_experts(e.total, e.share)[1]
+            sub = jax.random.split(keys[5], 7)
+
+            def matrix(k, m, width, *lead):
+                return normal(k, *lead, *((width, d) if m == "wd"
+                                          else (d, width)))
+
+            blk["router"] = normal(sub[0], d, e.total)
+            for i, m in enumerate(("wg", "wu", "wd")):
+                if m in EXPERT_MATRICES[e.kind]:
+                    blk["shared_" + m] = matrix(sub[1 + i], m,
+                                                e.shared_width)
+                    blk["experts_" + m] = matrix(sub[4 + i], m,
+                                                 e.width, held)
+        return blk
 
     def _init_ssm(self, ssm, key, normal) -> dict:
         """A state-space mixer's leaves (``init`` says the stream)."""
@@ -671,32 +834,54 @@ class BlockLM:
             return factor * jnp.cos(ang), factor * jnp.sin(ang)
         return jnp.cos(ang), jnp.sin(ang)
 
+    @staticmethod
+    def _rope_width(spec, head_dim):
+        """The features a block's rope acts on: the model's heads, or a
+        latent layer's rope part."""
+        return spec.latent.rope_dim if spec.mixer == "latent" else head_dim
+
+    @staticmethod
+    def _rope_key(spec):
+        """Where ``_rope_tables`` keeps a block's table: by its rope, and
+        a latent layer's by the width it turns too."""
+        return ((spec.rope, spec.latent.rope_dim) if spec.mixer == "latent"
+                else spec.rope)
+
     def _rope_tables(self, positions):
-        """{rope: (cos, sin)} for each distinct rope of the blocks."""
+        """{``_rope_key``: (cos, sin)} for each distinct rope of the
+        blocks (the MTP module's too)."""
         tables = {}
-        for spec in self.blocks:
-            if (spec.mixer == "attention" and spec.rope is not None
-                    and spec.rope not in tables):
-                r, inv, factor = spec.rope.table(self.head_dim)
-                tables[spec.rope] = self._rope_angles(
+        for spec in self._every:
+            key = self._rope_key(spec)
+            if spec.attends and spec.rope is not None and key not in tables:
+                r, inv, factor = spec.rope.table(
+                    self._rope_width(spec, self.head_dim))
+                tables[key] = self._rope_angles(
                     positions, r, spec.rope.base, inv, factor)
         return tables
 
     @staticmethod
-    def _rope_rotate(x, cos, sin):
-        """Rotate feature pairs (half-split convention) of the leading
-        ``2 * cos.shape[-1]`` features of x's last axis; the rest pass
-        through. cos/sin broadcast against x's leading axes. The result
-        keeps x's dtype: f32 cos/sin must not silently promote a bf16
-        stream (which would also let decode's cache cast rotated keys
-        back DOWN, drifting incremental decode away from full-apply)."""
+    def _rope_rotate(x, cos, sin, interleaved=False):
+        """Rotate feature pairs of the leading ``2 * cos.shape[-1]``
+        features of x's last axis; the rest pass through. Pairs are the
+        two halves' (``rotate_half``) or, ``interleaved``, adjacent
+        features ``(2j, 2j + 1)``. cos/sin broadcast against x's leading
+        axes. The result keeps x's dtype: f32 cos/sin must not silently
+        promote a bf16 stream (which would also let decode's cache cast
+        rotated keys back DOWN, drifting incremental decode away from
+        full-apply)."""
         import jax.numpy as jnp
 
         r = 2 * cos.shape[-1]
         if r < x.shape[-1]:
             return jnp.concatenate(
-                [BlockLM._rope_rotate(x[..., :r], cos, sin), x[..., r:]],
-                axis=-1)
+                [BlockLM._rope_rotate(x[..., :r], cos, sin, interleaved),
+                 x[..., r:]], axis=-1)
+        if interleaved:
+            x1, x2 = x[..., 0::2], x[..., 1::2]
+            return jnp.stack(
+                [x1 * cos - x2 * sin, x2 * cos + x1 * sin],
+                axis=-1).reshape(x.shape).astype(x.dtype)
         x1, x2 = jnp.split(x, 2, axis=-1)
         return jnp.concatenate(
             [x1 * cos - x2 * sin, x2 * cos + x1 * sin],
@@ -798,7 +983,7 @@ class BlockLM:
             """The norm behind a part, where the layer has one."""
             return self._rms(y, blk[gain]) if spec.post_norm else y
 
-        if spec.mixer == "attention":
+        if spec.attends:
             with jax.named_scope("lm.attn"), \
                     jax.named_scope(spec.attn_kind), jax.named_scope("out"):
                 x = x + behind(mixed @ blk["wo"], "post_norm1")
@@ -835,10 +1020,12 @@ class BlockLM:
         """tokens (max_seq,) int -> logits (max_seq, vocab).
 
         The named scopes (``lm.embed``, ``lm.attn`` with ``window`` or
-        ``full`` and under it ``qkv``, ``kernel``, ``out``, ``lm.ssm``
-        with ``in_proj``, ``conv``, ``scan``, ``gate_norm``, ``out``,
-        ``lm.mlp``, ``lm.moe``, ``lm.head_loss``) are metadata: every op's
-        ``op_name`` in a profile starts with its phase."""
+        ``full`` and under it ``qkv``, ``kernel``, ``out``, or ``latent``
+        and under it ``q_proj``, ``kv_proj``, ``kernel``, ``out``,
+        ``lm.ssm`` with ``in_proj``, ``conv``, ``scan``, ``gate_norm``,
+        ``out``, ``lm.mlp``, ``lm.moe``, ``lm.mtp``, ``lm.head_loss``) are
+        metadata: every op's ``op_name`` in a profile starts with its
+        phase."""
         import numpy as np
 
         order = self._ring_order()
@@ -863,6 +1050,25 @@ class BlockLM:
         (passes, S, dim): the passes are one ``lax.scan`` over the
         layers' walk, each closed by the final norm."""
         import jax
+
+        x, ropes = self._embedded(params, tokens, order)
+        if self.passes == 1:
+            x = self._walk(params["blocks"], x, ropes, taps)
+            with jax.named_scope("lm.head_loss"):
+                return self._rms(x, params["final_norm"])
+
+        def one_pass(x, _):
+            with jax.named_scope("lm.pass"):
+                x = self._rms(self._walk(params["blocks"], x, ropes, taps),
+                              params["final_norm"])
+            return x, x
+
+        return jax.lax.scan(one_pass, x, None, length=self.passes)[1]
+
+    def _embedded(self, params, tokens, order=None):
+        """The stream before the first layer, (S, dim), and the ropes'
+        tables at the rows' positions."""
+        import jax
         import jax.numpy as jnp
 
         S = self.max_seq
@@ -883,38 +1089,55 @@ class BlockLM:
             device_telemetry.passes_traced(self.passes, self.layers,
                                            self._recompute_label,
                                            self._kept_label)
-        if self.passes == 1:
-            x = self._walk(params["blocks"], x, ropes, taps)
-            with jax.named_scope("lm.head_loss"):
-                return self._rms(x, params["final_norm"])
-
-        def one_pass(x, _):
-            with jax.named_scope("lm.pass"):
-                x = self._rms(self._walk(params["blocks"], x, ropes, taps),
-                              params["final_norm"])
-            return x, x
-
-        return jax.lax.scan(one_pass, x, None, length=self.passes)[1]
+        return x, ropes
 
     def _walk(self, blocks, x, ropes, taps=None):
-        """The stream ``x`` (S, dim) through the layers once, in order;
-        with ``recompute="layer"`` each application under a
+        """The stream ``x`` (S, dim) through the layers once, in order."""
+        for spec, blk in zip(self.blocks, blocks):
+            x = self._apply_layer(spec, blk, x, ropes, taps)
+        return x
+
+    def _apply_layer(self, spec, blk, x, ropes, taps=None):
+        """One application of a layer; with ``recompute="layer"`` under a
         ``jax.checkpoint`` that keeps the flash kernel's two named
         results and nothing else."""
         import jax
 
         from fiber_tpu.ops.pallas_attention import KEPT_NAMES
 
-        keep = jax.checkpoint_policies.save_only_these_names(*KEPT_NAMES)
-        for spec, blk in zip(self.blocks, blocks):
-            def layer(blk, x, spec=spec):
-                return self._layer(spec, blk, x, ropes, taps)
+        def layer(blk, x):
+            return self._layer(spec, blk, x, ropes, taps)
 
-            if self.recompute == "layer" and taps is None:
-                # (``routing`` taps the expert layers: forward only)
-                layer = jax.checkpoint(layer, policy=keep)
-            x = layer(blk, x)
-        return x
+        if self.recompute == "layer" and taps is None:
+            # (``routing`` taps the expert layers: forward only)
+            keep = jax.checkpoint_policies.save_only_these_names(*KEPT_NAMES)
+            layer = jax.checkpoint(layer, policy=keep)
+        return layer(blk, x)
+
+    def _mtp_rows(self, params, tokens, taps=None):
+        """(the rows the head reads, (S, dim); the MTP module's, (S,
+        dim)): the main stack once, then the module on its stream before
+        the final norm (``MTP`` says how)."""
+        import jax
+        import jax.numpy as jnp
+
+        S = self.max_seq
+        x, ropes = self._embedded(params, tokens)
+        h = self._walk(params["blocks"], x, ropes, taps)
+        with jax.named_scope("lm.head_loss"):
+            rows = self._rms(h, params["final_norm"])
+        m = params["mtp"]
+        with jax.named_scope("lm.mtp"):
+            with jax.named_scope("embed_proj"):
+                # the embedding of token i + 1; the last row has none
+                e = jnp.where((jnp.arange(S) < S - 1)[:, None],
+                              params["embed"][jnp.roll(tokens, -1)], 0.0)
+                z = jnp.concatenate(
+                    [self._rms(e, m["enorm"]), self._rms(h, m["hnorm"])],
+                    axis=-1) @ m["eh_proj"]
+            z = self._apply_layer(self.mtp.block, m["block"], z, ropes, taps)
+            with jax.named_scope("head_loss"):
+                return rows, self._rms(z, m["norm"])
 
     def _layer(self, spec, blk, x, ropes, taps=None):
         """One layer on the stream ``x`` (S, dim)."""
@@ -924,6 +1147,8 @@ class BlockLM:
         mixed = None
         if spec.mixer == "ssm":
             mixed = self._ssm_mix(spec.ssm, blk, x)
+        elif spec.mixer == "latent":
+            mixed = self._latent_mix(spec, blk, x, ropes)
         elif spec.mixer == "attention":
             with jax.named_scope("lm.attn"), \
                     jax.named_scope(spec.attn_kind):
@@ -934,12 +1159,50 @@ class BlockLM:
                     k = k.reshape(S, KVH, Dh)
                     v = v.reshape(S, KVH, Dh)
                     if spec.rope is not None:
-                        q = self._rope_rotate(q, *ropes[spec.rope])
-                        k = self._rope_rotate(k, *ropes[spec.rope])
+                        turn = spec.rope.interleaved
+                        q = self._rope_rotate(q, *ropes[spec.rope], turn)
+                        k = self._rope_rotate(k, *ropes[spec.rope], turn)
                 with jax.named_scope("kernel"):
                     mixed = self._attend(
                         q, k, v, spec.window).reshape(S, -1)
         return self._block_tail(spec, blk, x, mixed, taps)
+
+    def _latent_mix(self, spec, blk, x, ropes):
+        """Latent attention (:class:`Latent`) on the stream ``x`` (S, dim)
+        -> the heads' outputs, flat (S, heads x v_dim), before their
+        out-projection."""
+        import jax
+        import jax.numpy as jnp
+
+        from fiber_tpu.telemetry import device as device_telemetry
+
+        a, H, S = spec.latent, spec.heads, self.max_seq
+        device_telemetry.latent_traced(H, a.q_rank, a.kv_rank, a.qk_dim,
+                                       a.v_dim)
+        cos, sin = ropes[self._rope_key(spec)]
+        turn = spec.rope.interleaved
+        with jax.named_scope("lm.attn"), jax.named_scope("latent"):
+            with jax.named_scope("q_proj"):
+                h = self._rms(x, blk["norm1"])
+                q = (self._rms(h @ blk["wq_a"], blk["q_norm"])
+                     @ blk["wq_b"]).reshape(S, H, a.qk_dim)
+                q = jnp.concatenate(
+                    [q[..., :a.nope],
+                     self._rope_rotate(q[..., a.nope:], cos, sin, turn)],
+                    axis=-1)
+            with jax.named_scope("kv_proj"):
+                c_kv, k_pe = jnp.split(h @ blk["wkv_a"], [a.kv_rank],
+                                       axis=-1)
+                kv = (self._rms(c_kv, blk["kv_norm"])
+                      @ blk["wkv_b"]).reshape(S, H, a.nope + a.v_dim)
+                # one rope key, shared by every head
+                k_pe = self._rope_rotate(k_pe[:, None, :], cos, sin, turn)
+                k = jnp.concatenate(
+                    [kv[..., :a.nope],
+                     jnp.broadcast_to(k_pe, (S, H, a.rope_dim))], axis=-1)
+                v = kv[..., a.nope:]
+            with jax.named_scope("kernel"):
+                return self._attend(q, k, v).reshape(S, H * a.v_dim)
 
     def routing(self, params, tokens):
         """For one sequence of tokens, what the expert layers' routers
@@ -952,7 +1215,10 @@ class BlockLM:
 
         taps = []
         order = self._ring_order()
-        self._forward(params, tokens, taps, order=order)
+        if self.mtp is not None:
+            self._mtp_rows(params, tokens, taps)
+        else:
+            self._forward(params, tokens, taps, order=order)
         if not taps:
             raise ValueError("the model has no expert layer")
         ids = jnp.stack([ids for ids, _ in taps])
@@ -979,11 +1245,25 @@ class BlockLM:
     def loss(self, params, tokens):
         """Mean next-token cross-entropy over positions 0..S-2; with an
         exit gate the expected-exit loss, per position ``sum_t p_t CE_t
-        - beta H(p)`` over the passes ``t``."""
+        - beta H(p)`` over the passes ``t``; with an MTP module plus its
+        weight times the MTP head's mean cross-entropy against the token
+        after next, over positions 0..S-3."""
         import jax
         import jax.numpy as jnp
         import numpy as np
 
+        if self.mtp is not None:
+            from fiber_tpu.telemetry import device as device_telemetry
+
+            device_telemetry.mtp_traced(self.mtp.depth, self.mtp.weight)
+            rows, extra = self._mtp_rows(params, tokens)
+            with jax.named_scope("lm.head_loss"):
+                main = jnp.mean(self._head_losses(
+                    rows, jnp.roll(tokens, -1), params["out"])[:-1])
+            with jax.named_scope("lm.mtp"), jax.named_scope("head_loss"):
+                after = jnp.mean(self._head_losses(
+                    extra, jnp.roll(tokens, -2), params["out"])[:-2])
+            return main + self.mtp.weight * after
         if self._loss_by_pass:
             ce, p = self.pass_losses(params, tokens)
             if p is None:
@@ -1161,8 +1441,9 @@ class BlockLM:
             if spec.rope is not None:
                 # Rotate q and k at THIS position; the cache stores
                 # post-rotation keys (standard RoPE decode).
-                q = self._rope_rotate(q, *ropes[spec.rope])
-                k = self._rope_rotate(k, *ropes[spec.rope])
+                turn = spec.rope.interleaved
+                q = self._rope_rotate(q, *ropes[spec.rope], turn)
+                k = self._rope_rotate(k, *ropes[spec.rope], turn)
             k_cache = cache["k"].at[pos].set(k)
             v_cache = cache["v"].at[pos].set(v.reshape(KVH, Dh))
             new_caches.append({"k": k_cache, "v": v_cache})
@@ -1194,6 +1475,11 @@ class BlockLM:
         exists to save); a state-space layer's state is float32."""
         import jax.numpy as jnp
 
+        if self._latent_or_mtp:
+            raise ValueError(
+                "latent layers and an MTP module do not decode yet: there "
+                "is no latent cache (c_kv and the rope key) and no draft "
+                "step (ROADMAP.md)")
         S, KVH, Dh = self.max_seq, self.kv_heads, self.head_dim
         caches = []
         for spec in self.blocks:

@@ -17,8 +17,13 @@ and gradients match the full-matrix reference to numerical tolerance,
 pinned by tests in interpret mode on CPU and, compiled through Mosaic,
 by ``chip_smoke.py`` on the chip.
 
+Values may be narrower or wider than queries and keys (latent attention
+attends over 192 features and returns 128): q and k are (S, H, Dqk), v and
+the output (S, H, Dv), and the scale is ``Dqk ** -0.5``. Where the two widths
+are equal the kernels are built as they always were.
+
 Under a checkpoint: the forward rules give the two residuals the kernel
-produced, ``out`` (S, H, D) and the compact ``lse`` (H, S), the names
+produced, ``out`` (S, H, Dv) and the compact ``lse`` (H, S), the names
 ``KEPT_NAMES`` (``jax.ad_checkpoint.checkpoint_name``); q, k and v get
 none. A ``jax.checkpoint`` whose policy saves those two names keeps
 them and recomputes q, k, v and all else, and then its backward pass
@@ -181,7 +186,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref,
     def _accumulate():
         q = q_ref[0].astype(jnp.float32)            # (block_q, d)
         k = k_ref[0].astype(jnp.float32)            # (block_kv, d)
-        v = v_ref[0].astype(jnp.float32)
+        v = v_ref[0].astype(jnp.float32)            # (block_kv, dv)
         s = jax.lax.dot_general(                     # (block_q, block_kv)
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
@@ -367,11 +372,13 @@ def flash_attention(q, k, v, *, causal: bool = False,
                     block_q: int = 512, block_kv: int = 512,
                     interpret: bool = False, window=None):
     """Exact attention, O(S) memory, differentiable. q:
-    (S, heads, head_dim); k, v: (S, kv_heads, head_dim) where kv_heads
+    (S, heads, head_dim); k: (S, kv_heads, head_dim) where kv_heads
     divides heads — kv_heads < heads is grouped-query attention (each
     group of heads/kv_heads query heads shares one KV head; the kernel
-    index maps do the sharing, so repeated KV never materializes).
-    Returns (S, heads, head_dim) in q's dtype.
+    index maps do the sharing, so repeated KV never materializes); v:
+    (S, kv_heads, v_dim), where the value width may differ from the
+    query/key width (latent attention). Returns (S, heads, v_dim) in
+    q's dtype; the scale is ``head_dim ** -0.5``.
 
     ``window`` (requires ``causal=True``) restricts every position to
     the last ``window`` tokens (self included): the kernels' grids hold
@@ -388,7 +395,8 @@ def flash_attention(q, k, v, *, causal: bool = False,
     (shape, dtype, flags).
     """
     fn = _build(q.shape, str(q.dtype), causal, block_q, block_kv,
-                interpret, _kv_heads_of(q, k), window, _kv_len_of(q, k))
+                interpret, _kv_heads_of(q, k), window, _kv_len_of(q, k),
+                _v_dim_of(q, v))
     return fn(q, k, v)
 
 
@@ -403,6 +411,13 @@ def _kv_len_of(q, k):
     zigzag ring attends all its rows to half a visiting block, and half
     its rows to a whole one)."""
     return None if k.shape[0] == q.shape[0] else k.shape[0]
+
+
+def _v_dim_of(q, v):
+    """None where values are as wide as queries and keys (cache-key
+    stability: the programs of equal widths are built as they always
+    were), else the value width."""
+    return None if v.shape[-1] == q.shape[-1] else v.shape[-1]
 
 
 def flash_attention_lse(q, k, v, *, causal: bool = False,
@@ -424,7 +439,7 @@ def flash_attention_lse(q, k, v, *, causal: bool = False,
     """
     fn = _build_lse(q.shape, str(q.dtype), causal, block_q, block_kv,
                     interpret, _kv_heads_of(q, k), window,
-                    _kv_len_of(q, k))
+                    _kv_len_of(q, k), _v_dim_of(q, v))
     return fn(q, k, v)
 
 
@@ -438,13 +453,14 @@ def flash_attention_lse_bwd(q, k, v, out, lse, dout, dlse, *,
     ring keeps each rotation's partial once, for its merge and for
     this); everyone else differentiates :func:`flash_attention_lse`."""
     _, bwd = _cores(q.shape, str(q.dtype), causal, block_q, block_kv,
-                    interpret, _kv_heads_of(q, k), None, _kv_len_of(q, k))
+                    interpret, _kv_heads_of(q, k), None, _kv_len_of(q, k),
+                    _v_dim_of(q, v))
     return bwd(q, k, v, out, lse, dout, dlse)
 
 
 @functools.lru_cache(maxsize=64)
 def _build_calls(shape, dtype, causal, block_q, block_kv, interpret,
-                 kv_heads=None, window=None, kv_len=None):
+                 kv_heads=None, window=None, kv_len=None, v_dim=None):
     """The three pallas_call programs (fwd, dq, dkv) for one config —
     shared by the out-only and the (out, lse) entry points.
 
@@ -463,7 +479,13 @@ def _build_calls(shape, dtype, causal, block_q, block_kv, interpret,
 
     ``kv_len`` is the number of keys where it is not the number of
     queries (non-causal only: the causal mask is in local coordinates
-    and takes row i for key i)."""
+    and takes row i for key i).
+
+    ``v_dim`` is the width of values and output where it is not that of
+    queries and keys (``shape[2]``): v, o and do travel in blocks of
+    that width, the forward's accumulator and dv's are that wide, and
+    the scale stays the query/key width's. None builds the programs of
+    one width exactly as before."""
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
@@ -493,6 +515,7 @@ def _build_calls(shape, dtype, causal, block_q, block_kv, interpret,
     n_q = s // bq
     n_kv = s_kv // bk
     scale = 1.0 / (d ** 0.5)
+    dv = d if v_dim is None else v_dim
 
     # Inner extents: the widest band any q-block (kv-block, for dkv)
     # has; the index maps name a band step's block as the kernels do.
@@ -512,9 +535,17 @@ def _build_calls(shape, dtype, causal, block_q, block_kv, interpret,
     def q_of(ik, j):
         return _band_block(j, _q_span(ik, bq, bk, n_q, causal, window))[0]
 
-    qkv_spec_q = pl.BlockSpec((1, bq, d), lambda ih, iq, j: (ih, iq, 0))
-    qkv_spec_k = pl.BlockSpec(
-        (1, bk, d), lambda ih, iq, j: (ih // group, kv_of(iq, j), 0))
+    def q_rows(width):
+        return pl.BlockSpec((1, bq, width), lambda ih, iq, j: (ih, iq, 0))
+
+    def kv_rows(width):
+        return pl.BlockSpec(
+            (1, bk, width),
+            lambda ih, iq, j: (ih // group, kv_of(iq, j), 0))
+
+    qkv_spec_q, qkv_spec_k = q_rows(d), kv_rows(d)
+    o_spec, v_spec = ((qkv_spec_q, qkv_spec_k) if dv == d
+                      else (q_rows(dv), kv_rows(dv)))
     # Per-row statistics (lse, delta) travel as (h, s, 1) columns:
     # Mosaic wants the last two block dims divisible by (8, 128) or
     # equal to the array's, which a (1, bq) row block of an (h, s)
@@ -526,14 +557,14 @@ def _build_calls(shape, dtype, causal, block_q, block_kv, interpret,
                           n_band=n_band_kv, causal=causal, scale=scale,
                           window=window),
         grid=(h, n_q, n_band_kv),
-        in_specs=[qkv_spec_q, qkv_spec_k, qkv_spec_k],
-        out_specs=[qkv_spec_q, row_spec_q],
-        out_shape=[jax.ShapeDtypeStruct((h, s, d), dtype),
+        in_specs=[qkv_spec_q, qkv_spec_k, v_spec],
+        out_specs=[o_spec, row_spec_q],
+        out_shape=[jax.ShapeDtypeStruct((h, s, dv), dtype),
                    jax.ShapeDtypeStruct((h, s, 1), jnp.float32)],
         scratch_shapes=[
             pltpu.VMEM((bq, 1), jnp.float32),    # running max m
             pltpu.VMEM((bq, 1), jnp.float32),    # denominator l
-            pltpu.VMEM((bq, d), jnp.float32),    # numerator acc
+            pltpu.VMEM((bq, dv), jnp.float32),   # numerator acc
         ],
         interpret=interpret,
         name="flash_attn_fwd",
@@ -544,7 +575,7 @@ def _build_calls(shape, dtype, causal, block_q, block_kv, interpret,
                           n_band=n_band_kv, causal=causal, scale=scale,
                           window=window),
         grid=(h, n_q, n_band_kv),
-        in_specs=[qkv_spec_q, qkv_spec_k, qkv_spec_k, qkv_spec_q,
+        in_specs=[qkv_spec_q, qkv_spec_k, v_spec, o_spec,
                   row_spec_q, row_spec_q],
         out_specs=qkv_spec_q,
         out_shape=jax.ShapeDtypeStruct((h, s, d), dtype),
@@ -556,11 +587,18 @@ def _build_calls(shape, dtype, causal, block_q, block_kv, interpret,
     # dkv grid is (kv_heads, n_kv, group, n_band_q): program ids land as
     # (ikv, ik, g, j); (g, j) innermost so each (ikv, ik) output
     # block's revisits are contiguous.
-    dkv_q_spec = pl.BlockSpec(
-        (1, bq, d),
-        lambda ikv, ik, g, j: (ikv * group + g, q_of(ik, j), 0))
-    dkv_k_spec = pl.BlockSpec(
-        (1, bk, d), lambda ikv, ik, g, j: (ikv, ik, 0))
+    def dkv_q_rows(width):
+        return pl.BlockSpec(
+            (1, bq, width),
+            lambda ikv, ik, g, j: (ikv * group + g, q_of(ik, j), 0))
+
+    def dkv_k_rows(width):
+        return pl.BlockSpec(
+            (1, bk, width), lambda ikv, ik, g, j: (ikv, ik, 0))
+
+    dkv_q_spec, dkv_k_spec = dkv_q_rows(d), dkv_k_rows(d)
+    dkv_do_spec, dkv_v_spec = ((dkv_q_spec, dkv_k_spec) if dv == d
+                               else (dkv_q_rows(dv), dkv_k_rows(dv)))
     dkv_row_spec = pl.BlockSpec(
         (1, bq, 1),
         lambda ikv, ik, g, j: (ikv * group + g, q_of(ik, j), 0))
@@ -569,13 +607,13 @@ def _build_calls(shape, dtype, causal, block_q, block_kv, interpret,
                           n_q=n_q, n_band=n_band_q, group=group,
                           causal=causal, scale=scale, window=window),
         grid=(kvh, n_kv, group, n_band_q),
-        in_specs=[dkv_q_spec, dkv_k_spec, dkv_k_spec, dkv_q_spec,
+        in_specs=[dkv_q_spec, dkv_k_spec, dkv_v_spec, dkv_do_spec,
                   dkv_row_spec, dkv_row_spec],
-        out_specs=[dkv_k_spec, dkv_k_spec],
+        out_specs=[dkv_k_spec, dkv_v_spec],
         out_shape=[jax.ShapeDtypeStruct((kvh, s_kv, d), dtype),
-                   jax.ShapeDtypeStruct((kvh, s_kv, d), dtype)],
+                   jax.ShapeDtypeStruct((kvh, s_kv, dv), dtype)],
         scratch_shapes=[pltpu.VMEM((bk, d), jnp.float32),
-                        pltpu.VMEM((bk, d), jnp.float32)],
+                        pltpu.VMEM((bk, dv), jnp.float32)],
         interpret=interpret,
         name="flash_attn_dkv",
     )
@@ -584,7 +622,7 @@ def _build_calls(shape, dtype, causal, block_q, block_kv, interpret,
 
 @functools.lru_cache(maxsize=64)
 def _cores(shape, dtype, causal, block_q, block_kv, interpret,
-           kv_heads=None, window=None, kv_len=None):
+           kv_heads=None, window=None, kv_len=None, v_dim=None):
     """(forward, backward) over the public (S, H, D) layout for one
     config: ``forward(q, k, v) -> (out, lse)`` and ``backward(q, k, v,
     out, lse, dout, dlse) -> (dq, dk, dv)``."""
@@ -592,7 +630,7 @@ def _cores(shape, dtype, causal, block_q, block_kv, interpret,
 
     fwd_call, dq_call, dkv_call = _build_calls(
         shape, dtype, causal, block_q, block_kv, interpret, kv_heads,
-        window, kv_len)
+        window, kv_len, v_dim)
 
     def _fwd_core(q, k, v):
         """(S,H,D) API -> (H,S,D) kernels and back; the kernel's
@@ -621,13 +659,14 @@ def _cores(shape, dtype, causal, block_q, block_kv, interpret,
 
 
 def _make_attn(shape, dtype, causal, block_q, block_kv, interpret,
-               with_lse: bool, kv_heads=None, window=None, kv_len=None):
+               with_lse: bool, kv_heads=None, window=None, kv_len=None,
+               v_dim=None):
     import jax
     from jax.ad_checkpoint import checkpoint_name
 
     _fwd_core, _bwd_core = _cores(
         shape, dtype, causal, block_q, block_kv, interpret, kv_heads,
-        window, kv_len)
+        window, kv_len, v_dim)
 
     def _named_fwd(q, k, v):
         """The forward rules' kernel call: the two residuals the kernel
@@ -672,15 +711,15 @@ def _make_attn(shape, dtype, causal, block_q, block_kv, interpret,
 
 @functools.lru_cache(maxsize=64)
 def _build(shape, dtype, causal, block_q, block_kv, interpret,
-           kv_heads=None, window=None, kv_len=None):
+           kv_heads=None, window=None, kv_len=None, v_dim=None):
     return _make_attn(shape, dtype, causal, block_q, block_kv,
                       interpret, with_lse=False, kv_heads=kv_heads,
-                      window=window, kv_len=kv_len)
+                      window=window, kv_len=kv_len, v_dim=v_dim)
 
 
 @functools.lru_cache(maxsize=64)
 def _build_lse(shape, dtype, causal, block_q, block_kv, interpret,
-               kv_heads=None, window=None, kv_len=None):
+               kv_heads=None, window=None, kv_len=None, v_dim=None):
     return _make_attn(shape, dtype, causal, block_q, block_kv,
                       interpret, with_lse=True, kv_heads=kv_heads,
-                      window=window, kv_len=kv_len)
+                      window=window, kv_len=kv_len, v_dim=v_dim)

@@ -117,6 +117,14 @@ _m_passes_traces = telemetry.counter(
     "than once or whose step recomputes, by passes over the stack, "
     "layers in it, what the backward pass recomputes and what it keeps "
     "of a layer application")
+_m_latent_traces = telemetry.counter(
+    "latent_layers_traced",
+    "Latent-attention (MLA) layer applications traced, by heads, the "
+    "query and key-value ranks and the query/key and value widths")
+_m_mtp_traces = telemetry.counter(
+    "mtp_traced",
+    "Losses traced with a multi-token-prediction term, by the module's "
+    "depth and the term's weight")
 _m_flash_grid_steps = telemetry.counter(
     "flash_grid_steps",
     "Inner grid steps a head makes in the flash-attention programs "
@@ -926,6 +934,25 @@ def passes_traced(passes: int, layers: int, recompute: str,
     forward moves it once whatever ``passes`` and ``recompute`` are."""
     _m_passes_traces.inc(passes=str(passes), layers=str(layers),
                          recompute=recompute, kept=kept)
+
+
+def latent_traced(heads: int, q_rank: int, kv_rank: int, qk_dim: int,
+                  v_dim: int) -> None:
+    """One application of a latent-attention layer was traced
+    (``models/transformer.py``; the MTP module's layer too). Counts
+    traces, not calls, like ``passes_traced``: a recomputed layer
+    replays its traced equations, so one traced step moves it once a
+    layer application."""
+    _m_latent_traces.inc(heads=str(heads), q_rank=str(q_rank),
+                         kv_rank=str(kv_rank), qk_dim=str(qk_dim),
+                         v_dim=str(v_dim))
+
+
+def mtp_traced(depth: int, weight: float) -> None:
+    """One loss with a multi-token-prediction term was traced
+    (``BlockLM.loss``). Counts traces, not calls: an operator reads off
+    it that the compiled step trains the module, and at which weight."""
+    _m_mtp_traces.inc(depth=str(depth), weight=f"{weight:g}")
 
 
 def flash_grid_built(kernel: str, run: int, idle: int) -> None:

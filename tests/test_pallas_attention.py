@@ -1282,3 +1282,84 @@ def test_kernels_raise_off_tpu_instead_of_interpreting():
     assert TinyLM(attention="flash", interpret=True).interpret is True
     # planes with no kernel in them are unaffected
     assert TinyLM(attention="ring").interpret is False
+
+
+# -- a value width of its own (latent attention: q, k at 192, v at 128) -------
+def _rand_qkv_widths(s, h, dqk, dv):
+    kq, kk, kv = jax.random.split(jax.random.PRNGKey(17), 3)
+    return (jax.random.normal(kq, (s, h, dqk)),
+            jax.random.normal(kk, (s, h, dqk)),
+            jax.random.normal(kv, (s, h, dv)))
+
+
+@pytest.mark.parametrize("with_lse", [False, True])
+@pytest.mark.parametrize("causal", [False, True])
+def test_flash_value_width_of_its_own(causal, with_lse):
+    """q and k 48 wide, v 32: the output is (S, H, 32), scaled by 48^-0.5,
+    and the output, the lse and all three gradients (an lse cotangent
+    too) equal plain attention's, over several blocks each way."""
+    from fiber_tpu.ops.pallas_attention import flash_attention_lse
+
+    q, k, v = _rand_qkv_widths(256, 2, 48, 32)
+    keys = jax.random.split(jax.random.PRNGKey(19), 2)
+    tgt = jax.random.normal(keys[0], (256, 2, 32))
+    w_lse = jax.random.normal(keys[1], (2, 256))
+
+    def plain(q, k, v):
+        s = jnp.einsum("qhd,khd->hqk", q, k) / np.sqrt(48.0)
+        if causal:
+            keep = jnp.arange(256)[:, None] >= jnp.arange(256)[None, :]
+            s = jnp.where(keep[None], s, -jnp.inf)
+        return (jnp.einsum("hqk,khd->qhd", jax.nn.softmax(s, axis=-1), v),
+                jax.nn.logsumexp(s, axis=-1))
+
+    def flash(q, k, v):
+        kw = dict(causal=causal, block_q=128, block_kv=64, interpret=True)
+        if with_lse:
+            return flash_attention_lse(q, k, v, **kw)
+        return flash_attention(q, k, v, **kw), None
+
+    def loss(attend):
+        def f(q, k, v):
+            out, lse = attend(q, k, v)
+            total = jnp.sum((out - tgt) ** 2)
+            return total + (jnp.sum(w_lse * lse) if with_lse else 0.0)
+        return f
+
+    out, lse = flash(q, k, v)
+    want_out, want_lse = plain(q, k, v)
+    assert out.shape == (256, 2, 32)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want_out),
+                               atol=2e-5)
+    if with_lse:
+        np.testing.assert_allclose(np.asarray(lse), np.asarray(want_lse),
+                                   atol=2e-5)
+    got = jax.grad(loss(flash), argnums=(0, 1, 2))(q, k, v)
+    want = jax.grad(loss(plain), argnums=(0, 1, 2))(q, k, v)
+    for name, a, b in zip("qkv", got, want):
+        assert a.shape == b.shape, name
+        rel = np.abs(np.asarray(a - b)).max() / np.abs(np.asarray(b)).max()
+        assert rel < 1e-4, (name, rel)
+
+
+def test_flash_equal_widths_keep_their_cache_key(monkeypatch):
+    """A call whose values are as wide as its queries hands the builder
+    None for the value width (as MHA hands it None for kv_heads), so its
+    programs are the ones one width always built; another width is
+    named."""
+    from fiber_tpu.ops import pallas_attention as pa
+
+    seen = []
+    real = pa._build
+
+    def build(*key):
+        seen.append(key)
+        return real(*key)
+
+    monkeypatch.setattr(pa, "_build", build)
+    q, k, v = _rand_qkv(128, 2, 32)
+    pa.flash_attention(q, k, v, causal=True, interpret=True)
+    pa.flash_attention(q, k, v[..., :16], causal=True, interpret=True)
+    assert seen[0] == ((128, 2, 32), "float32", True, 512, 512, True,
+                       None, None, None, None)
+    assert seen[1][:-1] == seen[0][:-1] and seen[1][-1] == 16

@@ -1318,8 +1318,9 @@ class BlockLM:
         """For one sequence of tokens, what the expert layers' routers
         decide: ``{"ids": (expert layers, S, top_k) int32, the experts
         each token takes (of all ``total``); "load": (expert layers,
-        held) int32, the tokens each held expert gets}``. A whole
-        forward pass; for set-up and checks, not for the step."""
+        held) int32, the tokens each held expert gets; "chunks": (expert
+        layers,) int32, the chunks each layer's dispatch walk makes}``.
+        A whole forward pass; for set-up and checks, not for the step."""
         import jax.numpy as jnp
         import numpy as np
 
@@ -1331,17 +1332,19 @@ class BlockLM:
             self._forward(params, tokens, taps, order=order)
         if not taps:
             raise ValueError("the model has no expert layer")
-        ids = jnp.stack([ids for ids, _ in taps])
+        ids = jnp.stack([ids for ids, _, _ in taps])
         if order is not None:
             ids = ids[:, np.argsort(order)]
         return {"ids": ids,
-                "load": jnp.stack([load for _, load in taps])}
+                "load": jnp.stack([load for _, load, _ in taps]),
+                "chunks": jnp.stack([chunks for _, _, chunks in taps])}
 
     def probe_routing(self, params, tokens) -> dict:
         """``routing`` jitted and fetched to the host (numpy arrays),
         with each held expert's load recorded in the registry's
-        ``moe_expert_load_max`` / ``moe_expert_load_mean`` gauges, by
-        expert layer. Host-side; call it outside a timed loop."""
+        ``moe_expert_load_max`` / ``moe_expert_load_mean`` gauges and the
+        dispatch walk's chunks in ``moe_chunks``, by expert layer.
+        Host-side; call it outside a timed loop."""
         import jax
 
         from fiber_tpu.telemetry import device as device_telemetry
@@ -1350,6 +1353,7 @@ class BlockLM:
             self._probe = jax.jit(self.routing)
         found = jax.device_get(self._probe(params, tokens))
         device_telemetry.moe_load(found["load"])
+        device_telemetry.moe_chunks(found["chunks"])
         return found
 
     def loss(self, params, tokens):

@@ -21,7 +21,11 @@ the chunk and time follows the pairs; the worst case (every token on a
 held expert) only makes the loop longer. The backward pass is written
 out (``jax.custom_vjp``) as the same walk over the forward pass's
 sorted pairs, recomputing each chunk, so no residual grows with the
-chunk count either.
+chunk count either. It computes the first chunk on its own, where any
+pair is held at all, and that chunk's gradients start the loop over the
+others: the matrices' gradients are never a zero-filled array that the
+first chunk's are added to, and where one chunk holds every pair the
+loop makes no trip; where none is held, no product runs.
 """
 
 from __future__ import annotations
@@ -160,6 +164,16 @@ def _chunk_experts(x, weight, valid, sizes, mats, kind: str = "swiglu"):
         return jnp.where(keep, down * weight[:, None], 0.0)
 
 
+def _walk_rows(chunk_rows: int, ids) -> int:
+    """Rows of a chunk of the walk over ``ids``' (token, expert) pairs."""
+    return min(chunk_rows, ids.size)
+
+
+def _chunks(pairs, rows: int):
+    """Chunks the walk makes over ``pairs`` held pairs."""
+    return (pairs + rows - 1) // rows
+
+
 def routed_experts(h, ids, weights, *mats, first: int, chunk_rows: int,
                    kind: str = "swiglu"):
     """``sum over the taken experts e held here of weights_e *
@@ -174,13 +188,16 @@ def routed_experts(h, ids, weights, *mats, first: int, chunk_rows: int,
 @functools.lru_cache(maxsize=None)
 def _routed(first: int, chunk_rows: int, kind: str = "swiglu"):
     """The differentiable walk for one (first held expert, chunk, expert
-    kind)."""
+    kind). The backward pass computes the first chunk on its own and
+    starts its loop over the other chunks from that chunk's results: the
+    rows' gradient is its scatter, the matrices' gradients are its own,
+    with no zeros to add them to."""
     import jax
     import jax.numpy as jnp
 
     def fwd(h, ids, weights, *mats):
         top_k = ids.shape[1]
-        rows = min(chunk_rows, ids.size)
+        rows = _walk_rows(chunk_rows, ids)
         with jax.named_scope("dispatch"):
             order, ends, pairs = _plan(ids, first, mats[0].shape[0], rows)
         flat_w = weights.reshape(-1)
@@ -194,18 +211,19 @@ def _routed(first: int, chunk_rows: int, kind: str = "swiglu"):
             with jax.named_scope("combine"):
                 return out.at[token].add(y)
 
-        out = jax.lax.fori_loop(0, (pairs + rows - 1) // rows, body,
+        out = jax.lax.fori_loop(0, _chunks(pairs, rows), body,
                                 jnp.zeros_like(h))
         return out, (h, weights, mats, order, ends, pairs)
 
     def bwd(res, d_out):
         h, weights, mats, order, ends, pairs = res
         top_k = weights.shape[1]
-        rows = min(chunk_rows, weights.size)
+        rows = _walk_rows(chunk_rows, weights)
         flat_w = weights.reshape(-1)
 
-        def body(c, carry):
-            d_h, d_w, d_mats = carry
+        # traced once for the first chunk and the loop's body both
+        @functools.partial(jax.jit, inline=True)
+        def chunk(c, h, d_out, flat_w, order, ends, pairs, mats):
             with jax.named_scope("dispatch"):
                 pair, token, valid, sizes = _chunk_of(
                     c, order, ends, pairs, rows, top_k)
@@ -214,15 +232,32 @@ def _routed(first: int, chunk_rows: int, kind: str = "swiglu"):
                 lambda x, w, *m: _chunk_experts(x, w, valid, sizes, m, kind),
                 x, flat_w[pair], *mats)
             d_x, d_row, *g_mats = vjp(d_y)
+            return token, d_x, d_row, tuple(g_mats)
+
+        walked = (h, d_out, flat_w, order, ends, pairs, mats)
+
+        def body(c, carry):
+            d_h, d_w, d_mats = carry
+            token, d_x, d_row, g_mats = chunk(c, *walked)
             with jax.named_scope("combine"):
                 d_h = d_h.at[token].add(d_x)
                 d_w = jax.lax.dynamic_update_slice(d_w, d_row, (c * rows,))
             return d_h, d_w, tuple(d + g for d, g in zip(d_mats, g_mats))
 
-        d_h, d_w, d_mats = jax.lax.fori_loop(
-            0, (pairs + rows - 1) // rows, body,
-            (jnp.zeros_like(h), jnp.zeros(order.shape, weights.dtype),
-             tuple(jnp.zeros_like(m) for m in mats)))
+        def walk():
+            token, d_x, d_row, g_mats = chunk(0, *walked)
+            with jax.named_scope("combine"):
+                d_h = jnp.zeros_like(h).at[token].add(d_x)
+                d_w = jnp.zeros(order.shape, weights.dtype).at[:rows].set(
+                    d_row)
+            return jax.lax.fori_loop(1, _chunks(pairs, rows), body,
+                                     (d_h, d_w, g_mats))
+
+        def empty():
+            return (jnp.zeros_like(h), jnp.zeros(order.shape, weights.dtype),
+                    tuple(jnp.zeros_like(m) for m in mats))
+
+        d_h, d_w, d_mats = jax.lax.cond(pairs > 0, walk, empty)
         with jax.named_scope("combine"):
             # back from sorted order to (token, slot); rows past the held
             # pairs were never written and stay 0 (the padding of
@@ -262,20 +297,22 @@ def moe_ffn(h, blk, *, total: int, top_k: int, scale: float,
     no ``shared_*`` leaf, and its result is the routed part alone, with
     no product and no sum for the shared one. ``renorm_eps`` is
     ``route``'s ``eps``. ``taps``, a list, is given (taken ids, load of
-    each held expert)."""
+    each held expert, chunks the walk makes over the held pairs)."""
     import jax
 
     from fiber_tpu.telemetry import device as device_telemetry
 
     names = EXPERT_MATRICES[kind]
     count = blk["experts_wd"].shape[0]
-    device_telemetry.moe_traced(count, total, top_k)
+    device_telemetry.moe_traced(count, total, top_k, walk="first+loop")
     with jax.named_scope("lm.moe"):
         with jax.named_scope("router"):
             ids, weights = route(h, blk["router"], top_k=top_k, scale=scale,
                                  eps=renorm_eps)
         if taps is not None:
-            taps.append((ids, expert_load(ids, first, count)))
+            load = expert_load(ids, first, count)
+            taps.append((ids, load, _chunks(
+                load.sum(), _walk_rows(chunk_rows, ids))))
         routed = routed_experts(
             h, ids, weights, *(blk["experts_" + m] for m in names),
             first=first, chunk_rows=chunk_rows, kind=kind)

@@ -105,7 +105,9 @@ _m_rollout_traces = telemetry.counter(
 _m_moe_traces = telemetry.counter(
     "moe_layers_traced",
     "Sparse-expert layers traced, by experts held here, experts in "
-    "all and experts a token takes")
+    "all, experts a token takes and the form of the backward pass's "
+    "dispatch walk ('first+loop': the first chunk on its own, a loop "
+    "over the rest)")
 _m_ssm_traces = telemetry.counter(
     "ssm_layers_traced",
     "State-space layers traced, by heads, state size, groups, chunk, "
@@ -149,6 +151,11 @@ _g_moe_load_mean = telemetry.gauge(
     "moe_expert_load_mean",
     "Tokens of the last probed batch on a held expert, mean over the "
     "held experts, by expert layer")
+_g_moe_chunks = telemetry.gauge(
+    "moe_chunks",
+    "Chunks the dispatch walks make over the held pairs of the last "
+    "probed batch, by expert layer: at 1 the backward pass's loop over "
+    "the chunks after the first makes no trip")
 _g_hbm_in_use = telemetry.gauge(
     "device_hbm_bytes_in_use", "HBM bytes in use on the first local device")
 _g_hbm_limit = telemetry.gauge(
@@ -902,11 +909,13 @@ def rollout_traced(policy: str, params: str) -> None:
     _m_rollout_traces.inc(policy=policy, params=params)
 
 
-def moe_traced(held: int, total: int, top_k: int) -> None:
+def moe_traced(held: int, total: int, top_k: int, walk: str) -> None:
     """One sparse-expert layer was traced (``ops/moe.py``). Counts
     traces, not calls, like ``rollout_traced``: an operator reads off it
-    which share of the experts the compiled program holds."""
-    _m_moe_traces.inc(held=str(held), total=str(total), top_k=str(top_k))
+    which share of the experts the compiled program holds, and the form
+    its backward pass's dispatch walks in (``walk``)."""
+    _m_moe_traces.inc(held=str(held), total=str(total), top_k=str(top_k),
+                      walk=walk)
 
 
 def ssm_traced(heads: int, state: int, groups: int, chunk: int,
@@ -1005,6 +1014,13 @@ def moe_load(loads) -> None:
         load = [float(x) for x in load]
         _g_moe_load_max.set(max(load), layer=str(layer))
         _g_moe_load_mean.set(sum(load) / len(load), layer=str(layer))
+
+
+def moe_chunks(chunks) -> None:
+    """The chunks each expert layer's dispatch walk makes on one probed
+    batch (``BlockLM.probe_routing``): ``chunks[layer]``."""
+    for layer, walked in enumerate(chunks):
+        _g_moe_chunks.set(float(walked), layer=str(layer))
 
 
 def snapshot() -> Dict[str, Any]:

@@ -307,6 +307,41 @@ def test_the_shares_parts_add_up_to_the_uncut_layer(shares):
                                rtol=2e-5, atol=2e-5)
 
 
+@pytest.mark.parametrize("chunk_rows", [256, 255])
+def test_an_ungated_layer_at_the_first_chunks_edge_follows_the_reference(
+        chunk_rows):
+    """Every expert held, so all 256 (token, expert) pairs are held: one
+    chunk that holds them all, and one pair more than the first chunk
+    holds. The layer and the gradients of its rows, router and matrices
+    are the reference's."""
+    total, d = 16, DIM
+    k = jax.random.split(jax.random.PRNGKey(3), 6)
+    blk = {"h": jax.random.normal(k[0], (S, d)),
+           "router": jax.random.normal(k[1], (d, total)),
+           "shared_wu": 0.2 * jax.random.normal(k[2], (d, 24)),
+           "shared_wd": 0.2 * jax.random.normal(k[3], (24, d)),
+           "experts_wu": 0.2 * jax.random.normal(k[4], (total, d, 8)),
+           "experts_wd": 0.2 * jax.random.normal(k[5], (total, 8, d))}
+
+    def ours(b):
+        return jnp.sum(moe.moe_ffn(
+            b["h"], b, total=total, top_k=4, scale=2.5, first=0,
+            chunk_rows=chunk_rows, kind="relu2") ** 2)
+
+    def theirs(b):
+        return jnp.sum(ref.expert_layer(b["h"], b, EXPERTS, (0, 1))[0] ** 2)
+
+    with jax.default_matmul_precision("highest"):
+        value, grads = jax.value_and_grad(ours)(blk)
+        want, their_grads = jax.value_and_grad(theirs)(blk)
+    assert float(value) == pytest.approx(float(want), rel=2e-5)
+    for name, g in grads.items():
+        top = float(jnp.max(jnp.abs(their_grads[name])))
+        np.testing.assert_allclose(np.asarray(g),
+                                   np.asarray(their_grads[name]), rtol=0,
+                                   atol=2e-5 * top, err_msg=name)
+
+
 def test_the_ungated_expert_is_relu_squared():
     h = jnp.asarray([[1.0, -2.0]])
     wu = jnp.asarray([[1.0, -1.0, 2.0], [0.5, 1.0, 0.0]])

@@ -222,11 +222,13 @@ def rigged(blk, experts):
                 * jnp.ones((DIM, 1)))
 
 
-@pytest.mark.parametrize("chunk_rows", [16, 32, 4096])
+@pytest.mark.parametrize("chunk_rows", [16, 32, 4096, 256, 255])
 def test_no_token_is_dropped_whatever_the_routing(chunk_rows):
-    """Every token on held experts (all S x top_k pairs here, many chunks),
-    no token on a held expert (no pair, no chunk), and fresh weights: one
-    compiled layer serves all three and equals the reference."""
+    """Every token on held experts (all S x top_k pairs here, many chunks;
+    at 256 rows exactly one chunk's worth, at 255 one pair more than the
+    first chunk holds), no token on a held expert (no pair, no chunk), and
+    fresh weights: one compiled layer serves all three and equals the
+    reference."""
     blk = layer_weights(jax.random.PRNGKey(2))
     share = (0, 2)                                   # holds experts 0..7
     cases = {"all": rigged(blk, (0, 1, 2, 3)),
@@ -261,6 +263,96 @@ def test_no_token_is_dropped_whatever_the_routing(chunk_rows):
         scale = max(float(np.abs(g).max()) for g in leaves(ref_grads).values())
         assert_trees_close(grads, ref_grads, rtol=1e-4, atol=1e-5 * scale)
     assert value_and_grads._cache_size() == 1
+
+
+def _eqns_under(jaxpr, within=()):
+    """Every equation of ``jaxpr`` and of the programs nested in it, with
+    the primitives it lies under: ``(eqn, (outer primitive, ...))``. A
+    ``cond`` branch is named ``cond/<n>`` and a loop's body ``while/body``."""
+    for eqn in jaxpr.eqns:
+        yield eqn, within
+        for key, value in eqn.params.items():
+            inner = value if isinstance(value, (tuple, list)) else [value]
+            for n, sub in enumerate(inner):
+                sub = getattr(sub, "jaxpr", sub)
+                if not hasattr(sub, "eqns"):
+                    continue
+                name = eqn.primitive.name
+                if name == "cond":
+                    name = f"cond/{n}"
+                elif name == "while":
+                    name = "while/" + key.split("_")[0]
+                yield from _eqns_under(sub, within + (name,))
+
+
+@pytest.mark.parametrize("kind", ["swiglu", "relu2"])
+def test_the_first_chunk_seeds_the_weight_gradients(kind):
+    """One chunk that holds every pair: the gradient's program forms a
+    zero-filled matrix of the experts' shapes only in the branch that no
+    held pair takes, and adds such matrices only in the loop over the
+    later chunks, which this layer never enters."""
+    blk = layer_weights(jax.random.PRNGKey(5))
+    names = moe.EXPERT_MATRICES[kind]
+    mats = {n: blk["experts_" + n][:8] for n in names}
+    ids, weights = moe.route(blk["h"], blk["router"], top_k=4)
+
+    def loss(mats):
+        return jnp.sum(moe.routed_experts(
+            blk["h"], ids, weights, *(mats[n] for n in names), first=0,
+            chunk_rows=4096, kind=kind) ** 2)
+
+    jaxpr = jax.make_jaxpr(jax.grad(loss))(mats).jaxpr
+    shapes = {m.shape for m in mats.values()}
+    filled, added = [], []
+    for eqn, within in _eqns_under(jaxpr):
+        shape = getattr(eqn.outvars[0].aval, "shape", None)
+        if shape not in shapes:
+            continue
+        if eqn.primitive.name == "broadcast_in_dim":
+            filled.append(within)
+        elif eqn.primitive.name in ("add", "add_any"):
+            added.append(within)
+    # zeros only where no pair is held: one cond branch, no product in it
+    assert len(filled) == len(names)
+    assert all(w and w[-1].startswith("cond/") for w in filled)
+    assert added and all("while/body" in w for w in added)
+    # the first chunk's products lie outside any loop
+    outside = [w for e, w in _eqns_under(jaxpr)
+               if e.primitive.name.startswith("ragged_dot")
+               and "while/body" not in w]
+    assert outside and all(any(p.startswith("cond/") for p in w)
+                           for w in outside)
+
+
+def test_a_layer_with_no_held_pair_runs_no_product(monkeypatch):
+    """No token on a held expert: neither direction runs a grouped
+    product. With pairs, every chunk runs its three forward products once
+    in each direction (the backward pass recomputes them; the gradients'
+    products are their pullbacks), the first chunk too."""
+    real, ran = moe._grouped, []
+
+    def counted(rows, matrices, sizes):
+        jax.debug.callback(lambda: ran.append(1))
+        return real(rows, matrices, sizes)
+
+    monkeypatch.setattr(moe, "_grouped", counted)
+    moe._routed.cache_clear()
+    blk = layer_weights(jax.random.PRNGKey(2))
+
+    @jax.jit
+    def value_and_grads(blk):
+        return jax.value_and_grad(lambda b: jnp.sum(layer_of(
+            dict(blk, **b), (0, 2), chunk_rows=32) ** 2))(
+            {k: blk[k] for k in blk if k != "router"})
+
+    try:
+        for name, case, chunks in [("none", rigged(blk, (8, 9, 10, 11)), 0),
+                                   ("all", rigged(blk, (0, 1, 2, 3)), 8)]:
+            ran.clear()
+            jax.block_until_ready(value_and_grads(case))
+            assert len(ran) == 6 * chunks, name
+    finally:
+        moe._routed.cache_clear()
 
 
 def test_rows_that_no_group_owns_are_never_read(monkeypatch):
@@ -546,7 +638,7 @@ def test_span_fields_counter_and_load_gauges():
     fiber_tpu.init()
     model, step, params, state = _step_and_state(donate=False)
     counter = telemetry.counter("moe_layers_traced")
-    labels = dict(held="8", total="16", top_k="4")
+    labels = dict(held="8", total="16", top_k="4", walk="first+loop")
     before = counter.value(**labels)
     tracing.SPANS.clear()
     tokens = tokens_of(3)
@@ -564,6 +656,11 @@ def test_span_fields_counter_and_load_gauges():
             layer=str(layer)) == found["load"][layer].max()
         assert telemetry.gauge("moe_expert_load_mean").value(
             layer=str(layer)) == pytest.approx(found["load"][layer].mean())
+        # the walk's chunks: ceil(held pairs / 32 rows)
+        pairs = int(found["load"][layer].sum())
+        assert found["chunks"][layer] == -(-pairs // 32) > 1
+        assert telemetry.gauge("moe_chunks").value(
+            layer=str(layer)) == found["chunks"][layer]
 
 
 def test_scopes_of_the_expert_layer_reach_the_lowered_program():

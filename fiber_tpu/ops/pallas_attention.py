@@ -138,40 +138,118 @@ def _band_block(j, span):
     return lax.min(at, last), lax.le(at, last)
 
 
-def _band_extents(n_q, n_kv, block_q, block_kv, causal, window):
-    """(widest kv span of a q-block, widest q span of a kv-block,
-    block pairs that run), from `_run_window` itself over all pairs."""
+def _grid_extents(n_q, n_kv, block_q, block_kv, causal, window):
+    """(fold of the q-blocks' rows, fold of the kv-blocks' rows, block
+    pairs that run), from `_run_window` itself over all pairs. A fold is
+    `_fold_of` the blocks each row runs, its triangle first: the
+    q-blocks in order, the kv-blocks from the last."""
     import numpy as np
 
     if not causal:
-        return n_kv, n_q, n_q * n_kv
+        return (0, 0, n_kv), (0, 0, n_q), n_q * n_kv
     run = _run_window(np.arange(n_q)[:, None], np.arange(n_kv)[None, :],
                       block_q, block_kv, causal, window)
-    return (int(run.sum(axis=1).max()), int(run.sum(axis=0).max()),
+    return (_fold_of(run.sum(axis=1)), _fold_of(run.sum(axis=0)[::-1]),
             int(run.sum()))
 
 
-def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref,
-                acc_ref, *, block_q: int, block_kv: int, n_band: int,
-                causal: bool, scale: float, window=None):
-    """One (head, q-block, band step) grid step.
+# The fold: where the first T rows of a causal band span 1, 2, ..., T
+# blocks (the triangle) and every later row spans T + 1, row p pairs
+# with row T - 1 - p into one folded row of exactly T + 1 steps, so no
+# inner step lies above the diagonal: a full causal layer of n blocks
+# (T = n, no later rows) makes n(n+1)/2 steps a head for n^2. A folded
+# row runs its first row's blocks, then its mate's; each half
+# accumulates its own output block in ascending order, as the band
+# did. The rows past the triangle keep their order, one to a folded
+# row. An odd triangle leaves its middle row alone, with its idle
+# steps; one of a single row (window 512 at 512-blocks) folds nothing
+# and keeps the band.
 
-    Grid = (heads, S/block_q, n_band), the band of kv-blocks innermost:
-    the VMEM scratch accumulators (m, l, acc) persist across the kv
-    sweep of one (head, q-block) and are re-initialized at its first
-    step. At its last step the normalized output block and the
-    logsumexp (the backward residual) are written once.
+
+def _fold_of(lengths):
+    """(pairs, odd, width) for rows running ``lengths`` blocks, the
+    triangle's rows first: the folded grid is ``len(lengths) - pairs``
+    rows of ``width`` steps. ``pairs`` 0 is the band, its width the
+    widest row. Checked on the lengths alone, since a step names its
+    block as its row's span's first plus its offset in the half."""
+    import numpy as np
+
+    lengths = np.asarray(lengths)
+    n = len(lengths)
+    tri = np.arange(1, n + 1)
+    fits = [t for t in range(n + 1)
+            if (lengths[:t] == tri[:t]).all() and (lengths[t:] == t + 1).all()]
+    for t in sorted(fits, key=lambda t: t % 2):      # even triangles first
+        if t // 2:
+            return t // 2, t % 2, t + 1
+    return 0, 0, int(lengths.max())
+
+
+def _fold_step(pr, j, fold):
+    """Folded row ``pr``, inner step ``j`` -> (row, offset in the row's
+    span, the first step of the row's half, its last step, in the
+    second half)."""
+    from jax import lax
+
+    pairs, odd, width = fold
+    first, last = lax.eq(j, 0), lax.eq(j, width - 1)
+    if not pairs:
+        return pr, j, first, last, False
+    paired = lax.lt(pr, pairs)
+    mate = lax.bitwise_and(paired, lax.gt(j, pr))   # second half of a pair
+    alone = lax.add(pr, pairs)                      # rows past the triangle
+    if odd:
+        alone = lax.select(lax.eq(pr, pairs), pr, alone)
+    row = lax.select(paired, lax.select(mate, lax.sub(2 * pairs + odd - 1, pr),
+                                        pr), alone)
+    turn = lax.add(pr, 1)                           # the mate's first step
+    return (row, lax.select(mate, lax.sub(j, turn), j),
+            lax.bitwise_or(first, lax.bitwise_and(paired, lax.eq(j, turn))),
+            lax.bitwise_or(last, lax.bitwise_and(paired, lax.eq(j, pr))),
+            mate)
+
+
+def _q_sweep(pr, j, fold, block_q, block_kv, causal, window):
+    """fwd / dq grid step -> (q-block, kv-block, live, first, last)."""
+    iq, t, first, last, _ = _fold_step(pr, j, fold)
+    ik, live = _band_block(t, _kv_span(iq, block_q, block_kv, causal, window))
+    return iq, ik, live, first, last
+
+
+def _kv_sweep(pr, j, fold, n_q, n_kv, block_q, block_kv, causal, window):
+    """dkv grid step -> (kv-block, q-block, live, first, last, in the
+    second half). A folded grid takes the kv-blocks from the last,
+    whose spans are the triangle."""
+    from jax import lax
+
+    row, t, first, last, mate = _fold_step(pr, j, fold)
+    ik = lax.sub(n_kv - 1, row) if fold[0] else row
+    iq, live = _band_block(
+        t, _q_span(ik, block_q, block_kv, n_q, causal, window))
+    return ik, iq, live, first, last, mate
+
+
+def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref,
+                acc_ref, *, block_q: int, block_kv: int, fold,
+                causal: bool, scale: float, window=None):
+    """One (head, folded row, step) grid step.
+
+    Grid = (heads, folded rows, width), a folded row's kv-blocks
+    innermost (`_q_sweep`): the VMEM scratch accumulators (m, l, acc)
+    persist across the kv sweep of one (head, q-block) and are
+    re-initialized at its first step. At its last step the normalized
+    output block and the logsumexp (the backward residual) are written
+    once.
     """
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
 
-    iq = pl.program_id(1)
-    j = pl.program_id(2)
-    ik, live = _band_block(
-        j, _kv_span(iq, block_q, block_kv, causal, window))
+    iq, ik, live, first, last = _q_sweep(
+        pl.program_id(1), pl.program_id(2), fold, block_q, block_kv,
+        causal, window)
 
-    @pl.when(j == 0)
+    @pl.when(first)
     def _init():
         m_ref[:] = jnp.full_like(m_ref, _NEG_INF)
         l_ref[:] = jnp.zeros_like(l_ref)
@@ -210,7 +288,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref,
         )
         m_ref[:] = m_new
 
-    @pl.when(j == n_band - 1)
+    @pl.when(last)
     def _finalize():
         l = l_ref[:]
         safe_l = jnp.where(l == 0.0, 1.0, l)         # fully-masked rows
@@ -260,18 +338,17 @@ def _bwd_p_ds(q, k, v, do, lse, delta, iq, ik, *, block_q, block_kv,
 
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                    dq_ref, dq_acc, *, block_q: int, block_kv: int,
-                   n_band: int, causal: bool, scale: float, window=None):
-    """Grid (heads, n_q, n_band), the band of kv-blocks innermost:
-    accumulate dq for one q-block across its KV sweep."""
+                   fold, causal: bool, scale: float, window=None):
+    """Grid (heads, folded rows, width), as the forward's: accumulate dq
+    for one q-block across its KV sweep."""
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
 
-    iq = pl.program_id(1)
-    j = pl.program_id(2)
-    ik, live = _band_block(
-        j, _kv_span(iq, block_q, block_kv, causal, window))
+    iq, ik, live, first, last = _q_sweep(
+        pl.program_id(1), pl.program_id(2), fold, block_q, block_kv,
+        causal, window)
 
-    @pl.when(j == 0)
+    @pl.when(first)
     def _init():
         dq_acc[:] = jnp.zeros_like(dq_acc)
 
@@ -293,34 +370,41 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             preferred_element_type=jnp.float32,
         )
 
-    @pl.when(j == n_band - 1)
+    @pl.when(last)
     def _finalize():
         dq_ref[0] = (dq_acc[:] * scale).astype(dq_ref.dtype)
 
 
 def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                     dk_ref, dv_ref, dk_acc, dv_acc, *, block_q: int,
-                    block_kv: int, n_q: int, n_band: int, group: int,
+                    block_kv: int, n_q: int, n_kv: int, fold, group: int,
                     causal: bool, scale: float, window=None):
-    """Grid (kv_heads, n_kv, group, n_band), (group, band of q-blocks)
-    innermost: accumulate dk and dv for one kv-block across the Q sweep
-    of EVERY query head sharing that KV head (GQA: ``group`` query heads
-    per KV head; MHA is group == 1). The two inner grid axes keep each
-    output block's revisits contiguous — the TPU accumulation-grid
-    rule."""
+    """Grid (kv_heads, folded rows, group, width), (group, folded row's
+    q-blocks) innermost: accumulate dk and dv for one kv-block across
+    the Q sweep of EVERY query head sharing that KV head (GQA: ``group``
+    query heads per KV head; MHA is group == 1). A folded row holds two
+    kv-blocks, which the group's sweeps take turns at, so each half
+    keeps its accumulators in a slot of its own (two slots where a fold
+    meets a group): a kv-block's sums run in the band's order, and it is
+    written at its half's last step of the group's last head, where the
+    output index map (`_build_calls`) turns to it — the TPU
+    accumulation-grid rule that keeps each output block's revisits
+    contiguous."""
     import jax.numpy as jnp
+    from jax import lax
     from jax.experimental import pallas as pl
 
-    ik = pl.program_id(1)
     g = pl.program_id(2)
-    j = pl.program_id(3)
-    iq, live = _band_block(
-        j, _q_span(ik, block_q, block_kv, n_q, causal, window))
+    ik, iq, live, first, last, mate = _kv_sweep(
+        pl.program_id(1), pl.program_id(3), fold, n_q, n_kv, block_q,
+        block_kv, causal, window)
+    slot = (lax.convert_element_type(mate, jnp.int32)
+            if dk_acc.shape[0] == 2 else 0)
 
-    @pl.when((g == 0) & (j == 0))
+    @pl.when(first & (g == 0))
     def _init():
-        dk_acc[:] = jnp.zeros_like(dk_acc)
-        dv_acc[:] = jnp.zeros_like(dv_acc)
+        dk_acc[slot] = jnp.zeros(dk_acc.shape[1:], dk_acc.dtype)
+        dv_acc[slot] = jnp.zeros(dv_acc.shape[1:], dv_acc.dtype)
 
     run = live & _run_window(iq, ik, block_q, block_kv, causal, window)
 
@@ -335,19 +419,19 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         p, ds = _bwd_p_ds(q, k, v, do, lse_ref[0], delta_ref[0], iq, ik,
                           block_q=block_q, block_kv=block_kv,
                           causal=causal, scale=scale, window=window)
-        dv_acc[:] += jax.lax.dot_general(            # p^T @ do
+        dv_acc[slot] += jax.lax.dot_general(         # p^T @ do
             p, do, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
-        dk_acc[:] += jax.lax.dot_general(            # ds^T @ q
+        dk_acc[slot] += jax.lax.dot_general(         # ds^T @ q
             ds, q, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
 
-    @pl.when((g == group - 1) & (j == n_band - 1))
+    @pl.when(last & (g == group - 1))
     def _finalize():
-        dk_ref[0] = (dk_acc[:] * scale).astype(dk_ref.dtype)
-        dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
+        dk_ref[0] = (dk_acc[slot] * scale).astype(dk_ref.dtype)
+        dv_ref[0] = dv_acc[slot].astype(dv_ref.dtype)
 
 
 # ---------------------------------------------------------------------------
@@ -465,11 +549,15 @@ def _build_calls(shape, dtype, causal, block_q, block_kv, interpret,
     shared by the out-only and the (out, lse) entry points.
 
     ``window`` (causal only) restricts attention to the last
-    ``window`` positions. Each kernel's innermost grid axis spans only
-    the band of blocks that `_run_window` lets run (2 kv-blocks for
-    window 512 at 512-blocks, all of them without a window), so blocks
-    outside every q row's window cost neither arithmetic nor a copy:
-    O(S*window) for O(S^2). A non-causal call has the square grid.
+    ``window`` positions. Each kernel's inner grid axes hold only the
+    blocks that `_run_window` lets run, so blocks outside every q row's
+    window cost neither arithmetic nor a copy: O(S*window) for O(S^2).
+    Where a causal band's rows fold (`_fold_of`: full causal, or a
+    window of two blocks or more, at equal blocks) every step runs: 136
+    a head at 8,192 tokens and 512-blocks, 108 for window 4,096.
+    Otherwise the innermost axis spans the band (2 kv-blocks for window
+    512), its short rows' steps idle. A non-causal call has the square
+    grid.
 
     ``kv_heads`` < heads enables grouped-query attention: K/V carry
     kv_heads heads and every group of ``heads // kv_heads`` query heads
@@ -488,6 +576,7 @@ def _build_calls(shape, dtype, causal, block_q, block_kv, interpret,
     one width exactly as before."""
     import jax
     import jax.numpy as jnp
+    from jax import lax
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -517,31 +606,31 @@ def _build_calls(shape, dtype, causal, block_q, block_kv, interpret,
     scale = 1.0 / (d ** 0.5)
     dv = d if v_dim is None else v_dim
 
-    # Inner extents: the widest band any q-block (kv-block, for dkv)
-    # has; the index maps name a band step's block as the kernels do.
-    n_band_kv, n_band_q, run_pairs = _band_extents(
+    fold_q, fold_kv, run_pairs = _grid_extents(
         n_q, n_kv, bq, bk, causal, window)
-    # flash_grid_steps{kernel, state}: the inner steps one head makes
-    # (dkv: one kv-head, its group's sweeps), run and idle.
-    for kernel, run, steps in (
-            ("flash_attn_fwd", run_pairs, n_q * n_band_kv),
-            ("flash_attn_dq", run_pairs, n_q * n_band_kv),
-            ("flash_attn_dkv", group * run_pairs, group * n_kv * n_band_q)):
-        device_telemetry.flash_grid_built(kernel, run, steps - run)
+    grid_q = (n_q - fold_q[0], fold_q[2])
+    grid_kv = (n_kv - fold_kv[0], fold_kv[2])
+    # flash_grid_steps{kernel, grid, state}: the inner steps one head
+    # makes (dkv: one kv-head, its group's sweeps), run and idle.
+    for kernel, fold, run, steps in (
+            ("flash_attn_fwd", fold_q, run_pairs, grid_q[0] * grid_q[1]),
+            ("flash_attn_dq", fold_q, run_pairs, grid_q[0] * grid_q[1]),
+            ("flash_attn_dkv", fold_kv, group * run_pairs,
+             group * grid_kv[0] * grid_kv[1])):
+        kind = "folded" if fold[0] else "band" if causal else "square"
+        device_telemetry.flash_grid_built(kernel, kind, run, steps - run)
 
-    def kv_of(iq, j):
-        return _band_block(j, _kv_span(iq, bq, bk, causal, window))[0]
-
-    def q_of(ik, j):
-        return _band_block(j, _q_span(ik, bq, bk, n_q, causal, window))[0]
+    def q_sweep(pr, j):
+        return _q_sweep(pr, j, fold_q, bq, bk, causal, window)
 
     def q_rows(width):
-        return pl.BlockSpec((1, bq, width), lambda ih, iq, j: (ih, iq, 0))
+        return pl.BlockSpec((1, bq, width),
+                            lambda ih, pr, j: (ih, q_sweep(pr, j)[0], 0))
 
     def kv_rows(width):
         return pl.BlockSpec(
             (1, bk, width),
-            lambda ih, iq, j: (ih // group, kv_of(iq, j), 0))
+            lambda ih, pr, j: (ih // group, q_sweep(pr, j)[1], 0))
 
     qkv_spec_q, qkv_spec_k = q_rows(d), kv_rows(d)
     o_spec, v_spec = ((qkv_spec_q, qkv_spec_k) if dv == d
@@ -550,13 +639,13 @@ def _build_calls(shape, dtype, causal, block_q, block_kv, interpret,
     # Mosaic wants the last two block dims divisible by (8, 128) or
     # equal to the array's, which a (1, bq) row block of an (h, s)
     # array is not — and the kernels consume them as columns anyway.
-    row_spec_q = pl.BlockSpec((1, bq, 1), lambda ih, iq, j: (ih, iq, 0))
+    row_spec_q = q_rows(1)
 
     fwd_call = pl.pallas_call(
         functools.partial(_fwd_kernel, block_q=bq, block_kv=bk,
-                          n_band=n_band_kv, causal=causal, scale=scale,
+                          fold=fold_q, causal=causal, scale=scale,
                           window=window),
-        grid=(h, n_q, n_band_kv),
+        grid=(h, *grid_q),
         in_specs=[qkv_spec_q, qkv_spec_k, v_spec],
         out_specs=[o_spec, row_spec_q],
         out_shape=[jax.ShapeDtypeStruct((h, s, dv), dtype),
@@ -572,9 +661,9 @@ def _build_calls(shape, dtype, causal, block_q, block_kv, interpret,
 
     dq_call = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, block_q=bq, block_kv=bk,
-                          n_band=n_band_kv, causal=causal, scale=scale,
+                          fold=fold_q, causal=causal, scale=scale,
                           window=window),
-        grid=(h, n_q, n_band_kv),
+        grid=(h, *grid_q),
         in_specs=[qkv_spec_q, qkv_spec_k, v_spec, o_spec,
                   row_spec_q, row_spec_q],
         out_specs=qkv_spec_q,
@@ -584,36 +673,50 @@ def _build_calls(shape, dtype, causal, block_q, block_kv, interpret,
         name="flash_attn_dq",
     )
 
-    # dkv grid is (kv_heads, n_kv, group, n_band_q): program ids land as
-    # (ikv, ik, g, j); (g, j) innermost so each (ikv, ik) output
-    # block's revisits are contiguous.
+    # dkv grid is (kv_heads, folded rows, group, width): program ids
+    # land as (ikv, pr, g, j); (g, j) innermost so each (ikv, ik) output
+    # block's revisits are contiguous. A folded row's two kv-blocks take
+    # turns over the group's heads, so where a fold meets a group its
+    # output block stays the first half's until the group's last head
+    # reaches the second.
+    def kv_sweep(pr, j):
+        return _kv_sweep(pr, j, fold_kv, n_q, n_kv, bq, bk, causal, window)
+
     def dkv_q_rows(width):
         return pl.BlockSpec(
             (1, bq, width),
-            lambda ikv, ik, g, j: (ikv * group + g, q_of(ik, j), 0))
+            lambda ikv, pr, g, j: (ikv * group + g, kv_sweep(pr, j)[1], 0))
 
     def dkv_k_rows(width):
         return pl.BlockSpec(
-            (1, bk, width), lambda ikv, ik, g, j: (ikv, ik, 0))
+            (1, bk, width), lambda ikv, pr, g, j: (ikv, kv_sweep(pr, j)[0], 0))
+
+    slots = 2 if fold_kv[0] and group > 1 else 1
+
+    def dkv_out_rows(width):
+        if slots == 1:
+            return dkv_k_rows(width)
+        return pl.BlockSpec(
+            (1, bk, width),
+            lambda ikv, pr, g, j: (ikv, kv_sweep(pr, lax.select(
+                lax.eq(g, group - 1), j, lax.full_like(j, 0)))[0], 0))
 
     dkv_q_spec, dkv_k_spec = dkv_q_rows(d), dkv_k_rows(d)
     dkv_do_spec, dkv_v_spec = ((dkv_q_spec, dkv_k_spec) if dv == d
                                else (dkv_q_rows(dv), dkv_k_rows(dv)))
-    dkv_row_spec = pl.BlockSpec(
-        (1, bq, 1),
-        lambda ikv, ik, g, j: (ikv * group + g, q_of(ik, j), 0))
+    dkv_row_spec = dkv_q_rows(1)
     dkv_call = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, block_q=bq, block_kv=bk,
-                          n_q=n_q, n_band=n_band_q, group=group,
+                          n_q=n_q, n_kv=n_kv, fold=fold_kv, group=group,
                           causal=causal, scale=scale, window=window),
-        grid=(kvh, n_kv, group, n_band_q),
+        grid=(kvh, grid_kv[0], group, grid_kv[1]),
         in_specs=[dkv_q_spec, dkv_k_spec, dkv_v_spec, dkv_do_spec,
                   dkv_row_spec, dkv_row_spec],
-        out_specs=[dkv_k_spec, dkv_v_spec],
+        out_specs=[dkv_out_rows(d), dkv_out_rows(dv)],
         out_shape=[jax.ShapeDtypeStruct((kvh, s_kv, d), dtype),
                    jax.ShapeDtypeStruct((kvh, s_kv, dv), dtype)],
-        scratch_shapes=[pltpu.VMEM((bk, d), jnp.float32),
-                        pltpu.VMEM((bk, dv), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((slots, bk, d), jnp.float32),
+                        pltpu.VMEM((slots, bk, dv), jnp.float32)],
         interpret=interpret,
         name="flash_attn_dkv",
     )

@@ -135,8 +135,9 @@ _m_mtp_traces = telemetry.counter(
 _m_flash_grid_steps = telemetry.counter(
     "flash_grid_steps",
     "Inner grid steps a head makes in the flash-attention programs "
-    "built (for dkv a kv-head), by kernel and state: 'run' computes, "
-    "'idle' only exists")
+    "built (for dkv a kv-head), by kernel, grid ('folded': the causal "
+    "triangle's rows paired, 'band', 'square' without a causal mask) "
+    "and state: 'run' computes, 'idle' only exists")
 _m_ring_rotations = telemetry.counter(
     "ring_rotations_traced",
     "Rotations of the ring-attention programs built, summed over the "
@@ -981,15 +982,16 @@ def mtp_traced(depth: int, weight: float) -> None:
     _m_mtp_traces.inc(depth=str(depth), weight=f"{weight:g}")
 
 
-def flash_grid_built(kernel: str, run: int, idle: int) -> None:
+def flash_grid_built(kernel: str, grid: str, run: int, idle: int) -> None:
     """One flash-attention kernel was built
-    (``ops/pallas_attention.py``) whose inner grid axis makes, for one
+    (``ops/pallas_attention.py``) whose inner grid axes make, for one
     head, ``run`` steps that compute and ``idle`` steps that only
-    exist. Counts programs built, not calls: an operator reads off it
-    whether a new shape (other blocks, an unaligned window) fell back
-    to a wide band."""
-    _m_flash_grid_steps.inc(run, kernel=kernel, state="run")
-    _m_flash_grid_steps.inc(idle, kernel=kernel, state="idle")
+    exist, on a ``grid`` that is ``folded``, ``band`` or ``square``.
+    Counts programs built, not calls: an operator reads off it whether
+    a new shape (other blocks, a window of one block) fell back to a
+    band."""
+    _m_flash_grid_steps.inc(run, kernel=kernel, grid=grid, state="run")
+    _m_flash_grid_steps.inc(idle, kernel=kernel, grid=grid, state="idle")
 
 
 def ring_built(layout: str, pairs) -> None:

@@ -908,10 +908,18 @@ def test_flash_window_lse_and_its_cotangent(s, h, kvh, window, block_q,
 def test_band_spans_are_the_runs_of_run_window(block_q, block_kv):
     """`_kv_span` / `_q_span` (what the kernels and index maps evaluate
     on program ids) name exactly the blocks `_run_window` lets run, for
-    every q-block and kv-block, aligned or not, and `_band_extents`
-    (what sizes the grids) is their widest."""
+    every q-block and kv-block, aligned or not, and `_grid_extents`
+    (what sizes the grids) gives a band their widest and a fold as many
+    steps as run, bar an odd triangle's middle row."""
     from fiber_tpu.ops.pallas_attention import (
-        _band_extents, _kv_span, _q_span, _run_window)
+        _grid_extents, _kv_span, _q_span, _run_window)
+
+    def runs(fold, n, widest):
+        """The steps of a fold that run; a band is the widest row."""
+        pairs, odd, width = fold
+        if not pairs:
+            return width == widest
+        return (n - pairs) * width - odd * (width - pairs - 1) == run.sum()
 
     s = 3072
     n_q, n_kv = s // block_q, s // block_kv
@@ -929,27 +937,37 @@ def test_band_spans_are_the_runs_of_run_window(block_q, block_kv):
                                window))
         want = (iq[:, None] >= first[None, :]) & (iq[:, None] <= last[None, :])
         assert np.array_equal(run, want), ("q", window)
-        assert _band_extents(n_q, n_kv, block_q, block_kv, True, window) == (
-            run.sum(1).max(), run.sum(0).max(), run.sum())
-    assert _band_extents(n_q, n_kv, block_q, block_kv, False, None) == (
-        n_kv, n_q, n_q * n_kv)
+        fold_q, fold_kv, pairs = _grid_extents(n_q, n_kv, block_q, block_kv,
+                                               True, window)
+        assert pairs == run.sum()
+        assert runs(fold_q, n_q, run.sum(1).max()), window
+        assert runs(fold_kv, n_kv, run.sum(0).max()), window
+    assert _grid_extents(n_q, n_kv, block_q, block_kv, False, None) == (
+        (0, 0, n_kv), (0, 0, n_q), n_q * n_kv)
+
+
+def _grids(calls, s, h, kvh, d=128, dv=None):
+    """The grid_mapping of each of the three built programs."""
+    dv = dv or d
+    x, o = (jax.ShapeDtypeStruct((h, s, w), jnp.float32) for w in (d, dv))
+    k, v = (jax.ShapeDtypeStruct((kvh or h, s, w), jnp.float32)
+            for w in (d, dv))
+    col = jax.ShapeDtypeStruct((h, s, 1), jnp.float32)
+    args = [(x, k, v), (x, k, v, o, col, col), (x, k, v, o, col, col)]
+    return [jax.make_jaxpr(call)(*a).eqns[0].params["grid_mapping"]
+            for call, a in zip(calls, args)]
 
 
 def _inner_extents(s, h, kvh, causal, window, block=512):
-    """The innermost grid extent of the three built programs."""
+    """(rows, innermost extent) of the three built programs' grids."""
     from fiber_tpu.ops.pallas_attention import _build_calls
 
     calls = _build_calls((s, h, 128), "float32", causal, block, block,
                          True, kvh, window)
-    x = jax.ShapeDtypeStruct((h, s, 128), jnp.float32)
-    kv = jax.ShapeDtypeStruct((kvh or h, s, 128), jnp.float32)
-    col = jax.ShapeDtypeStruct((h, s, 1), jnp.float32)
-    args = [(x, kv, kv), (x, kv, kv, x, col, col), (x, kv, kv, x, col, col)]
-    grids = [jax.make_jaxpr(call)(*a).eqns[0].params["grid_mapping"].grid
-             for call, a in zip(calls, args)]
-    assert grids[0][:2] == grids[1][:2] == (h, s // block)
-    assert grids[2][:3] == (kvh or h, s // block, h // (kvh or h))
-    return [g[-1] for g in grids]
+    grids = [gm.grid for gm in _grids(calls, s, h, kvh)]
+    assert grids[0] == grids[1] and grids[0][0] == h
+    assert grids[2][0] == (kvh or h) and grids[2][2] == h // (kvh or h)
+    return [(g[1], g[-1]) for g in grids]
 
 
 @pytest.mark.parametrize("causal,window,extent", [
@@ -957,18 +975,29 @@ def _inner_extents(s, h, kvh, causal, window, block=512):
     (True, None, 16), (False, None, 16),
 ])
 def test_flash_grids_are_the_band(causal, window, extent):
-    """At 8,192 tokens and 512-blocks the inner axis of all three grids
-    spans the band: 2 blocks for window 512, 9 for 4,096, all 16 without
-    a window (causal: the diagonal row needs them; non-causal: the grid
-    it always had). An unaligned window costs one more block at most."""
-    assert _inner_extents(8192, 8, 2, causal, window) == [extent] * 3
+    """At 8,192 tokens and 512-blocks (16 blocks) ``extent`` is the band
+    each grid's innermost axis spanned before the fold: 2 blocks for
+    window 512, 9 for 4,096, all 16 without a window. Where the causal
+    triangle folds, every grid holds exactly the 136 (full), 108 (window
+    4,096) or 45 (window 514) steps that run: 8 rows of 17, 12 of 9 and
+    15 of 3. Window 512, 500 and 513 have a triangle of one row and keep
+    the band, 16 rows of 2, as the non-causal call keeps its square."""
+    folded = {None: (8, 17), 4096: (12, 9), 514: (15, 3)}
+    rows, width = (folded[window] if causal and window in folded
+                   else (16, extent))
+    assert _inner_extents(8192, 8, 2, causal, window) == [(rows, width)] * 3
+    if causal and window in folded:
+        assert rows * width == {None: 136, 4096: 108, 514: 45}[window]
+        assert width - 1 <= extent
 
 
 def test_flash_grid_steps_counter():
     """Building a program adds its inner steps a head to
-    flash_grid_steps{kernel, state}: window 512 at 8,192 tokens runs 31
-    of the band's 32 steps (256 on the square grid), dkv the same for
-    each of its kv-head's 4 query heads."""
+    flash_grid_steps{kernel, grid, state}: at 8,192 tokens full causal
+    and window 4,096 fold, every step running (136 and 108 for the
+    band's 256 and 144), window 512 keeps its band and runs 31 of 32,
+    dkv the same for each of its kv-head's 4 query heads; the
+    non-causal square runs all 256. Nothing moves under another grid."""
     import fiber_tpu
     from fiber_tpu import telemetry
     from fiber_tpu.ops.pallas_attention import _build_calls
@@ -976,10 +1005,11 @@ def test_flash_grid_steps_counter():
     fiber_tpu.init()
     counter = telemetry.counter("flash_grid_steps")
     kernels = ("flash_attn_fwd", "flash_attn_dq", "flash_attn_dkv")
+    grids = ("folded", "band", "square")
 
     def read():
-        return {(k, st): counter.value(kernel=k, state=st)
-                for k in kernels for st in ("run", "idle")}
+        return {(k, gr, st): counter.value(kernel=k, grid=gr, state=st)
+                for k in kernels for gr in grids for st in ("run", "idle")}
 
     def built(window, causal=True):
         _build_calls.cache_clear()
@@ -987,23 +1017,28 @@ def test_flash_grid_steps_counter():
         _build_calls((8192, 8, 128), "float32", causal, 512, 512, True, 2,
                      window)
         after = read()
-        return [(int(after[k, "run"] - before[k, "run"]),
-                 int(after[k, "idle"] - before[k, "idle"]))
-                for k in kernels]
+        moved = {key for key in after if after[key] != before[key]}
+        kind = {key[1] for key in moved}
+        assert len(kind) == 1, moved
+        gr = kind.pop()
+        return gr, [(int(after[k, gr, "run"] - before[k, gr, "run"]),
+                     int(after[k, gr, "idle"] - before[k, gr, "idle"]))
+                    for k in kernels]
 
-    assert built(512) == [(31, 1), (31, 1), (124, 4)]
-    assert built(4096) == [(108, 36), (108, 36), (432, 144)]
-    assert built(None) == [(136, 120), (136, 120), (544, 480)]
-    assert built(None, causal=False) == [(256, 0), (256, 0), (1024, 0)]
+    assert built(512) == ("band", [(31, 1), (31, 1), (124, 4)])
+    assert built(4096) == ("folded", [(108, 0), (108, 0), (432, 0)])
+    assert built(None) == ("folded", [(136, 0), (136, 0), (544, 0)])
+    assert built(None, causal=False) == (
+        "square", [(256, 0), (256, 0), (1024, 0)])
 
 
-def _square_grid_calls(shape, causal, bq, bk, kv_heads, window):
+def _square_grid_calls(shape, causal, bq, bk, kv_heads, window, v_dim=None):
     """The three programs as they were built before the band: every
     kernel on the full (n_q, n_kv) square, a step outside the band
     skipped by ``pl.when`` alone. Kept here, and not in the library, as
-    the yardstick the banded programs must equal bit for bit. Shares
-    the library's per-step arithmetic (`_run_window`, `_keep_mask`,
-    `_bwd_p_ds`), which the band did not touch."""
+    the yardstick the banded and folded programs must equal bit for
+    bit. Shares the library's per-step arithmetic (`_run_window`,
+    `_keep_mask`, `_bwd_p_ds`), which neither touched."""
     import functools
 
     from jax.experimental import pallas as pl
@@ -1013,6 +1048,7 @@ def _square_grid_calls(shape, causal, bq, bk, kv_heads, window):
         _NEG_INF, _bwd_p_ds, _keep_mask, _run_window)
 
     s, h, d = shape
+    dv = v_dim or d
     kvh = kv_heads or h
     group = h // kvh
     n_q, n_kv = s // bq, s // bk
@@ -1100,33 +1136,42 @@ def _square_grid_calls(shape, causal, bq, bk, kv_heads, window):
             dk_ref[0] = (dk_acc[:] * scale).astype(dk_ref.dtype)
             dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
 
-    spec_q = pl.BlockSpec((1, bq, d), lambda ih, iq, ik: (ih, iq, 0))
-    spec_k = pl.BlockSpec((1, bk, d), lambda ih, iq, ik: (ih // group, ik, 0))
-    row_q = pl.BlockSpec((1, bq, 1), lambda ih, iq, ik: (ih, iq, 0))
-    hsd = jax.ShapeDtypeStruct((h, s, d), f32)
-    kvsd = jax.ShapeDtypeStruct((kvh, s, d), f32)
+    def spec_q(w):
+        return pl.BlockSpec((1, bq, w), lambda ih, iq, ik: (ih, iq, 0))
+
+    def spec_k(w):
+        return pl.BlockSpec((1, bk, w),
+                            lambda ih, iq, ik: (ih // group, ik, 0))
+
+    row_q = spec_q(1)
     fwd_call = pl.pallas_call(
-        fwd, grid=(h, n_q, n_kv), in_specs=[spec_q, spec_k, spec_k],
-        out_specs=[spec_q, row_q],
-        out_shape=[hsd, jax.ShapeDtypeStruct((h, s, 1), f32)],
+        fwd, grid=(h, n_q, n_kv), in_specs=[spec_q(d), spec_k(d), spec_k(dv)],
+        out_specs=[spec_q(dv), row_q],
+        out_shape=[jax.ShapeDtypeStruct((h, s, dv), f32),
+                   jax.ShapeDtypeStruct((h, s, 1), f32)],
         scratch_shapes=[pltpu.VMEM((bq, 1), f32), pltpu.VMEM((bq, 1), f32),
-                        pltpu.VMEM((bq, d), f32)],
+                        pltpu.VMEM((bq, dv), f32)],
         interpret=True)
     dq_call = pl.pallas_call(
         dq, grid=(h, n_q, n_kv),
-        in_specs=[spec_q, spec_k, spec_k, spec_q, row_q, row_q],
-        out_specs=spec_q, out_shape=hsd,
+        in_specs=[spec_q(d), spec_k(d), spec_k(dv), spec_q(dv), row_q, row_q],
+        out_specs=spec_q(d), out_shape=jax.ShapeDtypeStruct((h, s, d), f32),
         scratch_shapes=[pltpu.VMEM((bq, d), f32)], interpret=True)
-    dkv_q = pl.BlockSpec(
-        (1, bq, d), lambda ikv, ik, g, iq: (ikv * group + g, iq, 0))
-    dkv_k = pl.BlockSpec((1, bk, d), lambda ikv, ik, g, iq: (ikv, ik, 0))
-    dkv_row = pl.BlockSpec(
-        (1, bq, 1), lambda ikv, ik, g, iq: (ikv * group + g, iq, 0))
+
+    def dkv_q(w):
+        return pl.BlockSpec(
+            (1, bq, w), lambda ikv, ik, g, iq: (ikv * group + g, iq, 0))
+
+    def dkv_k(w):
+        return pl.BlockSpec((1, bk, w), lambda ikv, ik, g, iq: (ikv, ik, 0))
+
     dkv_call = pl.pallas_call(
         dkv, grid=(kvh, n_kv, group, n_q),
-        in_specs=[dkv_q, dkv_k, dkv_k, dkv_q, dkv_row, dkv_row],
-        out_specs=[dkv_k, dkv_k], out_shape=[kvsd, kvsd],
-        scratch_shapes=[pltpu.VMEM((bk, d), f32), pltpu.VMEM((bk, d), f32)],
+        in_specs=[dkv_q(d), dkv_k(d), dkv_k(dv), dkv_q(dv), dkv_q(1),
+                  dkv_q(1)],
+        out_specs=[dkv_k(d), dkv_k(dv)],
+        out_shape=[jax.ShapeDtypeStruct((kvh, s, w), f32) for w in (d, dv)],
+        scratch_shapes=[pltpu.VMEM((bk, d), f32), pltpu.VMEM((bk, dv), f32)],
         interpret=True)
     return fwd_call, dq_call, dkv_call
 
@@ -1161,6 +1206,175 @@ def test_banded_kernels_equal_square_grid_bit_for_bit(causal, window,
         dq = dq_call(q, k, v, do, lse, delta)
         dk, dv = dkv_call(q, k, v, do, lse, delta)
         outs.append([np.asarray(x) for x in (out, lse, dq, dk, dv)])
+    for name, got, want in zip(("out", "lse", "dq", "dk", "dv"), *outs):
+        assert np.isfinite(want).all() and np.abs(want).max() > 0, name
+        assert np.array_equal(got, want), name
+
+
+def _walk(gm):
+    """Each block mapping's block indices at every step of the grid, in
+    the order the grid runs (the last axis fastest): (program ids, one
+    (steps, ndim) array per mapping, inputs then outputs)."""
+    ids = [jnp.asarray(a.ravel(), jnp.int32) for a in np.indices(gm.grid)]
+
+    def index(*i):
+        return [jnp.stack(jnp.broadcast_arrays(*jax.core.eval_jaxpr(
+            bm.index_map_jaxpr.jaxpr, bm.index_map_jaxpr.consts, *i)), -1)
+            for bm in gm.block_mappings]
+
+    maps = jax.jit(jax.vmap(index))(*ids)
+    return [np.asarray(i) for i in ids], [np.asarray(m) for m in maps]
+
+
+def _replay(out_block, slot, init, live, term, fin, owner, want):
+    """Replays a kernel's accumulators over its grid steps: ``init``
+    zeroes ``slot`` for ``owner``, a ``live`` step adds ``term`` to it,
+    ``fin`` writes it to the output block the index map names. Each
+    output block must be visited in one contiguous run (the TPU
+    pipeline writes a block back when the index moves on), written once
+    at that run's last step, and hold exactly ``want[owner]``'s terms in
+    that order: the order the band summed them in."""
+    runs = [b for i, b in enumerate(out_block)
+            if i == 0 or b != out_block[i - 1]]
+    assert len(runs) == len(set(runs)), "an output block is revisited"
+    acc, written = {}, {}
+    for t in range(len(out_block)):
+        if init[t]:
+            acc[slot[t]] = (owner[t], [])
+        if live[t]:
+            assert acc[slot[t]][0] == owner[t], t
+            acc[slot[t]][1].append(term[t])
+        if fin[t]:
+            got, terms = acc.pop(slot[t])
+            assert got == owner[t] == out_block[t], t
+            assert t + 1 == len(out_block) or out_block[t + 1] != got, t
+            written[got] = terms
+    assert written == want
+
+
+@pytest.mark.parametrize("causal,s,block_q,block_kv,window,grid", [
+    (True, 512, 128, 128, None, "folded"),    # n = 4
+    (True, 1024, 128, 128, None, "folded"),   # n = 8
+    (True, 2048, 128, 128, None, "folded"),   # n = 16
+    (True, 640, 128, 128, None, "folded"),    # n = 5: odd, still exact
+    (True, 2048, 128, 128, 512, "folded"),    # aligned window, triangle 4
+    (True, 2048, 128, 128, 384, "folded"),    # triangle 3: a lone middle row
+    (True, 2048, 128, 128, 200, "folded"),    # unaligned, triangle 2
+    (True, 2048, 128, 128, 300, "folded"),    # unaligned, triangle 3
+    (True, 2048, 128, 128, 128, "band"),      # a triangle of one row
+    (True, 2048, 128, 128, 100, "band"),
+    (True, 1024, 128, 256, None, "band"),     # unequal blocks
+    (True, 1024, 256, 128, 300, "band"),
+    (False, 1024, 128, 128, None, "square"),
+])
+def test_folded_grid_visits_each_run_pair_once(causal, s, block_q, block_kv,
+                                               window, grid):
+    """Walked step by step through the built programs' own index maps
+    and the kernels' own `_q_sweep` / `_kv_sweep`, each grid visits
+    every (q-block, kv-block) pair that `_run_window` runs once a head
+    (dkv: once for each head of the group), sums each output block's
+    pairs in ascending order (dkv: the group's heads in turn) and
+    writes it once, at the end of its one contiguous run. A folded grid
+    makes exactly the steps that run, bar an odd triangle's middle
+    row; a band or square grid makes the ones it always did."""
+    from fiber_tpu.ops.pallas_attention import (
+        _build_calls, _grid_extents, _kv_sweep, _q_sweep, _run_window)
+
+    h, kvh, d = 4, 2, 32
+    group = h // kvh
+    calls = _build_calls((s, h, d), "float32", causal, block_q, block_kv,
+                         True, kvh, window)
+    n_q, n_kv = s // block_q, s // block_kv
+    run = np.asarray(_run_window(np.arange(n_q)[:, None],
+                                 np.arange(n_kv)[None, :], block_q,
+                                 block_kv, causal, window))
+    run = np.broadcast_to(run, (n_q, n_kv))
+    fold_q, fold_kv, pairs = _grid_extents(n_q, n_kv, block_q, block_kv,
+                                           causal, window)
+    assert pairs == run.sum()
+    fwd, dq, dkv = _grids(calls, s, h, kvh, d)
+    assert fwd.grid == dq.grid == (h, n_q - fold_q[0], fold_q[2])
+    assert dkv.grid == (kvh, n_kv - fold_kv[0], group, fold_kv[2])
+    kinds = {"folded" if f[0] else "band" if causal else "square"
+             for f in (fold_q, fold_kv)}
+    assert kinds == {grid}
+
+    def scalar_ops(fn, *ids):
+        got = jax.jit(jax.vmap(lambda *i: [jnp.asarray(x) for x in fn(*i)]))(
+            *(jnp.asarray(i) for i in ids))
+        return [np.asarray(x) for x in got]
+
+    # fwd and dq: one program's walk, the other's grid and maps the same.
+    (ih, pr, j), maps = _walk(fwd)
+    for a, b in zip(maps, _walk(dq)[1]):
+        assert np.array_equal(a[:, 1], b[:, 1])
+    iq, ik, live, first, last = scalar_ops(
+        lambda p, i: _q_sweep(p, i, fold_q, block_q, block_kv, causal,
+                              window), pr, j)
+    live = live & run[iq, ik]
+    assert np.array_equal(maps[0][:, :2], np.stack([ih, iq], -1))
+    assert np.array_equal(maps[1][:, :2], np.stack([ih // group, ik], -1))
+    want = {(a, b): [c for c in range(n_kv) if run[b, c]]
+            for a in range(h) for b in range(n_q)}
+    _replay(list(zip(ih, maps[3][:, 1])), np.zeros_like(ih), first, live,
+            ik, last, list(zip(ih, iq)), want)
+    idle_q = len(ih) // h - pairs
+    # dkv
+    (ikv, pr, g, j), maps = _walk(dkv)
+    ik, iq, live, first, last, mate = scalar_ops(
+        lambda p, i: _kv_sweep(p, i, fold_kv, n_q, n_kv, block_q, block_kv,
+                               causal, window), pr, j)
+    live = live & run[iq, ik]
+    assert np.array_equal(maps[0][:, :2], np.stack([ikv * group + g, iq], -1))
+    assert np.array_equal(maps[1][:, :2], np.stack([ikv, ik], -1))
+    want = {(a, c): [(gg, b) for gg in range(group) for b in range(n_q)
+                     if run[b, c]]
+            for a in range(kvh) for c in range(n_kv)}
+    _replay(list(zip(ikv, maps[6][:, 1])), mate, first & (g == 0), live,
+            list(zip(g, iq)), last & (g == group - 1), list(zip(ikv, ik)),
+            want)
+    idle_kv = len(ikv) // kvh - group * pairs
+    if grid == "folded":
+        for fold, idle in ((fold_q, idle_q), (fold_kv, idle_kv // group)):
+            pairs_, odd, width = fold
+            assert idle == odd * (width - pairs_ - 1)
+    else:
+        assert idle_q == n_q * fold_q[2] - pairs
+
+
+@pytest.mark.parametrize("s,h,kvh,d,dv,window", [
+    (512, 1, 1, 32, 16, None),     # MHA, values narrower
+    (640, 2, 1, 32, 16, None),     # GQA, n = 5, values narrower
+    (768, 3, 1, 32, 32, 384),      # GQA group 3, window, triangle of 3
+])
+def test_folded_kernels_equal_square_grid_bit_for_bit(s, h, kvh, d, dv,
+                                                      window):
+    """The fold changes which grid steps exist and the order the heads
+    of a folded row take turns, never a sum or its order: out, lse, dq,
+    dk and dv of the folded programs equal the square-grid programs'
+    bit for bit (interpret mode, 128-blocks), under GQA, where dkv keeps
+    a folded row's two kv-blocks in slots of their own, and with values
+    of a width of their own."""
+    from fiber_tpu.ops.pallas_attention import _build_calls, _grid_extents
+
+    n = s // 128
+    assert all(f[0] for f in _grid_extents(n, n, 128, 128, True, window)[:2])
+    keys = jax.random.split(jax.random.PRNGKey(5), 5)
+    q = jax.random.normal(keys[0], (h, s, d))
+    do = jax.random.normal(keys[1], (h, s, dv))
+    k = jax.random.normal(keys[2], (kvh, s, d))
+    v = jax.random.normal(keys[3], (kvh, s, dv))
+    dlse = jax.random.normal(keys[4], (h, s, 1))
+    outs = []
+    for fwd_call, dq_call, dkv_call in (
+            _build_calls((s, h, d), "float32", True, 128, 128, True, kvh,
+                         window, None, None if dv == d else dv),
+            _square_grid_calls((s, h, d), True, 128, 128, kvh, window, dv)):
+        out, lse = fwd_call(q, k, v)
+        delta = jnp.sum(do * out, axis=-1, keepdims=True) - dlse
+        dq = dq_call(q, k, v, do, lse, delta)
+        dk, dv_ = dkv_call(q, k, v, do, lse, delta)
+        outs.append([np.asarray(x) for x in (out, lse, dq, dk, dv_)])
     for name, got, want in zip(("out", "lse", "dq", "dk", "dv"), *outs):
         assert np.isfinite(want).all() and np.abs(want).max() > 0, name
         assert np.array_equal(got, want), name
